@@ -13,7 +13,7 @@ import json
 from pathlib import Path
 
 from repro.analysis import memmodel
-from repro.analysis.roofline import HBM_BW, ICI_BW, PEAK_FLOPS
+from repro.analysis.roofline import TARGET
 from repro.configs import SHAPES, get_config
 
 from .common import csv_row
@@ -28,15 +28,15 @@ def cell_summary(rec: dict) -> dict:
     chips = rec["chips"]
     ext = rec["cost_extrapolated_per_chip"]
     rf = rec["roofline"]
-    compute_s = ext["flops"] / PEAK_FLOPS
-    coll_s = sum(ext["collectives"].values()) / ICI_BW
+    compute_s = ext["flops"] / TARGET.peak_bf16_flops
+    coll_s = sum(ext["collectives"].values()) / TARGET.ici_link_bytes_per_s
     mem_s = memmodel.memory_seconds(cfg, shape, multi_pod=multi,
                                     remat=rec.get("remat", "full"))
-    mem_upper_s = ext["bytes"] / HBM_BW
+    mem_upper_s = ext["bytes"] / TARGET.hbm_bytes_per_s
     terms = {"compute": compute_s, "memory": mem_s, "collective": coll_s}
     bottleneck = max(terms, key=terms.get)
     lb = max(terms.values())
-    ideal = rf["model_flops"] / chips / PEAK_FLOPS
+    ideal = rf["model_flops"] / chips / TARGET.peak_bf16_flops
     return {
         "arch": rec["arch"], "shape": rec["shape"], "mesh": rec["mesh"],
         "chips": chips,

@@ -13,8 +13,9 @@ per-participant, so we multiply by the number of chips to get fleet
 totals, then divide back per the roofline formulas (the per-chip terms
 are what matter).
 
-Hardware constants (TPU v5e target): 197 TFLOP/s bf16 per chip,
-819 GB/s HBM, ~50 GB/s/link ICI.
+Hardware rates come from the TPU v5e row of ``repro.platform`` (the
+dry-run's target chip, named as such): peak bf16 FLOP/s and HBM
+bandwidth per chip, and one ICI link's bandwidth.
 """
 from __future__ import annotations
 
@@ -22,9 +23,9 @@ import dataclasses
 import re
 from typing import Dict, Optional
 
-PEAK_FLOPS = 197e12          # bf16 FLOP/s per chip
-HBM_BW = 819e9               # bytes/s per chip
-ICI_BW = 50e9                # bytes/s per link
+from ..platform import DEVICE_SPECS, INTERPRET_DEVICE_KIND
+
+TARGET = DEVICE_SPECS[INTERPRET_DEVICE_KIND]
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1,
@@ -85,9 +86,11 @@ class RooflineTerms:
     model_flops: Optional[float] = None
 
     def finalize(self):
-        self.compute_s = self.flops / (self.chips * PEAK_FLOPS)
-        self.memory_s = self.hbm_bytes / (self.chips * HBM_BW)
-        self.collective_s = self.collective_bytes / (self.chips * ICI_BW)
+        self.compute_s = self.flops / (self.chips * TARGET.peak_bf16_flops)
+        self.memory_s = self.hbm_bytes / (self.chips
+                                          * TARGET.hbm_bytes_per_s)
+        self.collective_s = self.collective_bytes / (
+            self.chips * TARGET.ici_link_bytes_per_s)
         terms = {"compute": self.compute_s, "memory": self.memory_s,
                  "collective": self.collective_s}
         self.bottleneck = max(terms, key=terms.get)
@@ -108,7 +111,7 @@ class RooflineTerms:
         """MODEL_FLOPS-time / achievable step time — the score."""
         if self.model_flops is None:
             return None
-        ideal = self.model_flops / (self.chips * PEAK_FLOPS)
+        ideal = self.model_flops / (self.chips * TARGET.peak_bf16_flops)
         lb = self.step_time_lower_bound
         return ideal / lb if lb > 0 else None
 

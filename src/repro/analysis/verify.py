@@ -57,6 +57,8 @@ tests/test_verify.py):
                                 staged window, or the window overruns
                                 the tail-padded stream / request region
   dma_window_alignment          window not STAGE_TILE-rounded (warning)
+  mxu_alignment                 an MXU block's lane-padded panels do not
+                                start on a STAGE_TILE slot of its member
   gather_oob                    a gather index falls outside
                                 [0, nnz] (or its request's vals range)
   cols_oob                      a column entry is out of bounds of its
@@ -163,26 +165,27 @@ def check_workspace(ws, *, nnz: Optional[int] = None,
 # re-enters it via repro.core.__init__ -> spmm, and that re-entry must
 # find those names already bound.  Everything after this line only
 # dereferences the plan symbols at call time.
-from ..core.plan import (MXU_TAG, STAGE_TILE, BatchedFusedWorkspace,  # noqa: E402
+from ..core.plan import (LANE, MXU_TAG, STAGE_TILE,  # noqa: E402
+                         BatchedFusedWorkspace,
                          FusedEllWorkspace, ShardedFusedWorkspace,
-                         SparseEinsumSpec)
+                         SparseEinsumSpec, workspace_row_map)
 
 
 # -- shared helpers ----------------------------------------------------------
 
-def _extents(tag: np.ndarray, L: np.ndarray, bm: int, bk: int):
+def _extents(tag: np.ndarray, L: np.ndarray, bm: int):
     """Per-descriptor slot/column footprints: a VPU block's slots are
     its (bm, L) ELL panel (column stream slot-parallel), an MXU
-    block-row's are its (L, bm, bk) value panels with only L column
-    entries.  Pad blocks (L == 0) are zero either way."""
+    block-row's are its (L, bm, LANE) lane-padded value panels with
+    only L column entries.  Pad blocks (L == 0) are zero either way."""
     L = L.astype(np.int64)
-    span = np.where(tag == MXU_TAG, L * bm * bk, L * bm)
+    span = np.where(tag == MXU_TAG, L * bm * LANE, L * bm)
     cspan = np.where(tag == MXU_TAG, L, L * bm)
     return span, cspan
 
 
 def _verify_member_tables(out: List[PlanViolation], *, tag, off, coff, L,
-                          bm: int, bk: int, merge_width: int,
+                          bm: int, merge_width: int,
                           window: int, cwindow: int,
                           slot_lo: int, slot_hi: int, slot_buf_hi: int,
                           col_lo: int, col_hi: int, col_buf_hi: int,
@@ -203,7 +206,7 @@ def _verify_member_tables(out: List[PlanViolation], *, tag, off, coff, L,
             f"{member}: {B} descriptors not a multiple of "
             f"merge_width={mw}"))
         return
-    span, cspan = _extents(tag, L, bm, bk)
+    span, cspan = _extents(tag, L, bm)
     real = L > 0
     if np.any(L < 0):
         bad = np.flatnonzero(L < 0)
@@ -240,6 +243,14 @@ def _verify_member_tables(out: List[PlanViolation], *, tag, off, coff, L,
             "blk_bounds", "blk_coff",
             f"{member}: descriptor col extent outside real region "
             f"[{col_lo}, {col_hi})",
+            indices=tuple(int(i) + idx_base
+                          for i in np.flatnonzero(bad)[:4])))
+    # the MXU trip reads each panel as one aligned (bm, LANE) tile
+    bad = real & (tag == MXU_TAG) & ((o64 - slot_lo) % STAGE_TILE != 0)
+    if np.any(bad):
+        out.append(PlanViolation(
+            "mxu_alignment", "blk_off",
+            f"{member}: MXU panels not on a {STAGE_TILE}-slot boundary",
             indices=tuple(int(i) + idx_base
                           for i in np.flatnonzero(bad)[:4])))
     # DMA-window coverage per merged trip (only when the workspace
@@ -286,7 +297,7 @@ def _verify_trip_spans(out: List[PlanViolation], ws: FusedEllWorkspace
     if ws.blk_span is None or ws.blk_cspan is None:
         return
     mw = max(ws.merge_width, 1)
-    span, cspan = _extents(ws.blk_tag, ws.blk_L, ws.row_block, ws.bk)
+    span, cspan = _extents(ws.blk_tag, ws.blk_L, ws.row_block)
     want = span.reshape(-1, mw).sum(axis=1)
     wantc = cspan.reshape(-1, mw).sum(axis=1)
     for name, have, need in (("blk_span", ws.blk_span, want),
@@ -303,12 +314,14 @@ def _verify_trip_spans(out: List[PlanViolation], ws: FusedEllWorkspace
 
 def _verify_perm(out: List[PlanViolation], inv_perm: np.ndarray,
                  ws_rows: int, field: str = "inv_perm",
-                 row_map: Optional[np.ndarray] = None) -> None:
+                 row_map: Optional[np.ndarray] = None, cont=None,
+                 trip_rows: int = 0) -> None:
     """``inv_perm`` must be injective into [0, ws_rows); a caller-
     STAGED forward ``row_map`` (the constant shipped to the kernel for
     row-indexed operands, e.g. attention's Q gather) must additionally
-    compose with it back to the identity on output rows and carry the
-    pad sentinel ``m`` everywhere else.  A freshly derived map inverts
+    compose with it back to the identity on output rows, repeat a split
+    block's rows on its earlier pieces (``cont``), and carry the pad
+    sentinel ``m`` everywhere else.  A freshly derived map inverts
     by construction — the round trip only means something for the
     artifact a dispatch will actually read."""
     m = int(inv_perm.shape[0])
@@ -338,8 +351,7 @@ def _verify_perm(out: List[PlanViolation], inv_perm: np.ndarray,
             f"staged row_map has {rm.shape[0]} slots, workspace has "
             f"{ws_rows}"))
         return
-    want = np.full(ws_rows, m, dtype=np.int64)
-    want[p] = np.arange(m, dtype=np.int64)
+    want = workspace_row_map(p, ws_rows, cont, trip_rows)
     bad = rm != want
     if np.any(bad):
         out.append(PlanViolation(
@@ -392,7 +404,7 @@ def _real_col_mask(tag, coff, L, *, base: int, size: int, bm: int):
     MXU block-column ids (vs VPU row ids)."""
     referenced = np.zeros(size, bool)
     mxu = np.zeros(size, bool)
-    _, cspan = _extents(tag, L, bm, 1)
+    _, cspan = _extents(tag, L, bm)
     for t, c, s in zip(tag, coff.astype(np.int64) - base, cspan):
         if s <= 0:
             continue
@@ -469,13 +481,14 @@ def verify_fused_workspace(ws: FusedEllWorkspace, *,
             f"{ws.num_blocks * bm}"))
     _verify_member_tables(
         out, tag=ws.blk_tag, off=ws.blk_off, coff=ws.blk_coff,
-        L=ws.blk_L, bm=bm, bk=bk, merge_width=ws.merge_width,
+        L=ws.blk_L, bm=bm, merge_width=ws.merge_width,
         window=ws.max_span, cwindow=ws.max_cspan,
         slot_lo=0, slot_hi=s_real, slot_buf_hi=S_buf,
         col_lo=0, col_hi=c_real, col_buf_hi=Sc_buf,
         member="workspace")
     _verify_trip_spans(out, ws)
-    _verify_perm(out, ws.inv_perm, ws.ws_rows, row_map=row_map)
+    _verify_perm(out, ws.inv_perm, ws.ws_rows, row_map=row_map,
+                 cont=ws.blk_cont, trip_rows=ws.merge_width * bm)
     _verify_pads_unread(out, ws.inv_perm, ws.blk_L, bm)
     _warn_window_alignment(out, ws.max_span, ws.max_cspan)
     if level != "full":
@@ -611,7 +624,7 @@ def verify_sharded_workspace(sw: ShardedFusedWorkspace, *,
         cwin = int(sw.chip_cspan[c])
         _verify_member_tables(
             out, tag=sw.blk_tag[c], off=sw.blk_off[c],
-            coff=sw.blk_coff[c], L=sw.blk_L[c], bm=bm, bk=bk,
+            coff=sw.blk_coff[c], L=sw.blk_L[c], bm=bm,
             merge_width=sw.merge_width, window=win, cwindow=cwin,
             slot_lo=0, slot_hi=max(S_buf - win, 0) if win else S_buf,
             slot_buf_hi=S_buf,
@@ -620,7 +633,9 @@ def verify_sharded_workspace(sw: ShardedFusedWorkspace, *,
         _verify_pads_unread(
             out, sw.inv_perm[b[c]:b[c + 1]] - c * sw.ws_rows,
             sw.blk_L[c], bm)
-    _verify_perm(out, sw.inv_perm, C * sw.ws_rows, row_map=row_map)
+    _verify_perm(out, sw.inv_perm, C * sw.ws_rows, row_map=row_map,
+                 cont=sw.blk_cont.reshape(-1),
+                 trip_rows=sw.merge_width * bm)
     chip_of_row = sw.inv_perm.astype(np.int64) // max(sw.ws_rows, 1)
     owner = np.repeat(np.arange(C), np.diff(b))
     if chip_of_row.shape == owner.shape and np.any(chip_of_row != owner):
@@ -710,7 +725,7 @@ def verify_batched_workspace(bw: BatchedFusedWorkspace, *,
         win, cwin = bw.max_span, bw.max_cspan
         _verify_member_tables(
             out, tag=bw.blk_tag[sl], off=bw.blk_off[sl],
-            coff=bw.blk_coff[sl], L=bw.blk_L[sl], bm=bm, bk=bk,
+            coff=bw.blk_coff[sl], L=bw.blk_L[sl], bm=bm,
             merge_width=bw.merge_width, window=win, cwindow=cwin,
             slot_lo=r * S,
             slot_hi=(r + 1) * S - win if win else (r + 1) * S,

@@ -7,7 +7,7 @@ tagging, the CGCM ``merge_threshold`` and the operand ``staging`` mode.
 This module closes the loop in two stages (DESIGN.md §11):
 
   predict  rank every candidate :class:`TuneConfig` with the analytic
-           roofline terms (``analysis.roofline`` hardware constants +
+           roofline terms (the device row of ``repro.platform`` +
            ``analysis.memmodel.spmm_hbm_traffic`` on the candidate's
            OWN packed workspace) plus a per-grid-step launch overhead —
            the term CGCM merging shrinks.  Host-only, no compilation.
@@ -37,7 +37,7 @@ from .csr import CSRMatrix
 from .jit_cache import GLOBAL_CACHE, JitCache, mesh_fingerprint
 from .plan import build_workspace
 from ..analysis.memmodel import spmm_hbm_traffic
-from ..analysis.roofline import HBM_BW, PEAK_FLOPS
+from ..platform import current_spec, resident_fits
 
 # amortized per-grid-step launch/descriptor overhead (s).  The absolute
 # value only has to be the right order of magnitude: it breaks ties
@@ -97,22 +97,30 @@ def default_candidates(*, bm: int = 8, bk: int = 8,
 
 
 def predict_seconds(a: CSRMatrix, d: int, cfg: TuneConfig, *,
-                    mixed: bool = False) -> float:
+                    mixed: bool = False, native: bool = False) -> float:
     """Analytic forward-time estimate for one candidate: the roofline
     max of compute and HBM terms on the candidate's own packed
-    workspace, plus the per-trip launch overhead.  Host-only."""
+    workspace, plus the per-trip launch overhead.  Host-only.  With
+    ``native`` (a compiled, not interpreted, run) a resident candidate
+    whose buffers exceed the chip's fast memories predicts ``inf``:
+    it is not offered."""
     ws = build_workspace(
         a.row_ptr, a.col_indices, a.shape, d, strategy=cfg.strategy,
         row_block=cfg.bm, mixed=mixed, bk=cfg.bk, mxu_gain=cfg.mxu_gain,
         merge_threshold=cfg.merge_threshold)
     d_pad = max(-(-d // 128) * 128, 128)
+    if native and cfg.staging == "resident" and not resident_fits(
+            ws.num_blocks, ws.gather_flat.size, ws.cols_flat.size,
+            4 * a.shape[1] * min(d_pad, 512)):
+        return float("inf")
     traffic = spmm_hbm_traffic(
         slots=int(ws.gather_flat.shape[0]),
         cols_entries=int(ws.cols_flat.shape[0]),
         padded_nnz=int(ws.gather_flat.shape[0]),
         ws_rows=ws.ws_rows, d_pad=d_pad)
-    compute_s = 2.0 * a.nnz * d / PEAK_FLOPS
-    memory_s = sum(traffic.values()) / HBM_BW
+    spec = current_spec()
+    compute_s = 2.0 * a.nnz * d / spec.peak_bf16_flops
+    memory_s = sum(traffic.values()) / spec.hbm_bytes_per_s
     return max(compute_s, memory_s) + ws.num_trips * TRIP_OVERHEAD_S
 
 
@@ -278,9 +286,14 @@ def autotune_spmm_with_result(
 
     def _search() -> TuneResult:
         t0 = time.perf_counter()
-        predicted = {c: predict_seconds(a, d, c, mixed=mixed)
+        predicted = {c: predict_seconds(a, d, c, mixed=mixed,
+                                        native=not interpret)
                      for c in candidates}
-        ranked = sorted(candidates, key=lambda c: predicted[c])
+        ranked = sorted((c for c in candidates
+                         if predicted[c] < float("inf")),
+                        key=lambda c: predicted[c])
+        if not ranked:
+            raise ValueError("no candidate fits the chip's fast memories")
         finalists = ranked[:max(int(top_k), 1)]
         vals = jnp.asarray(a.vals)
         rng = np.random.default_rng(0)

@@ -25,8 +25,7 @@ from __future__ import annotations
 import dataclasses
 from typing import List, Tuple
 
-LANE = 128          # TPU lane count (minor-most tile dim)
-SUBLANE = 8         # f32 sublane count
+from ..platform import LANE, SUBLANE
 VMEM_BYTES = 128 * 1024  # conservative per-core working-set budget for acc
 
 

@@ -232,6 +232,7 @@ def clear_global_cache():
     # the sharded dispatches memoize jitted shard_map closures at the
     # kernel layer; release those executables (and their mesh/device
     # handles) together with the artifacts that were built on them
-    from ..kernels import spmm_bcsr_fused, spmm_ell_fused
-    spmm_ell_fused._sharded_callable.cache_clear()
-    spmm_bcsr_fused._sharded_callable.cache_clear()
+    from ..kernels.attn_fused import _sharded_callable as attn_sharded
+    from ..kernels.spmm_bcsr_fused import _sharded_callable as spmm_sharded
+    spmm_sharded.cache_clear()
+    attn_sharded.cache_clear()

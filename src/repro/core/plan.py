@@ -54,6 +54,7 @@ from typing import List, Optional
 
 import numpy as np
 
+from ..platform import LANE, STAGE_TILE, stage_limits
 from .ccm import DTiling, plan_d_tiles
 
 STRATEGIES = ("row_split", "nnz_split", "merge_split")
@@ -63,13 +64,11 @@ STRATEGIES = ("row_split", "nnz_split", "merge_split")
 VPU_TAG = 0   # scalar-row ELL gather+FMA (the faithful CCM path)
 MXU_TAG = 1   # (bm x bk) block matmuls (the beyond-paper BCSR path)
 
-# DMA staging tile (DESIGN.md §7.7): the staged kernels prefetch each
-# block's slot/cols panel as ONE fixed-size async copy, so every
-# workspace's per-block maxima are rounded up to this granularity (the
-# TPU lane count — a 1-D DMA window that tiles VREG lanes exactly) and
-# the flat buffers are tail-padded so any window starting at a real
-# block offset stays in bounds.
-STAGE_TILE = 128
+# DMA staging (DESIGN.md §7.7): the kernels see the flat streams as
+# (rows, LANE) arrays, and every staged window starts at a STAGE_TILE-
+# aligned slot.  Windows are rounded up to it plus one tile of slack for
+# the aligned-down start, and the flat buffers are tail-padded so any
+# window starting at a real block offset stays in bounds.
 
 
 def _stage_tile_ceil(v: int) -> int:
@@ -351,12 +350,26 @@ class FusedEllWorkspace:
 
     CGCM merging pads the descriptor table to a multiple of
     ``merge_width`` with inert blocks (``blk_L == 0`` — zero trips,
-    ``blk_off``/``blk_coff`` at the stream end, zero span) so the grid
-    is exactly ``num_blocks // merge_width`` steps; the descriptor
-    table itself is the merged trip's per-row segment table (each
-    member keeps its own ``off``/``L``, so every row still reduces its
-    lanes separately in-register and the output is bit-identical to
-    the unmerged plan).
+    zero span) so the grid is exactly ``num_blocks // merge_width``
+    steps; the descriptor table itself is the merged trip's per-row
+    segment table (each member keeps its own ``off``/``L``, so every
+    row still reduces its lanes separately in-register and the output
+    is bit-identical to the unmerged plan).  A merged trip never mixes
+    VPU and MXU members and never stages more than the platform's
+    window (:func:`~repro.platform.stage_limits`): the packer
+    closes a trip early with inert blocks instead.
+
+    Chip-sized fast memories (DESIGN.md §7.7): a block whose panel is
+    wider than the window is split into consecutive *pieces*, each its
+    own trip.  ``blk_cont[t] == 1`` marks a trip that continues the
+    previous trip's rows: the kernel starts its accumulator from the
+    previous trip's instead of zeros, so the per-row accumulation order
+    is the unsplit one and the result is bit-identical.  ``inv_perm``
+    names the last piece's rows.
+
+    MXU block-rows store each ``(bm, bk)`` value panel lane-padded to
+    ``(bm, LANE)`` at a :data:`STAGE_TILE`-aligned offset, so the MXU
+    trip reads it as one aligned vector tile (``bk <= LANE``).
     """
     cols_flat: np.ndarray    # (Sc,) int32 — VPU: X row per slot;
                              #               MXU: block-column per step
@@ -387,6 +400,7 @@ class FusedEllWorkspace:
     # DESIGN.md §15); -1 means unknown (hand-built workspaces), and the
     # gather-bounds invariant is then skipped rather than guessed.
     nnz: int = -1
+    blk_cont: Optional[np.ndarray] = None  # (B//W,) int32 piece trips
 
     def __post_init__(self):
         # pure-VPU packings (the pre-mixed layout): every block is VPU
@@ -395,6 +409,8 @@ class FusedEllWorkspace:
             self.blk_tag = np.zeros_like(self.blk_L)
         if self.blk_coff is None:
             self.blk_coff = self.blk_off.copy()
+        if self.blk_cont is None:
+            self.blk_cont = np.zeros(self.num_trips, np.int32)
 
     @property
     def num_blocks(self) -> int:
@@ -421,8 +437,9 @@ def build_fused_workspace(plan, *, merge_width: int = 1
     :class:`MixedPlan`, whose MXU block-rows join the same descriptor
     stream with ``MXU_TAG`` so the whole mixed plan still lowers as ONE
     ``pallas_call``.  ``merge_width`` is the CGCM width from the merge
-    stage (:func:`choose_merge_width`); 1 reproduces the pre-CGCM
-    layout byte-for-byte.
+    stage (:func:`choose_merge_width`).  Every trip's staged panel is
+    bounded by the platform's window
+    (:func:`~repro.platform.stage_limits`).
     """
     if isinstance(plan, MixedPlan):
         return _pack_workspace(plan, mixed_kernel=True,
@@ -487,7 +504,8 @@ SPARSE_ATTN_MIXED_EINSUM = dataclasses.replace(
     SPARSE_ATTN_EINSUM, mixed=True)
 
 
-def workspace_row_map(inv_perm, ws_rows: int) -> np.ndarray:
+def workspace_row_map(inv_perm, ws_rows: int, cont=None,
+                      trip_rows: int = 0) -> np.ndarray:
     """Forward permutation for row-indexed operands (DESIGN.md §13).
 
     ``inv_perm`` maps output row ``i`` to its workspace slot; this is
@@ -497,11 +515,19 @@ def workspace_row_map(inv_perm, ws_rows: int) -> np.ndarray:
     sentinel gathers zeros.  With it, an operand indexed by output row
     (attention's Q) is staged into workspace order by ONE host-free
     gather, the mirror of the ``y_ws[inv_perm]`` output gather.
+
+    A split block's piece trips (``cont``, per trip of ``trip_rows``
+    rows) compute the same output rows as the last piece, which
+    ``inv_perm`` names, so they get its row map too.
     """
     inv = np.asarray(inv_perm, dtype=np.int64)
     m = int(inv.shape[0])
     row_map = np.full(int(ws_rows), m, dtype=np.int64)
     row_map[inv] = np.arange(m, dtype=np.int64)
+    if cont is not None and trip_rows:
+        trips = row_map.reshape(-1, trip_rows)
+        for t in np.flatnonzero(np.asarray(cont))[::-1]:
+            trips[t - 1] = trips[t]
     return row_map.astype(np.int32)
 
 
@@ -510,8 +536,11 @@ def sharded_workspace_row_maps(sw: "ShardedFusedWorkspace") -> np.ndarray:
 
     The sharded workspace's ``inv_perm`` is global over the flattened
     ``(C * ws_rows)`` workspace, so one flat map reshapes into the
-    per-chip tables ``shard_map`` feeds each chip."""
-    flat = workspace_row_map(sw.inv_perm, sw.n_chips * sw.ws_rows)
+    per-chip tables ``shard_map`` feeds each chip (a chip's first trip
+    never continues, so piece chains never cross chips)."""
+    flat = workspace_row_map(sw.inv_perm, sw.n_chips * sw.ws_rows,
+                             sw.blk_cont.reshape(-1),
+                             sw.merge_width * sw.row_block)
     return flat.reshape(sw.n_chips, sw.ws_rows)
 
 
@@ -761,142 +790,182 @@ def _pack_workspace(plan: MixedPlan, *, mixed_kernel: bool,
     arrive as degenerate mixed plans, see ``build_fused_workspace``).
 
     VPU blocks first (plan order, gather remapped from sub-nnz to global
-    nnz ids), then the MXU block-rows.  Column and slot streams advance
-    independently (see :class:`FusedEllWorkspace`).  ``mixed_kernel``
-    marks workspaces destined for ``spmm_bcsr_fused`` (identity remap
-    skipped only when False, and the slot-stream floor applied only
-    when True — the pure ELL kernel needs neither).
+    nnz ids), then the MXU block-rows, each starting at a
+    :data:`STAGE_TILE`-aligned slot with lane-padded panels.  Column and
+    slot streams advance independently (see :class:`FusedEllWorkspace`).
+    ``mixed_kernel=False`` marks the degenerate wrap, whose gather ids
+    are already global.
 
-    ``merge_width == W > 1`` (CGCM, DESIGN.md §7.9) pads the descriptor
-    table to a multiple of ``W`` with inert zero-trip blocks and emits
-    PER-MERGED-TRIP spans (each the sum of its ``W`` members' extents —
-    both streams are contiguous across consecutive descriptors, so a
-    merged trip is still one contiguous DMA window).
+    Blocks whose panel exceeds the platform's staging window
+    (:func:`~repro.platform.stage_limits`) are split into pieces
+    (``blk_cont``).  Trips then form greedily: up to ``merge_width``
+    consecutive descriptors of one tag whose summed extents fit the
+    window (CGCM, DESIGN.md §7.9); a piece trip holds the piece alone.
+    Short trips are filled with inert zero-trip blocks, so per-trip
+    spans are the sum of the members' extents and one contiguous DMA
+    window covers each trip.
     """
     t_pack0 = time.perf_counter()
     mw = max(int(merge_width), 1)
-    bm = plan.row_block
-    nnz = plan.nnz
+    bm, bk, nnz = plan.row_block, plan.bk, plan.nnz
+    if bk > LANE:
+        raise ValueError(f"bk={bk} exceeds the lane width {LANE}")
+    W = stage_limits().window
+    panel = bm * LANE                 # slots of one lane-padded MXU panel
     sub_nnz = int(plan.vpu_nnz_map.shape[0])
     cols_parts: List[np.ndarray] = []
     gather_parts: List[np.ndarray] = []
-    tags: List[int] = []
-    offs: List[int] = []
-    coffs: List[int] = []
-    Ls: List[int] = []
-    spans: List[int] = []
-    cspans: List[int] = []
-    inv_perm = np.zeros(plan.m, dtype=np.int32)
-    ws_row = 0
-    slot = 0
-    cpos = 0
+    # per real descriptor, in stream order
+    tags, Ls, offs, coffs, spans, cspans, pieces, npieces = (
+        [] for _ in range(8))
+    row_src = []          # (first descriptor, pieces per block, out rows)
+    slot = cpos = n_desc = 0
+
+    def emit(tag, piece_L, nblk, slots_per_step, cols_per_step):
+        """Descriptors of ``nblk`` consecutive blocks, each split into
+        ``piece_L.size`` pieces of ``piece_L`` loop steps."""
+        nonlocal slot, cpos, n_desc
+        P = piece_L.size
+        L = np.tile(piece_L, nblk)
+        span, cspan = slots_per_step * L, cols_per_step * L
+        tags.append(np.full(L.size, tag, np.int64))
+        Ls.append(L)
+        spans.append(span)
+        cspans.append(cspan)
+        offs.append(slot + np.cumsum(span) - span)
+        coffs.append(cpos + np.cumsum(cspan) - cspan)
+        pieces.append(np.tile(np.arange(P), nblk))
+        npieces.append(np.full(L.size, P))
+        slot += int(span.sum())
+        cpos += int(cspan.sum())
+        n_desc += L.size
+
     for seg in plan.vpu.segments:
         Lp = max(seg.L, 1)
-        cols_parts.append(seg.cols_pad.reshape(-1))
-        # sub-nnz ids -> global nnz ids; the sub sentinel becomes global
-        g = seg.gather_idx.reshape(-1)
-        if not mixed_kernel:
-            # degenerate wrap: the nnz map is the identity by
-            # construction, so the plan's gather ids ARE global
-            gather_parts.append(g)
-        elif sub_nnz == 0:        # all-empty VPU rows: pure sentinel
-            gather_parts.append(np.full(g.shape, nnz, np.int64))
-        else:
-            safe = np.minimum(g, sub_nnz - 1)
-            gather_parts.append(
-                np.where(g < sub_nnz, plan.vpu_nnz_map[safe], nnz))
         nblk = seg.R_pad // bm
-        for b in range(nblk):
-            tags.append(VPU_TAG)
-            offs.append(slot + b * bm * Lp)
-            coffs.append(cpos + b * bm * Lp)
-            Ls.append(Lp)
-            spans.append(bm * Lp)
-            cspans.append(bm * Lp)
-        inv_perm[plan.vpu_rows[seg.row_ids]] = (
-            ws_row + np.arange(seg.R, dtype=np.int32))
-        ws_row += seg.R_pad
-        slot += seg.R_pad * Lp
-        cpos += seg.R_pad * Lp
+        g = seg.gather_idx
+        if mixed_kernel and sub_nnz == 0:   # all-empty VPU rows
+            g = np.full(g.shape, nnz, np.int64)
+        elif mixed_kernel:                 # sub-nnz ids -> global ids
+            g = np.where(g < sub_nnz,
+                         plan.vpu_nnz_map[np.minimum(g, sub_nnz - 1)], nnz)
+        CL = max(min(Lp, W // bm), 1)
+        piece_L = np.minimum(CL, Lp - CL * np.arange(-(-Lp // CL)))
+        if piece_L.size == 1:
+            cols_parts.append(seg.cols_pad.reshape(-1))
+            gather_parts.append(g.reshape(-1))
+        else:                              # each piece its own (bm, CL) panel
+            c3 = seg.cols_pad.reshape(nblk, bm, Lp)
+            g3 = g.reshape(nblk, bm, Lp)
+            for b in range(nblk):
+                for lo in range(0, Lp, CL):
+                    cols_parts.append(c3[b, :, lo:lo + CL].reshape(-1))
+                    gather_parts.append(g3[b, :, lo:lo + CL].reshape(-1))
+        row_src.append((n_desc, piece_L.size, plan.vpu_rows[seg.row_ids]))
+        emit(VPU_TAG, piece_L, nblk, bm, bm)
+    Kp = max(W // panel, 1)
     for blk in plan.mxu_rows:
-        tags.append(MXU_TAG)
-        offs.append(slot)
-        coffs.append(cpos)
-        Ls.append(blk.K)
-        spans.append(blk.K * bm * plan.bk)
-        cspans.append(blk.K)
+        pad = -slot % STAGE_TILE           # aligned panel tiles
+        if pad:
+            gather_parts.append(np.full(pad, nnz, np.int64))
+            slot += pad
+        gp = np.full((blk.K, bm, LANE), nnz, np.int64)
+        gp[:, :, :bk] = blk.gather
         cols_parts.append(blk.bcols)
-        gather_parts.append(blk.gather.reshape(-1))
-        inv_perm[blk.row0:blk.row0 + blk.nrows] = (
-            ws_row + np.arange(blk.nrows, dtype=np.int32))
-        ws_row += bm
-        slot += blk.K * bm * plan.bk
-        cpos += blk.K
+        gather_parts.append(gp.reshape(-1))
+        row_src.append((n_desc, -(-blk.K // Kp),
+                        np.arange(blk.row0, blk.row0 + blk.nrows)))
+        emit(MXU_TAG, np.minimum(Kp, blk.K - Kp * np.arange(-(-blk.K // Kp))),
+             1, panel, 1)
 
     assert slot < (1 << 31), ("mixed workspace exceeds int32 slot space",
                               slot)
+    cat = (lambda parts: np.concatenate(parts) if parts
+           else np.zeros(0, np.int64))
+    tag, L, off, coff = cat(tags), cat(Ls), cat(offs), cat(coffs)
+    span, cspan = cat(spans), cat(cspans)
+    piece, npiece = cat(pieces), cat(npieces)
 
-    # CGCM (DESIGN.md §7.9): pad the descriptor table to a multiple of
-    # the merge width with inert blocks — zero trips, zero span, offsets
-    # at the stream end — so the grid is exactly num_blocks // W merged
-    # steps and a partially-filled final trip reads nothing extra.  The
-    # pad blocks cost bm zero output rows each (inv_perm never points at
-    # them), bounded by (W - 1) * bm rows total.
-    while len(Ls) % mw:
-        tags.append(VPU_TAG)
-        offs.append(slot)
-        coffs.append(cpos)
-        Ls.append(0)
-        spans.append(0)
-        cspans.append(0)
-        ws_row += bm
+    # trip formation: the final table holds a descriptor index per
+    # member, or -(tag + 1) for an inert pad
+    if mw == 1:
+        final = np.arange(n_desc)
+        cont = (piece > 0).astype(np.int32)
+    else:
+        final, cont = [], []
+        fill = tspan = tcspan = 0
+        ttag = VPU_TAG
 
-    # fixed-size DMA windows for the staged kernels (DESIGN.md §7.7):
-    # every merged trip's panel copy is [off, off + max_span) whatever
-    # its own span, so the flat streams get a max-window tail of inert
-    # sentinels (gather -> the zero slot, cols -> row/block-column 0).
-    # Per-trip spans are the sum over the trip's W members (contiguous
-    # streams make that the exact contiguous footprint); W == 1 keeps
-    # the historical per-block arrays byte-for-byte.
-    trip_spans = np.asarray(spans, np.int64).reshape(-1, mw).sum(axis=1)
-    trip_cspans = np.asarray(cspans, np.int64).reshape(-1, mw).sum(axis=1)
-    max_span = _stage_tile_ceil(trip_spans.max(initial=0))
-    max_cspan = _stage_tile_ceil(trip_cspans.max(initial=0))
+        def close():
+            nonlocal fill
+            if fill:
+                final.extend([-(ttag + 1)] * (mw - fill))
+                fill = 0
 
-    def cat(parts, dtype, floor, min_size, tail):
-        out = (np.concatenate(parts).astype(dtype) if parts
-               else np.zeros(0, dtype))
-        if out.size < min_size and tags and mixed_kernel:
-            # the mixed kernel traces BOTH units (lax.cond), so the slot
-            # stream must admit the MXU branch's (bm*bk,) slice even on
-            # tiny or pure-VPU plans; inert sentinel entries pad it up
-            # (zero-length operands don't block-spec either)
-            pad = np.full(min_size - out.size, floor, dtype)
-            out = np.concatenate([out, pad])
-        if tail:
-            out = np.concatenate([out, np.full(tail, floor, dtype)])
-        return out
+        for i, (t, s, c, p, n_p) in enumerate(zip(
+                tag.tolist(), span.tolist(), cspan.tolist(),
+                piece.tolist(), npiece.tolist())):
+            if fill and (n_p > 1 or t != ttag or tspan + s > W
+                         or tcspan + c > W):
+                close()
+            if fill == 0:
+                cont.append(int(p > 0))
+                ttag, tspan, tcspan = t, 0, 0
+            final.append(i)
+            fill, tspan, tcspan = fill + 1, tspan + s, tcspan + c
+            if fill == mw or n_p > 1:
+                close()
+        close()
+        final = np.asarray(final, np.int64)
+        cont = np.asarray(cont, np.int32)
+    real = final >= 0
+    src = np.where(real, final, 0)
+    # a pad sits where the previous real member ends (never first in
+    # its trip), so its zero-extent window is in bounds
+    prev = np.maximum.accumulate(np.where(real, np.arange(final.size), 0))
+    psrc = src[prev]
+    blk_tag = np.where(real, tag[src], -final - 1)
+    blk_L = np.where(real, L[src], 0)
+    blk_off = np.where(real, off[src], off[psrc] + span[psrc])
+    blk_coff = np.where(real, coff[src], coff[psrc] + cspan[psrc])
 
+    pos = np.zeros(n_desc, np.int64)
+    pos[final[real]] = np.nonzero(real)[0]
+    inv_perm = np.zeros(plan.m, dtype=np.int32)
+    for first, P, rows in row_src:
+        r = np.arange(rows.size)
+        last = first + (r // bm) * P + P - 1
+        inv_perm[rows] = pos[last] * bm + r % bm
+
+    trip_spans = np.where(real, span[src], 0).reshape(-1, mw).sum(axis=1)
+    trip_cspans = np.where(real, cspan[src], 0).reshape(-1, mw).sum(axis=1)
+
+    def window_of(v):
+        top = int(v.max(initial=0))
+        return _stage_tile_ceil(top) + STAGE_TILE if top else 0
+
+    max_span, max_cspan = window_of(trip_spans), window_of(trip_cspans)
     ws = FusedEllWorkspace(
-        cols_flat=cat(cols_parts, np.int32, 0, 1, max_cspan),
-        gather_flat=cat(gather_parts, np.int64, nnz, bm * plan.bk,
-                        max_span),
-        blk_off=np.asarray(offs, np.int32),
-        blk_L=np.asarray(Ls, np.int32),
+        cols_flat=np.concatenate(
+            [cat(cols_parts).astype(np.int32), np.zeros(max_cspan, np.int32)]),
+        gather_flat=np.concatenate(
+            [cat(gather_parts), np.full(max_span, nnz, np.int64)]),
+        blk_off=blk_off.astype(np.int32),
+        blk_L=blk_L.astype(np.int32),
         inv_perm=inv_perm,
-        ws_rows=ws_row,
+        ws_rows=final.size * bm,
         row_block=bm,
-        blk_tag=np.asarray(tags, np.int32),
-        blk_coff=np.asarray(coffs, np.int32),
-        bk=plan.bk,
+        blk_tag=blk_tag.astype(np.int32),
+        blk_coff=blk_coff.astype(np.int32),
+        bk=bk,
         blk_span=trip_spans.astype(np.int32),
         blk_cspan=trip_cspans.astype(np.int32),
         max_span=max_span,
         max_cspan=max_cspan,
         merge_width=mw,
         pack_seconds=time.perf_counter() - t_pack0,
-        nnz=nnz)
-    assert ws.ws_rows == ws.num_blocks * bm
+        nnz=nnz,
+        blk_cont=cont)
     assert ws.num_blocks % mw == 0
     return ws
 
@@ -1033,12 +1102,16 @@ class ShardedFusedWorkspace:
     # bounds cut at merged-trip boundaries
     merge_width: int = 1
     pack_seconds: float = 0.0  # summed host cost of the per-chip packs
+    blk_cont: Optional[np.ndarray] = None  # (C, B//W) int32 piece trips
 
     def __post_init__(self):
         if self.blk_tag is None:
             self.blk_tag = np.zeros_like(self.blk_L)
         if self.blk_coff is None:
             self.blk_coff = self.blk_off.copy()
+        if self.blk_cont is None:
+            self.blk_cont = np.zeros((self.n_chips, self.num_trips),
+                                     np.int32)
         if self.chip_span is None:
             self.chip_span = np.full(self.n_chips, self.max_span, np.int32)
         if self.chip_cspan is None:
@@ -1126,6 +1199,7 @@ class StackedFusedTables:
     member_cspan: np.ndarray  # (K,) int32 per-member staged cols window
     num_blocks: int          # common per-member block count B
     ws_rows: int             # per-member workspace rows B * row_block
+    blk_cont: np.ndarray     # (K, B // W) int32 piece-continuation trips
 
 
 def stack_fused_workspaces(members: List[FusedEllWorkspace], *,
@@ -1167,12 +1241,17 @@ def stack_fused_workspaces(members: List[FusedEllWorkspace], *,
     if uniform_windows:
         member_span[:] = member_span.max()
         member_cspan[:] = member_cspan.max()
-    S = max(r + int(s) for r, s in zip(real_s, member_span))
-    Sc = max(r + int(s) for r, s in zip(real_c, member_cspan))
+    # tile-aligned widths keep every member's MXU panels aligned after
+    # the request axis folds member r's base r*S into its offsets
+    S = _stage_tile_ceil(max(r + int(s) for r, s in zip(real_s,
+                                                         member_span)))
+    Sc = _stage_tile_ceil(max(r + int(s) for r, s in zip(real_c,
+                                                          member_cspan)))
     blk_off = np.zeros((K, B), np.int32)
     blk_L = np.zeros((K, B), np.int32)       # pad blocks: L == 0
     blk_tag = np.zeros((K, B), np.int32)
     blk_coff = np.zeros((K, B), np.int32)
+    blk_cont = np.zeros((K, B // max(merge_width, 1)), np.int32)
     cols_flat = np.zeros((K, Sc), np.int32)
     # pad -> the global 0.0 value sentinel
     gather_flat = np.full((K, S), global_nnz, np.int64)
@@ -1182,6 +1261,7 @@ def stack_fused_workspaces(members: List[FusedEllWorkspace], *,
         blk_L[k, :nb] = ws.blk_L
         blk_tag[k, :nb] = ws.blk_tag
         blk_coff[k, :nb] = ws.blk_coff
+        blk_cont[k, :ws.num_trips] = ws.blk_cont
         cols = ws.cols_flat[:real_c[k]]
         if cols_map is not None:
             cols = cols_map(k, ws, cols)
@@ -1195,7 +1275,7 @@ def stack_fused_workspaces(members: List[FusedEllWorkspace], *,
         blk_off=blk_off, blk_L=blk_L, blk_tag=blk_tag, blk_coff=blk_coff,
         cols_flat=cols_flat, gather_flat=gather_flat,
         member_span=member_span, member_cspan=member_cspan,
-        num_blocks=B, ws_rows=B * row_block)
+        num_blocks=B, ws_rows=B * row_block, blk_cont=blk_cont)
 
 
 def build_sharded_workspace(row_ptr: np.ndarray, col_indices: np.ndarray,
@@ -1322,7 +1402,8 @@ def build_sharded_workspace(row_ptr: np.ndarray, col_indices: np.ndarray,
         x_sharding=x_sharding, x_panels=x_panels,
         x_own_panels=own_panels, x_fetch=x_fetch, x_send=x_send,
         x_recv=x_recv, merge_width=merge_width,
-        pack_seconds=sum(ws.pack_seconds for ws in shards))
+        pack_seconds=sum(ws.pack_seconds for ws in shards),
+        blk_cont=st.blk_cont)
 
 
 def _x_fetch_tables(needs: List[np.ndarray], own_panels: int,
@@ -1408,6 +1489,7 @@ class BatchedFusedWorkspace:
     max_cspan: int           # uniform staged-DMA cols window
     merge_width: int         # common CGCM width across the batch
     pack_seconds: float = 0.0
+    blk_cont: Optional[np.ndarray] = None  # (R*B//W,) int32 piece trips
 
     @property
     def nnz(self) -> int:
@@ -1535,4 +1617,5 @@ def build_batched_workspace(structures, d: int, *,
         max_span=int(st.member_span.max(initial=0)),
         max_cspan=int(st.member_cspan.max(initial=0)),
         merge_width=mw,
-        pack_seconds=sum(ws.pack_seconds for ws in shards))
+        pack_seconds=sum(ws.pack_seconds for ws in shards),
+        blk_cont=st.blk_cont.reshape(-1))

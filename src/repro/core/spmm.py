@@ -53,6 +53,7 @@ from .plan import (SPARSE_ATTN_EINSUM, SPARSE_ATTN_MIXED_EINSUM,
 from ..analysis.verify import (PlanVerificationError, check_workspace,
                                resolve_validate)
 from ..kernels.ops import resolve_interpret, resolve_staging
+from ..platform import STAGE_TILE, resident_fits
 
 __all__ = [
     "BACKENDS", "FUSED_BACKENDS", "X_SHARDING_MODES",
@@ -77,6 +78,10 @@ FUSED_BACKENDS = ("pallas_ell", "pallas_bcsr")
 #               descriptor stream touches via the planner's exact-panel
 #               exchange — instance size scales with the mesh
 X_SHARDING_MODES = ("replicated", "rows")
+
+# nonzeros per step of the gradient's SDDMM: bounds its gathered
+# (chunk, d) operands to a few hundred MB at d <= 256
+_SDDMM_CHUNK = 1 << 18
 
 
 def _resolve_x_sharding_for(backend: str, x_sharding, interpret: bool,
@@ -213,6 +218,46 @@ class _FusedConsts:
     max_span: int = 0        # staged-DMA slot window (DESIGN.md §7.7)
     max_cspan: int = 0       # staged-DMA cols window
     merge_width: int = 1     # CGCM width (DESIGN.md §7.9)
+    cont: Optional[jax.Array] = None   # (B//W,) int32 piece trips
+
+
+def _require_resident_fit(staging: str, interpret: bool, ws,
+                          operand_bytes: int, context: str) -> None:
+    """The resident lowering is the interpret oracle and the small-
+    instance path: compiled for a chip, its buffers must fit the fast
+    memories (``repro.platform.resident_fits``)."""
+    if staging != "resident" or interpret:
+        return
+    slots, cols = ws.gather_flat.shape[-1], ws.cols_flat.shape[-1]
+    if not resident_fits(ws.num_blocks, slots, cols, operand_bytes):
+        raise ValueError(
+            f"{context}: staging='resident' keeps {slots} slots and "
+            f"{cols} column entries in SMEM and {operand_bytes} operand "
+            f"bytes in VMEM, more than this chip holds; use "
+            f"staging='dma' (the default on a TPU)")
+
+
+def _stream(a: np.ndarray, fill, sharding=None) -> jax.Array:
+    """Device copy of a flat stream (or a per-chip stack of them) padded
+    to whole tiles: the kernels view streams as (rows, LANE) arrays."""
+    pad = -a.shape[-1] % STAGE_TILE
+    a = np.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, pad)],
+               constant_values=fill)
+    return jax.device_put(a.astype(np.int32) if a.dtype == np.int64 else a,
+                          sharding)
+
+
+def _fused_consts(ws, nnz: int) -> "_FusedConsts":
+    """Device constants of a solo or request-batched workspace; ``nnz``
+    is the gather stream's zero-slot sentinel."""
+    return _FusedConsts(
+        blk_off=jnp.asarray(ws.blk_off), blk_L=jnp.asarray(ws.blk_L),
+        cols_flat=_stream(ws.cols_flat, 0),
+        gather_flat=_stream(ws.gather_flat, nnz),
+        inv_perm=jnp.asarray(ws.inv_perm), num_blocks=ws.num_blocks,
+        blk_tag=jnp.asarray(ws.blk_tag), blk_coff=jnp.asarray(ws.blk_coff),
+        max_span=ws.max_span, max_cspan=ws.max_cspan,
+        merge_width=ws.merge_width, cont=jnp.asarray(ws.blk_cont))
 
 
 @dataclasses.dataclass
@@ -245,6 +290,33 @@ class _ShardedConsts:
     x_send: Optional[jax.Array] = None    # (C, C, T2) int32 local panels
     x_recv: Optional[jax.Array] = None    # (C, T) int32 into (C*T2,)
     merge_width: int = 1     # CGCM width, global across chips (§7.9)
+    cont: Optional[jax.Array] = None      # (C, B//W) int32 piece trips
+
+
+def _sharded_consts(sw: ShardedFusedWorkspace, mesh: Mesh
+                    ) -> _ShardedConsts:
+    """Device constants of a chip-stacked workspace, each chip's rows
+    placed on that chip."""
+    from ..distributed.sharding import chip_row_sharding
+    on_chips = chip_row_sharding(mesh)
+
+    def put(a):
+        return None if a is None else jax.device_put(a, on_chips)
+
+    return _ShardedConsts(
+        blk_off=put(sw.blk_off), blk_L=put(sw.blk_L),
+        cols_flat=_stream(sw.cols_flat, 0, on_chips),
+        gather_flat=_stream(sw.gather_flat, sw.nnz, on_chips),
+        inv_perm=jnp.asarray(sw.inv_perm), ws_rows=sw.ws_rows,
+        num_blocks=sw.num_blocks, n_chips=sw.n_chips, mesh=mesh,
+        blk_tag=put(sw.blk_tag), blk_coff=put(sw.blk_coff),
+        max_span=sw.max_span, max_cspan=sw.max_cspan,
+        chip_span=tuple(int(s) for s in sw.chip_span),
+        chip_cspan=tuple(int(s) for s in sw.chip_cspan),
+        x_sharding=sw.x_sharding, x_panels=sw.x_panels,
+        x_own_panels=sw.x_own_panels, x_send=put(sw.x_send),
+        x_recv=put(sw.x_recv), merge_width=sw.merge_width,
+        cont=put(sw.blk_cont))
 
 
 class CompiledSpmm:
@@ -313,30 +385,10 @@ class CompiledSpmm:
             _verify_workspace_timed(
                 sw, level=self.validate, n_cols=a.shape[1],
                 context=f"compile_spmm[{self.backend}/sharded]")
-            self._sharded = _ShardedConsts(
-                blk_off=jnp.asarray(sw.blk_off),
-                blk_L=jnp.asarray(sw.blk_L),
-                cols_flat=jnp.asarray(sw.cols_flat),
-                gather_flat=jnp.asarray(sw.gather_flat),
-                inv_perm=jnp.asarray(sw.inv_perm),
-                ws_rows=sw.ws_rows,
-                num_blocks=sw.num_blocks,
-                n_chips=sw.n_chips,
-                mesh=self.mesh,
-                blk_tag=jnp.asarray(sw.blk_tag),
-                blk_coff=jnp.asarray(sw.blk_coff),
-                max_span=sw.max_span,
-                max_cspan=sw.max_cspan,
-                chip_span=tuple(int(s) for s in sw.chip_span),
-                chip_cspan=tuple(int(s) for s in sw.chip_cspan),
-                x_sharding=sw.x_sharding,
-                x_panels=sw.x_panels,
-                x_own_panels=sw.x_own_panels,
-                x_send=None if sw.x_send is None
-                else jnp.asarray(sw.x_send),
-                x_recv=None if sw.x_recv is None
-                else jnp.asarray(sw.x_recv),
-                merge_width=sw.merge_width)
+            _require_resident_fit(
+                self.staging, self.interpret, sw,
+                4 * self._x_rows_pad * self.d_tiling.dt, "compile_spmm")
+            self._sharded = _sharded_consts(sw, self.mesh)
             _record_build(
                 sum(p.plan_seconds for p in sw.shard_plans),
                 sw.pack_seconds)
@@ -362,18 +414,10 @@ class CompiledSpmm:
             _verify_workspace_timed(
                 ws, level=self.validate, n_cols=a.shape[1],
                 context=f"compile_spmm[{self.backend}]")
-            self._fused = _FusedConsts(
-                blk_off=jnp.asarray(ws.blk_off),
-                blk_L=jnp.asarray(ws.blk_L),
-                cols_flat=jnp.asarray(ws.cols_flat),
-                gather_flat=jnp.asarray(ws.gather_flat),
-                inv_perm=jnp.asarray(ws.inv_perm),
-                num_blocks=ws.num_blocks,
-                blk_tag=jnp.asarray(ws.blk_tag),
-                blk_coff=jnp.asarray(ws.blk_coff),
-                max_span=ws.max_span,
-                max_cspan=ws.max_cspan,
-                merge_width=ws.merge_width)
+            _require_resident_fit(
+                self.staging, self.interpret, ws,
+                4 * self._x_rows_pad * self.d_tiling.dt, "compile_spmm")
+            self._fused = _fused_consts(ws, a.nnz)
             _record_build(
                 (self.mixed_plan or self.plan).plan_seconds,
                 ws.pack_seconds)
@@ -459,7 +503,8 @@ class CompiledSpmm:
             prod = (vals[:, None].astype(jnp.float32)
                     * x[self._cols].astype(jnp.float32))
             return jax.ops.segment_sum(prod, self._expanded_rows(),
-                                       num_segments=m)
+                                       num_segments=m,
+                                       indices_are_sorted=True)
         vals_ext = jnp.concatenate(
             [vals.astype(jnp.float32), jnp.zeros((1,), jnp.float32)])
         x_pad = ccm.pad_cols(x, self.d_tiling.d_pad)
@@ -476,7 +521,7 @@ class CompiledSpmm:
                         if sw.x_sharding == "rows" else x_pad)
                 y_ws = spmm_ell_fused_sharded_op(
                     sw.blk_off, sw.blk_L, sw.cols_flat, vals_flat, xarg,
-                    mesh=sw.mesh, bm=self.bm, mw=sw.merge_width,
+                    sw.cont, mesh=sw.mesh, bm=self.bm, mw=sw.merge_width,
                     interpret=self.interpret,
                     staging=self.staging, span=sw.chip_span,
                     cspan=sw.chip_cspan, x_sharding=sw.x_sharding,
@@ -493,9 +538,9 @@ class CompiledSpmm:
             vals_flat = vals_ext[fw.gather_flat]
             y_ws = spmm_ell_fused_op(
                 fw.blk_off, fw.blk_L, fw.cols_flat, vals_flat, x_pad,
-                bm=self.bm, mw=fw.merge_width, interpret=self.interpret,
-                staging=self.staging, span=fw.max_span,
-                cspan=fw.max_cspan)
+                fw.cont, bm=self.bm, mw=fw.merge_width,
+                interpret=self.interpret, staging=self.staging,
+                span=fw.max_span, cspan=fw.max_cspan)
             # single inverse-permutation gather replaces N scatters
             return y_ws[fw.inv_perm, :d]
         if backend == "pallas_bcsr":
@@ -515,7 +560,7 @@ class CompiledSpmm:
                         if sw.x_sharding == "rows" else x_pad)
                 y_ws = spmm_bcsr_fused_sharded_op(
                     sw.blk_tag, sw.blk_off, sw.blk_coff, sw.blk_L,
-                    sw.cols_flat, vals_flat, xarg, mesh=sw.mesh,
+                    sw.cols_flat, vals_flat, xarg, sw.cont, mesh=sw.mesh,
                     bm=self.bm, bk=self.bk, mw=sw.merge_width,
                     interpret=self.interpret,
                     staging=self.staging, span=sw.chip_span,
@@ -530,8 +575,8 @@ class CompiledSpmm:
             vals_flat = vals_ext[fw.gather_flat]
             y_ws = spmm_bcsr_fused_op(
                 fw.blk_tag, fw.blk_off, fw.blk_coff, fw.blk_L,
-                fw.cols_flat, vals_flat, x_pad, bm=self.bm, bk=self.bk,
-                mw=fw.merge_width, interpret=self.interpret,
+                fw.cols_flat, vals_flat, x_pad, fw.cont, bm=self.bm,
+                bk=self.bk, mw=fw.merge_width, interpret=self.interpret,
                 staging=self.staging, span=fw.max_span,
                 cspan=fw.max_cspan)
             return y_ws[fw.inv_perm, :d]
@@ -539,9 +584,23 @@ class CompiledSpmm:
 
     # -- gradients ----------------------------------------------------------
     def _sddmm(self, dy, x):
-        cols = jnp.asarray(self._col_indices)
-        return jnp.sum(dy[self._expanded_rows()].astype(jnp.float32)
-                       * x[cols].astype(jnp.float32), axis=-1)
+        """dvals[e] = <dy[row_e], x[col_e]>, in chunks of
+        ``_SDDMM_CHUNK`` nonzeros: the gathered (nnz, d) operands of a
+        graph at scale would not fit the chip's HBM at once."""
+        rows, cols = self._expanded_rows(), jnp.asarray(self._col_indices)
+        nnz = cols.shape[0]
+        n_chunks = max(-(-nnz // _SDDMM_CHUNK), 1)
+        size = -(-nnz // n_chunks)
+        pad = n_chunks * size - nnz
+
+        def chunk(rc):
+            r, c = rc
+            return jnp.sum(dy[r].astype(jnp.float32)
+                           * x[c].astype(jnp.float32), axis=-1)
+
+        out = jax.lax.map(chunk, (jnp.pad(rows, (0, pad)).reshape(-1, size),
+                                  jnp.pad(cols, (0, pad)).reshape(-1, size)))
+        return out.reshape(-1)[:nnz]
 
     def _transpose_apply(self, vals, dy):
         if self._transpose is None:
@@ -721,18 +780,11 @@ class CompiledBatchedSpmm:
         _verify_workspace_timed(
             bw, level=self.validate,
             context=f"compile_batched_spmm[{self.backend}]")
-        self._consts = _FusedConsts(
-            blk_off=jnp.asarray(bw.blk_off),
-            blk_L=jnp.asarray(bw.blk_L),
-            cols_flat=jnp.asarray(bw.cols_flat),
-            gather_flat=jnp.asarray(bw.gather_flat),
-            inv_perm=jnp.asarray(bw.inv_perm),
-            num_blocks=bw.num_blocks,
-            blk_tag=jnp.asarray(bw.blk_tag),
-            blk_coff=jnp.asarray(bw.blk_coff),
-            max_span=bw.max_span,
-            max_cspan=bw.max_cspan,
-            merge_width=bw.merge_width)
+        _require_resident_fit(
+            self.staging, self.interpret, bw,
+            4 * bw.n_requests * bw.x_rows_pad * self.d_tiling.dt,
+            "compile_batched_spmm")
+        self._consts = _fused_consts(bw, bw.nnz)
         _record_build(sum(p.plan_seconds for p in bw.request_plans),
                       bw.pack_seconds)
         self._row_splits = [int(v) for v in bw.row_splits]
@@ -768,15 +820,15 @@ class CompiledBatchedSpmm:
             from ..kernels.ops import spmm_ell_fused_op
             y_ws = spmm_ell_fused_op(
                 fw.blk_off, fw.blk_L, fw.cols_flat, vals_flat, x_pad,
-                bm=self.bm, mw=fw.merge_width, interpret=self.interpret,
-                staging=self.staging, span=fw.max_span,
-                cspan=fw.max_cspan)
+                fw.cont, bm=self.bm, mw=fw.merge_width,
+                interpret=self.interpret, staging=self.staging,
+                span=fw.max_span, cspan=fw.max_cspan)
         else:
             from ..kernels.ops import spmm_bcsr_fused_op
             y_ws = spmm_bcsr_fused_op(
                 fw.blk_tag, fw.blk_off, fw.blk_coff, fw.blk_L,
-                fw.cols_flat, vals_flat, x_pad, bm=self.bm, bk=self.bk,
-                mw=fw.merge_width, interpret=self.interpret,
+                fw.cols_flat, vals_flat, x_pad, fw.cont, bm=self.bm,
+                bk=self.bk, mw=fw.merge_width, interpret=self.interpret,
                 staging=self.staging, span=fw.max_span,
                 cspan=fw.max_cspan)
         # one inverse-permutation gather un-interleaves ALL requests
@@ -965,6 +1017,9 @@ class CompiledSparseAttention:
                 bk=bk, mxu_gain=mxu_gain, x_sharding="replicated",
                 merge_threshold=self.merge_threshold)
             self.sharded_workspace = sw
+            _require_resident_fit(
+                self.staging, self.interpret, sw, self._kv_bytes(),
+                "compile_sparse_attention")
             row_maps = sharded_workspace_row_maps(sw)
             _verify_workspace_timed(
                 sw, level=self.validate, n_cols=a.shape[1],
@@ -974,23 +1029,7 @@ class CompiledSparseAttention:
                 vals=np.asarray(a.vals), row_map=row_maps,
                 context=f"compile_sparse_attention[{self.backend}"
                         f"/sharded]")
-            self._sharded = _ShardedConsts(
-                blk_off=jnp.asarray(sw.blk_off),
-                blk_L=jnp.asarray(sw.blk_L),
-                cols_flat=jnp.asarray(sw.cols_flat),
-                gather_flat=jnp.asarray(sw.gather_flat),
-                inv_perm=jnp.asarray(sw.inv_perm),
-                ws_rows=sw.ws_rows,
-                num_blocks=sw.num_blocks,
-                n_chips=sw.n_chips,
-                mesh=self.mesh,
-                blk_tag=jnp.asarray(sw.blk_tag),
-                blk_coff=jnp.asarray(sw.blk_coff),
-                max_span=sw.max_span,
-                max_cspan=sw.max_cspan,
-                chip_span=tuple(int(s) for s in sw.chip_span),
-                chip_cspan=tuple(int(s) for s in sw.chip_cspan),
-                merge_width=sw.merge_width)
+            self._sharded = _sharded_consts(sw, self.mesh)
             self._row_map = jnp.asarray(row_maps)
             _record_build(
                 sum(p.plan_seconds for p in sw.shard_plans),
@@ -1005,26 +1044,20 @@ class CompiledSparseAttention:
                 mxu_gain=mxu_gain, merge_threshold=self.merge_threshold,
                 fingerprint=a.fingerprint)
             self.workspace = ws
+            _require_resident_fit(
+                self.staging, self.interpret, ws, self._kv_bytes(),
+                "compile_sparse_attention")
             # verify the SAME forward map the Q gather will ship (the
             # perm_roundtrip invariant guards the staged constant, not
             # a re-derivation)
-            row_map = workspace_row_map(ws.inv_perm, ws.ws_rows)
+            row_map = workspace_row_map(
+                ws.inv_perm, ws.ws_rows, ws.blk_cont,
+                ws.merge_width * ws.row_block)
             _verify_workspace_timed(
                 ws, level=self.validate, n_cols=a.shape[1], spec=spec,
                 vals=np.asarray(a.vals), row_map=row_map,
                 context=f"compile_sparse_attention[{self.backend}]")
-            self._fused = _FusedConsts(
-                blk_off=jnp.asarray(ws.blk_off),
-                blk_L=jnp.asarray(ws.blk_L),
-                cols_flat=jnp.asarray(ws.cols_flat),
-                gather_flat=jnp.asarray(ws.gather_flat),
-                inv_perm=jnp.asarray(ws.inv_perm),
-                num_blocks=ws.num_blocks,
-                blk_tag=jnp.asarray(ws.blk_tag),
-                blk_coff=jnp.asarray(ws.blk_coff),
-                max_span=ws.max_span,
-                max_cspan=ws.max_cspan,
-                merge_width=ws.merge_width)
+            self._fused = _fused_consts(ws, a.nnz)
             self._row_map = jnp.asarray(row_map)
             _record_build(0.0, ws.pack_seconds)
         elif self.backend != "ref":
@@ -1048,6 +1081,10 @@ class CompiledSparseAttention:
 
         _apply.defvjp(_apply_fwd, _apply_bwd)
         self._apply = _apply
+
+    def _kv_bytes(self) -> int:
+        """VMEM bytes of the resident K and V panels."""
+        return 4 * self._kv_rows_pad * (self._dh_pad + self.d_tiling.dt)
 
     def _expanded_rows(self) -> np.ndarray:
         # host numpy on purpose: _ref_forward may first run inside a
@@ -1120,7 +1157,7 @@ class CompiledSparseAttention:
             q_ws = q_ext[self._row_map]       # (C, ws_rows, dh_pad)
             y_ws = attn_fused_sharded_op(
                 sw.blk_tag, sw.blk_off, sw.blk_coff, sw.blk_L,
-                sw.cols_flat, vals_flat, q_ws, k_pad, v_pad,
+                sw.cols_flat, vals_flat, q_ws, k_pad, v_pad, sw.cont,
                 mesh=sw.mesh, bm=self.bm, bk=self.bk,
                 mw=sw.merge_width, interpret=self.interpret,
                 staging=self.staging, span=sw.chip_span,
@@ -1135,7 +1172,8 @@ class CompiledSparseAttention:
         q_ws = q_ext[self._row_map]           # (ws_rows, dh_pad)
         y_ws = attn_fused_op(
             fw.blk_tag, fw.blk_off, fw.blk_coff, fw.blk_L,
-            fw.cols_flat, vals_flat, q_ws, k_pad, v_pad, bm=self.bm,
+            fw.cols_flat, vals_flat, q_ws, k_pad, v_pad, fw.cont,
+            bm=self.bm,
             bk=self.bk, mw=fw.merge_width, interpret=self.interpret,
             staging=self.staging, span=fw.max_span, cspan=fw.max_cspan)
         return y_ws[fw.inv_perm, :self.dv]

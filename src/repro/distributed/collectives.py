@@ -20,7 +20,6 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -44,8 +43,8 @@ def compressed_psum(x: jax.Array, mesh: Mesh, axis: str = "data"):
         return jnp.sum(deq, axis=0)
 
     specs = P(*([None] * x.ndim))
-    return shard_map(local, mesh=mesh, in_specs=specs,
-                     out_specs=specs, check_rep=False)(x)
+    return jax.shard_map(local, mesh=mesh, in_specs=specs,
+                         out_specs=specs, check_vma=False)(x)
 
 
 def exact_panel_exchange(own: jax.Array, send_tbl: jax.Array,

@@ -1,37 +1,25 @@
 # Pallas TPU kernels for the paper's compute hot-spots, each with a
-# pure-jnp oracle in ref.py (validated via interpret=True on CPU):
-#   spmm_ell_fused          — the VPU serving hot path: one dispatch for
-#                             the whole multi-segment plan via a per-row-
-#                             block descriptor table (SMEM scalar prefetch)
-#   spmm_ell_fused_staged   — the same dispatch with double-buffered
-#                             per-block slot/cols panel DMA instead of a
-#                             resident flat VMEM buffer (staging="dma",
-#                             DESIGN.md §7.7); bit-identical output
-#   spmm_ell_fused_sharded  — the same kernel per chip under shard_map:
-#                             n_chips dispatches per forward over a 1-D
-#                             device mesh (ShardedFusedWorkspace tables)
-#   spmm_bcsr_fused         — the mixed VPU/MXU dispatch: BCSR block-rows
-#                             join the descriptor stream with an MXU tag
-#                             and per-block-row kmax, so a plan that mixes
-#                             ELL rows and (bm x bk) matmul block-rows is
-#                             STILL one pallas_call (backend=pallas_bcsr)
-#   spmm_bcsr_fused_staged  — the mixed dispatch with panel DMA staging
-#                             for ALL streams: slots/cols per block, X
-#                             per trip ((bk, dt) MXU panels, bm-row VPU
-#                             gathers) — n·dt no longer bounds VMEM
-#   spmm_bcsr_fused_sharded — the mixed kernel per chip under shard_map;
-#                             closes the "MXU xor multi-chip" gap
+# pure-jnp oracle in ref.py (validated in interpret mode on CPU):
+#   spmm_bcsr_fused         — THE fused SpMM dispatch: every block-row of
+#                             the plan, VPU (ELL gather+FMA) or MXU ((bm x
+#                             bk) block matmul) by its descriptor tag, in
+#                             one pallas_call; _staged stages every stream
+#                             through HBM->SMEM/VMEM window rings, _sharded
+#                             runs it once per chip under shard_map
+#   spmm_ell_fused          — the same kernel on a pure-ELL table (every
+#                             tag VPU), keeping the ELL entry points
+#   staging                 — the (rows, LANE) stream view, window DMAs,
+#                             per-chip windows and multi-call issue the
+#                             fused kernels share
+#   attn_fused              — the sparse-attention sandwich: SDDMM ->
+#                             in-register segment softmax -> S·V through
+#                             the SAME descriptor stream, one dispatch,
+#                             S never in HBM (DESIGN.md §13)
 #   spmm_ell_segment        — single-segment micro-oracle retained from
 #                             the per-segment era (paper Listing 2 CCM/VPU
 #                             port); production traffic uses the fused path
 #   spmm_bcsr               — pre-fusion MXU micro-oracle (global-Kmax
-#                             padding, single dispatch path); retained for
-#                             kernel-level regression sweeps only
-#   attn_fused              — the sparse-attention sandwich: SDDMM →
-#                             in-register segment softmax → S·V through
-#                             the SAME descriptor stream, one dispatch,
-#                             S never in HBM (DESIGN.md §13); _staged
-#                             and _sharded twins mirror the SpMM ones
+#                             padding); kernel-level regression sweeps only
 #   sddmm                   — backward twin (dA.vals = <dY[row], X[col]>)
 # ops.py wraps each kernel with the resolved interpret flag and the
 # DISPATCH_COUNTS host counter the Table IV invariant tests read.

@@ -30,15 +30,16 @@ the clamp form ``p = w · exp(min(z - m_new, 0))``: when ``w > 0`` the
 running max already dominates ``z`` so the clamp is inactive; when
 ``w == 0`` it stops ``0 · exp(+inf)``.
 
-Operand staging matches the SpMM kernels: ``resident`` keeps every
-operand in VMEM; ``dma`` (``attn_fused_staged``) double-buffers the
-slot/column panels from HBM per merged trip.  Q/K/V stay resident
-BlockSpec panels in both modes (the ELL-staged SpMM kernel keeps X
-resident for the same reason — the row gather touches arbitrary rows;
-streaming K/V panels the way the mixed SpMM kernel streams X is the
-noted follow-up).  ``attn_fused_sharded`` runs the same kernel once
-per chip under ``shard_map``: descriptor tables and the
-workspace-ordered Q stacked per chip, K/V replicated.
+Operand staging matches the SpMM kernels: ``resident`` scalar-prefetches
+the column and weight streams into SMEM; ``dma`` (``attn_fused_staged``)
+double-buffers each trip's windows of them into SMEM (VPU weights) or
+VMEM (MXU weight panels) rings.  Q/K/V stay resident BlockSpec panels
+in both modes (the row gather touches arbitrary key rows).  A block
+split into piece trips carries its softmax state ``(acc, m, l)`` from
+trip to trip in VMEM, so the result is the unsplit one.
+``attn_fused_sharded`` runs the same kernel once per chip under
+``shard_map``: descriptor tables and the workspace-ordered Q stacked
+per chip, K/V replicated.
 """
 from __future__ import annotations
 
@@ -50,223 +51,181 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
-try:                                   # jax >= 0.6 promotes it to jax.*
-    from jax import shard_map as _shard_map
-except ImportError:                    # jax 0.4/0.5
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-from .spmm_ell_fused import _chip_windows, _staged_dispatch
+from ..platform import LANE
+from . import staging as st
 
 # finite "masked" score: matches models/layers.py NEG_INF; keeping it
 # finite (not -inf) makes the m == m_new warmup rescale exp(0) exact
 _NEG = -1e30
+_VPU = 0
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _softmax_trip(acc, m, l, z, w, vg):
     """Fold one trip's scores into the online-softmax carry.
 
-    acc (bm, dt) weighted-V accumulator, m (bm,) running max, l (bm,)
+    acc (bm, dt) weighted-V accumulator, m (bm, 1) running max, l (bm, 1)
     running denominator; z (bm, k) trip scores, w (bm, k) mask weights
     (0 on padding), vg (k, dt) the trip's V rows.  Exact across trips:
     the exp(m - m_new) rescale telescopes to one global max.
     """
     zm = jnp.where(w > 0, z, _NEG)
-    m_new = jnp.maximum(m, jnp.max(zm, axis=1))
+    m_new = jnp.maximum(m, jnp.max(zm, axis=1, keepdims=True))
     r = jnp.exp(m - m_new)
     # clamp keeps padding slots NaN-free: w == 0 kills the term and the
     # min() stops exp overflowing; w > 0 implies z <= m_new so the
     # clamp never alters a live score
-    p = w * jnp.exp(jnp.minimum(z - m_new[:, None], 0.0))
-    acc = acc * r[:, None] + jax.lax.dot_general(
+    p = w * jnp.exp(jnp.minimum(z - m_new, 0.0))
+    acc = acc * r + jax.lax.dot_general(
         p, vg, dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    l = l * r + jnp.sum(p, axis=1)
+        preferred_element_type=jnp.float32, precision=_HIGHEST)
+    l = l * r + jnp.sum(p, axis=1, keepdims=True)
     return acc, m_new, l
 
 
-def _kernel(tag_ref, off_ref, coff_ref, L_ref, cols_ref, vals_ref,
-            q_ref, k_ref, v_ref, y_ref, *, bm: int, bk: int, dt: int,
-            mw: int = 1):
+def _scores(q_blk, kp):
+    """(bm, dh)·(bk, dh)ᵀ block scores on the MXU."""
+    return jax.lax.dot_general(
+        q_blk, kp, dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32, precision=_HIGHEST)
+
+
+def _member(w, *, tag, L, cont, carries, j, q_ref, k_ref, v_ref,
+            col, weight, weight_panel, bm: int, bk: int, dt: int):
+    """One member descriptor of a merged trip: its own tag dispatch and
+    its own ``(acc, m, l)`` softmax carry, started from the previous
+    trip's on a piece trip.  ``col(i)``/``weight(i)`` read the i-th
+    entry of the member's column/weight stream, ``weight_panel(k)`` its
+    k-th MXU weight panel."""
+    cacc, cm, cl = carries
+    rows = pl.ds(w * bm, bm)
+    q_blk = q_ref[rows, :].astype(jnp.float32)
+    carry0 = jax.lax.cond(
+        cont, lambda: (cacc[j, rows, :], cm[j, rows, :], cl[j, rows, :]),
+        lambda: (jnp.zeros((bm, dt), jnp.float32),
+                 jnp.full((bm, 1), _NEG, jnp.float32),
+                 jnp.zeros((bm, 1), jnp.float32)))
+
+    def vpu_block():
+        # SDDMM one column at a time: gather the bm K/V rows the trip's
+        # slots name, score against the resident Q block
+        def nnz_step(nz, carry):
+            acc, m, l = carry
+            cs = [col(rr * L + nz) for rr in range(bm)]
+            kg = jnp.concatenate([k_ref[pl.ds(c, 1), :] for c in cs],
+                                 axis=0).astype(jnp.float32)
+            vg = jnp.concatenate([v_ref[pl.ds(c, 1), :] for c in cs],
+                                 axis=0).astype(jnp.float32)
+            wv = st.column([weight(rr * L + nz) for rr in range(bm)])
+            z = jnp.sum(q_blk * kg, axis=1, keepdims=True)
+            zm = jnp.where(wv > 0, z, _NEG)
+            m_new = jnp.maximum(m, zm)
+            r = jnp.exp(m - m_new)
+            p = wv * jnp.exp(jnp.minimum(z - m_new, 0.0))
+            return acc * r + p * vg, m_new, l * r + p
+        return jax.lax.fori_loop(0, L, nnz_step, carry0)
+
+    def mxu_block():
+        # SDDMM a block-column at a time: (bm, dh)·(bk, dh)ᵀ scores on
+        # the MXU, then the (bm, bk)·(bk, dt) S·V panel matmul
+        def blk_step(kk, carry):
+            bc = col(kk)
+            kp = k_ref[pl.ds(pl.multiple_of(bc * bk, bk), bk), :]
+            vp = v_ref[pl.ds(pl.multiple_of(bc * bk, bk), bk), :]
+            return _softmax_trip(*carry, _scores(q_blk, kp.astype(
+                jnp.float32)), weight_panel(kk), vp.astype(jnp.float32))
+        return jax.lax.fori_loop(0, L, blk_step, carry0)
+
+    return jax.lax.cond(tag == _VPU, vpu_block, mxu_block)
+
+
+def _finish(results, carries, j, y_ref, mw: int):
+    """Keep the raw softmax state for a following piece trip and store
+    the normalized rows (all-padding rows keep l == 0 and normalize to
+    zero)."""
+    cacc, cm, cl = carries
+    cat = (lambda xs: xs[0] if mw == 1 else jnp.concatenate(xs, axis=0))
+    acc, m, l = (cat([r[i] for r in results]) for i in range(3))
+    cacc[j], cm[j], cl[j] = acc, m, l
+    y_ref[...] = (acc / jnp.where(l > 0, l, 1.0)).astype(y_ref.dtype)
+
+
+def _kernel(tag_ref, off_ref, coff_ref, L_ref, cont_ref, cols_ref,
+            vals_s_ref, vals_ref, q_ref, k_ref, v_ref, y_ref, cacc, cm, cl,
+            *, bm: int, bk: int, dt: int, mw: int):
     g = pl.program_id(0)
+    j = pl.program_id(1)
+    carries = (cacc, cm, cl)
 
-    def sub_block(w, tag, off, coff, L):
-        # one member descriptor of the merged trip (CGCM, DESIGN.md
-        # §7.9): its own tag dispatch and its own (acc, m, l) softmax
-        # carry, so merged rows normalize independently.
-        q_blk = q_ref[pl.ds(w * bm, bm), :].astype(jnp.float32)
+    def member(w):
+        d = g * mw + w
+        off, coff = off_ref[d], coff_ref[d]
+        return _member(
+            w, tag=tag_ref[d], L=L_ref[d], cont=cont_ref[g] > 0,
+            carries=carries, j=j, q_ref=q_ref, k_ref=k_ref, v_ref=v_ref,
+            col=lambda i: cols_ref[coff + i],
+            weight=lambda i: vals_s_ref[off + i],
+            weight_panel=lambda k: st.panel_at(vals_ref, off // LANE
+                                               + k * bm, bm, bk),
+            bm=bm, bk=bk, dt=dt)
 
-        def vpu_block():
-            # SDDMM one column at a time: gather the bm K/V rows the
-            # trip's slots name, score against the resident Q block
-            def nnz_step(nz, carry):
-                acc, m, l = carry
-                ks, vs, ws = [], [], []
-                for rr in range(bm):
-                    s = off + rr * L + nz
-                    c = cols_ref[coff + rr * L + nz]  # SMEM scalar read
-                    ks.append(k_ref[pl.ds(c, 1), :])  # (1, dh_pad)
-                    vs.append(v_ref[pl.ds(c, 1), :])  # (1, dt)
-                    ws.append(vals_ref[pl.ds(s, 1)])  # (1,) mask weight
-                kg = jnp.concatenate(ks, axis=0).astype(jnp.float32)
-                vg = jnp.concatenate(vs, axis=0).astype(jnp.float32)
-                wv = jnp.concatenate(ws, axis=0).astype(jnp.float32)
-                z = jnp.sum(q_blk * kg, axis=1)       # (bm,) scores
-                zm = jnp.where(wv > 0, z, _NEG)
-                m_new = jnp.maximum(m, zm)
-                r = jnp.exp(m - m_new)
-                p = wv * jnp.exp(jnp.minimum(z - m_new, 0.0))
-                acc = acc * r[:, None] + p[:, None] * vg
-                return acc, m_new, l * r + p
-            return jax.lax.fori_loop(
-                0, L, nnz_step,
-                (jnp.zeros((bm, dt), jnp.float32),
-                 jnp.full((bm,), _NEG, jnp.float32),
-                 jnp.zeros((bm,), jnp.float32)))
-
-        def mxu_block():
-            # SDDMM a block-column at a time: (bm, dh)·(bk, dh)ᵀ scores
-            # on the MXU, then the (bm, bk)·(bk, dt) S·V panel matmul
-            def blk_step(kk, carry):
-                bc = cols_ref[coff + kk]             # block-column (SMEM)
-                wv = vals_ref[pl.ds(off + kk * (bm * bk), bm * bk)]
-                kp = k_ref[pl.ds(bc * bk, bk), :].astype(jnp.float32)
-                vp = v_ref[pl.ds(bc * bk, bk), :].astype(jnp.float32)
-                z = jax.lax.dot_general(
-                    q_blk, kp,
-                    dimension_numbers=(((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)   # (bm, bk)
-                return _softmax_trip(
-                    *carry, z, wv.reshape(bm, bk).astype(jnp.float32),
-                    vp)
-            return jax.lax.fori_loop(
-                0, L, blk_step,
-                (jnp.zeros((bm, dt), jnp.float32),
-                 jnp.full((bm,), _NEG, jnp.float32),
-                 jnp.zeros((bm,), jnp.float32)))
-
-        acc, m, l = jax.lax.cond(tag == 0, vpu_block, mxu_block)
-        # all-padding rows keep l == 0 and normalize to zero output
-        return acc / jnp.where(l > 0, l, 1.0)[:, None]
-
-    accs = [sub_block(w, tag_ref[g * mw + w], off_ref[g * mw + w],
-                      coff_ref[g * mw + w], L_ref[g * mw + w])
-            for w in range(mw)]
-    acc = accs[0] if mw == 1 else jnp.concatenate(accs, axis=0)
-    y_ref[...] = acc.astype(y_ref.dtype)             # one store per trip
+    _finish([member(w) for w in range(mw)], carries, j, y_ref, mw)
 
 
-def _staged_kernel(tag_ref, off_ref, coff_ref, L_ref, cols_ref, vals_ref,
-                   q_ref, k_ref, v_ref, y_ref, cbuf, vbuf, csem, vsem, *,
-                   bm: int, bk: int, dt: int, span: int, cspan: int,
-                   mw: int = 1):
-    """Double-buffered twin of :func:`_kernel` (DESIGN.md §7.7/§13).
-
-    Only the slot/column streams stage: each merged trip's panels are
-    the fixed windows ``[off, off + span)`` / ``[coff, coff + cspan)``
-    anchored at the trip's FIRST member descriptor, copied into the
-    alternate ring buffer while the previous trip computes.  Q/K/V stay
-    resident BlockSpec panels (see module docstring).  Accumulation
-    order is identical to the resident kernel — the staged path stays
-    BIT-identical, only the stream source moves to the panel ring.
-    """
+def _staged_kernel(tag_ref, off_ref, coff_ref, L_ref, cont_ref, cols_ref,
+                   vals_ref, q_ref, k_ref, v_ref, y_ref, cring, vring,
+                   mring, cacc, cm, cl, csem, vsem, *, bm: int, bk: int,
+                   dt: int, span: int, cspan: int, mw: int):
+    """Double-buffered twin of :func:`_kernel` (DESIGN.md §7.7/§13):
+    the same trip windows and rings as the staged SpMM kernel; Q/K/V
+    stay resident BlockSpec panels."""
     g = pl.program_id(0)
     j = pl.program_id(1)
     ng = pl.num_programs(0)
-
-    def panel_dmas(slot, grp):
-        return (
-            pltpu.make_async_copy(
-                cols_ref.at[pl.ds(coff_ref[grp * mw], cspan)],
-                cbuf.at[slot], csem.at[slot]),
-            pltpu.make_async_copy(
-                vals_ref.at[pl.ds(off_ref[grp * mw], span)],
-                vbuf.at[slot], vsem.at[slot]),
-        )
-
-    @pl.when((g == 0) & (j == 0))
-    def _warmup():
-        for dma in panel_dmas(0, 0):
-            dma.start()
-
-    @pl.when((j == 0) & (g + 1 < ng))
-    def _prefetch_next():
-        for dma in panel_dmas((g + 1) % 2, g + 1):
-            dma.start()
-
-    @pl.when(j == 0)
-    def _arrive():
-        for dma in panel_dmas(g % 2, g):
-            dma.wait()
-
+    carries = (cacc, cm, cl)
+    st.stage_trip_windows(tag_ref, off_ref, coff_ref, cols_ref, vals_ref,
+                          cring, vring, mring, csem, vsem, g=g, j=j, ng=ng,
+                          mw=mw, vrows=span // LANE, crows=cspan // LANE)
     slot = g % 2
+    vbase = st.window_base(off_ref[g * mw]) * LANE
+    cbase = st.window_base(coff_ref[g * mw]) * LANE
 
-    def sub_block(w, tag, loff, lcoff, L):
-        # ``loff``/``lcoff`` are the member's panel-local stream bases
-        # (0 for the trip's first member)
-        q_blk = q_ref[pl.ds(w * bm, bm), :].astype(jnp.float32)
+    def member(w):
+        d = g * mw + w
+        loff, lcoff = off_ref[d] - vbase, coff_ref[d] - cbase
+        return _member(
+            w, tag=tag_ref[d], L=L_ref[d], cont=cont_ref[g] > 0,
+            carries=carries, j=j, q_ref=q_ref, k_ref=k_ref, v_ref=v_ref,
+            col=lambda i: st.read(cring, slot, lcoff + i),
+            weight=lambda i: st.read(vring, slot, loff + i),
+            weight_panel=lambda k: st.panel(mring, slot, loff // LANE
+                                            + k * bm, bm, bk),
+            bm=bm, bk=bk, dt=dt)
 
-        def vpu_block():
-            def nnz_step(nz, carry):
-                acc, m, l = carry
-                ks, vs, ws = [], [], []
-                for rr in range(bm):
-                    s = loff + rr * L + nz           # panel-local slot
-                    c = cbuf[slot, lcoff + rr * L + nz]
-                    ks.append(k_ref[pl.ds(c, 1), :])
-                    vs.append(v_ref[pl.ds(c, 1), :])
-                    ws.append(vbuf[slot, pl.ds(s, 1)])
-                kg = jnp.concatenate(ks, axis=0).astype(jnp.float32)
-                vg = jnp.concatenate(vs, axis=0).astype(jnp.float32)
-                wv = jnp.concatenate(ws, axis=0).astype(jnp.float32)
-                z = jnp.sum(q_blk * kg, axis=1)
-                zm = jnp.where(wv > 0, z, _NEG)
-                m_new = jnp.maximum(m, zm)
-                r = jnp.exp(m - m_new)
-                p = wv * jnp.exp(jnp.minimum(z - m_new, 0.0))
-                acc = acc * r[:, None] + p[:, None] * vg
-                return acc, m_new, l * r + p
-            return jax.lax.fori_loop(
-                0, L, nnz_step,
-                (jnp.zeros((bm, dt), jnp.float32),
-                 jnp.full((bm,), _NEG, jnp.float32),
-                 jnp.zeros((bm,), jnp.float32)))
+    _finish([member(w) for w in range(mw)], carries, j, y_ref, mw)
 
-        def mxu_block():
-            def blk_step(kk, carry):
-                bc = cbuf[slot, lcoff + kk]
-                wv = vbuf[slot, pl.ds(loff + kk * (bm * bk), bm * bk)]
-                kp = k_ref[pl.ds(bc * bk, bk), :].astype(jnp.float32)
-                vp = v_ref[pl.ds(bc * bk, bk), :].astype(jnp.float32)
-                z = jax.lax.dot_general(
-                    q_blk, kp,
-                    dimension_numbers=(((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-                return _softmax_trip(
-                    *carry, z, wv.reshape(bm, bk).astype(jnp.float32),
-                    vp)
-            return jax.lax.fori_loop(
-                0, L, blk_step,
-                (jnp.zeros((bm, dt), jnp.float32),
-                 jnp.full((bm,), _NEG, jnp.float32),
-                 jnp.zeros((bm,), jnp.float32)))
 
-        acc, m, l = jax.lax.cond(tag == 0, vpu_block, mxu_block)
-        return acc / jnp.where(l > 0, l, 1.0)[:, None]
+def _carry_scratch(nj: int, rows: int, dt: int):
+    return [pltpu.VMEM((nj, rows, dt), jnp.float32),
+            pltpu.VMEM((nj, rows, 1), jnp.float32),
+            pltpu.VMEM((nj, rows, 1), jnp.float32)]
 
-    accs = [sub_block(w, tag_ref[g * mw + w],
-                      0 if mw == 1 else off_ref[g * mw + w] - off_ref[g * mw],
-                      0 if mw == 1 else coff_ref[g * mw + w] - coff_ref[g * mw],
-                      L_ref[g * mw + w])
-            for w in range(mw)]
-    acc = accs[0] if mw == 1 else jnp.concatenate(accs, axis=0)
-    y_ref[...] = acc.astype(y_ref.dtype)
+
+def _prepare(blk_L, k, v, cont, bk: int, mw: int):
+    if cont is None:
+        cont = jnp.zeros((blk_L.shape[0] // mw,), jnp.int32)
+    pad = -k.shape[0] % max(bk, 8)
+    if pad:
+        k = jnp.pad(k, ((0, pad), (0, 0)))
+        v = jnp.pad(v, ((0, pad), (0, 0)))
+    return k, v, cont
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bk", "mw", "interpret"))
 def attn_fused(blk_tag: jax.Array, blk_off: jax.Array,
                blk_coff: jax.Array, blk_L: jax.Array,
                cols_flat: jax.Array, vals_flat: jax.Array,
-               q_ws: jax.Array, k: jax.Array, v: jax.Array, *,
+               q_ws: jax.Array, k: jax.Array, v: jax.Array, cont=None, *,
                bm: int = 8, bk: int = 8, mw: int = 1,
                interpret: bool = True) -> jax.Array:
     """Compute the WHOLE sparse-attention plan in one dispatch:
@@ -281,9 +240,10 @@ def attn_fused(blk_tag: jax.Array, blk_off: jax.Array,
     q_ws      : (B*bm, dh_pad) float — Q in WORKSPACE row order (the
                 planner's ``workspace_row_map`` gather, scale folded
                 in), head dim padded to the lane tile
-    k         : (n_pad, dh_pad) float — rows padded to a bk multiple
-    v         : (n_pad, dv_pad) float — value dim padded to the lane
-                tile; dv tiles the second grid axis
+    k         : (n, dh_pad) float
+    v         : (n, dv_pad) float — value dim padded to the lane tile;
+                dv tiles the second grid axis
+    cont      : (B // mw,) int32 — piece trips (default: none)
 
     Returns workspace-ordered rows; the caller applies the plan's
     ``inv_perm`` gather to recover output row order.
@@ -292,36 +252,32 @@ def attn_fused(blk_tag: jax.Array, blk_off: jax.Array,
 
     num_blocks = blk_tag.shape[0]
     assert num_blocks % mw == 0, (num_blocks, mw)
-    (S,) = vals_flat.shape
+    k, v, cont = _prepare(blk_L, k, v, cont, bk, mw)
+    vals2 = st.stream_rows(vals_flat.astype(jnp.float32))
     n_pad, dh_pad = k.shape
     dv_pad = v.shape[1]
     dt = kernel_lane_tile(dv_pad)
-    grid = (num_blocks // mw, dv_pad // dt)
+    nj = dv_pad // dt
 
     return pl.pallas_call(
         functools.partial(_kernel, bm=bm, bk=bk, dt=dt, mw=mw),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,
-            grid=grid,
+            num_scalar_prefetch=7,
+            grid=(num_blocks // mw, nj),
             in_specs=[
-                pl.BlockSpec((S,),
-                             lambda g, j, tag, off, coff, L, cols: (0,)),
-                pl.BlockSpec((mw * bm, dh_pad),
-                             lambda g, j, tag, off, coff, L, cols: (g, 0)),
-                pl.BlockSpec((n_pad, dh_pad),
-                             lambda g, j, tag, off, coff, L, cols: (0, 0)),
-                pl.BlockSpec((n_pad, dt),
-                             lambda g, j, tag, off, coff, L, cols: (0, j)),
+                pl.BlockSpec(vals2.shape, lambda g, j, *_: (0, 0)),
+                pl.BlockSpec((mw * bm, dh_pad), lambda g, j, *_: (g, 0)),
+                pl.BlockSpec((n_pad, dh_pad), lambda g, j, *_: (0, 0)),
+                pl.BlockSpec((n_pad, dt), lambda g, j, *_: (0, j)),
             ],
-            out_specs=pl.BlockSpec(
-                (mw * bm, dt),
-                lambda g, j, tag, off, coff, L, cols: (g, j)),
+            out_specs=pl.BlockSpec((mw * bm, dt), lambda g, j, *_: (g, j)),
+            scratch_shapes=_carry_scratch(nj, mw * bm, dt),
         ),
         out_shape=jax.ShapeDtypeStruct((num_blocks * bm, dv_pad),
                                        jnp.float32),
         interpret=interpret,
-    )(blk_tag, blk_off, blk_coff, blk_L, cols_flat, vals_flat,
-      q_ws, k, v)
+    )(blk_tag, blk_off, blk_coff, blk_L, cont, cols_flat,
+      vals_flat.astype(jnp.float32), vals2, q_ws, k, v)
 
 
 @functools.partial(
@@ -330,9 +286,10 @@ def attn_fused(blk_tag: jax.Array, blk_off: jax.Array,
 def attn_fused_staged(blk_tag: jax.Array, blk_off: jax.Array,
                       blk_coff: jax.Array, blk_L: jax.Array,
                       cols_flat: jax.Array, vals_flat: jax.Array,
-                      q_ws: jax.Array, k: jax.Array, v: jax.Array, *,
-                      span: int, cspan: int, bm: int = 8, bk: int = 8,
-                      mw: int = 1, interpret: bool = True) -> jax.Array:
+                      q_ws: jax.Array, k: jax.Array, v: jax.Array,
+                      cont=None, *, span: int, cspan: int, bm: int = 8,
+                      bk: int = 8, mw: int = 1,
+                      interpret: bool = True) -> jax.Array:
     """The DMA-staged fused attention dispatch — same contract as
     :func:`attn_fused` and BIT-identical output.  ``span``/``cspan``
     are the workspace's ``max_span``/``max_cspan`` per-merged-trip DMA
@@ -341,33 +298,36 @@ def attn_fused_staged(blk_tag: jax.Array, blk_off: jax.Array,
 
     num_blocks = blk_tag.shape[0]
     assert num_blocks % mw == 0, (num_blocks, mw)
+    if num_blocks > st.call_descs(mw):
+        raise ValueError(
+            f"{num_blocks} attention descriptors exceed the "
+            f"{st.call_descs(mw)} one staged call holds in SMEM")
+    k, v, cont = _prepare(blk_L, k, v, cont, bk, mw)
     n_pad, dh_pad = k.shape
     dv_pad = v.shape[1]
     dt = kernel_lane_tile(dv_pad)
-    grid = (num_blocks // mw, dv_pad // dt)
+    nj = dv_pad // dt
+    vrows, crows = span // LANE, cspan // LANE
 
     return pl.pallas_call(
         functools.partial(_staged_kernel, bm=bm, bk=bk, dt=dt, span=span,
                           cspan=cspan, mw=mw),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=grid,
+            num_scalar_prefetch=5,
+            grid=(num_blocks // mw, nj),
             in_specs=[
-                pl.BlockSpec(memory_space=pltpu.ANY),     # cols (HBM)
-                pl.BlockSpec(memory_space=pltpu.ANY),     # vals (HBM)
-                pl.BlockSpec((mw * bm, dh_pad),
-                             lambda g, j, tag, off, coff, L: (g, 0)),
-                pl.BlockSpec((n_pad, dh_pad),
-                             lambda g, j, tag, off, coff, L: (0, 0)),
-                pl.BlockSpec((n_pad, dt),
-                             lambda g, j, tag, off, coff, L: (0, j)),
+                pl.BlockSpec(memory_space=pl.ANY),     # cols (HBM)
+                pl.BlockSpec(memory_space=pl.ANY),     # vals (HBM)
+                pl.BlockSpec((mw * bm, dh_pad), lambda g, j, *_: (g, 0)),
+                pl.BlockSpec((n_pad, dh_pad), lambda g, j, *_: (0, 0)),
+                pl.BlockSpec((n_pad, dt), lambda g, j, *_: (0, j)),
             ],
-            out_specs=pl.BlockSpec(
-                (mw * bm, dt),
-                lambda g, j, tag, off, coff, L: (g, j)),
+            out_specs=pl.BlockSpec((mw * bm, dt), lambda g, j, *_: (g, j)),
             scratch_shapes=[
-                pltpu.SMEM((2, cspan), jnp.int32),        # cols panels
-                pltpu.VMEM((2, span), jnp.float32),       # weight panels
+                pltpu.SMEM((2, crows, LANE), jnp.int32),     # cols
+                pltpu.SMEM((2, vrows, LANE), jnp.float32),   # VPU weights
+                pltpu.VMEM((2, vrows, LANE), jnp.float32),   # MXU weights
+                *_carry_scratch(nj, mw * bm, dt),
                 pltpu.SemaphoreType.DMA((2,)),
                 pltpu.SemaphoreType.DMA((2,)),
             ],
@@ -375,16 +335,16 @@ def attn_fused_staged(blk_tag: jax.Array, blk_off: jax.Array,
         out_shape=jax.ShapeDtypeStruct((num_blocks * bm, dv_pad),
                                        jnp.float32),
         interpret=interpret,
-    )(blk_tag, blk_off, blk_coff, blk_L, cols_flat, vals_flat,
-      q_ws, k, v)
+    )(blk_tag, blk_off, blk_coff, blk_L, cont, st.stream_rows(cols_flat),
+      st.stream_rows(vals_flat.astype(jnp.float32)), q_ws, k, v)
 
 
 def attn_fused_sharded(blk_tag: jax.Array, blk_off: jax.Array,
                        blk_coff: jax.Array, blk_L: jax.Array,
                        cols_flat: jax.Array, vals_flat: jax.Array,
-                       q_ws: jax.Array, k: jax.Array, v: jax.Array, *,
-                       mesh, bm: int = 8, bk: int = 8, mw: int = 1,
-                       interpret: bool = True,
+                       q_ws: jax.Array, k: jax.Array, v: jax.Array,
+                       cont=None, *, mesh, bm: int = 8, bk: int = 8,
+                       mw: int = 1, interpret: bool = True,
                        staging: str = "resident", span=0,
                        cspan=0) -> jax.Array:
     """Run one fused attention dispatch per chip under ``shard_map``.
@@ -396,16 +356,18 @@ def attn_fused_sharded(blk_tag: jax.Array, blk_off: jax.Array,
     the SpMM path does not apply (``x_sharding`` is pinned
     ``"replicated"`` upstream).  Returns (C, B*bm, dv_pad) workspace
     rows sharded over the chip axis; the caller flattens and applies
-    the sharded workspace's GLOBAL ``inv_perm`` gather.  A forward
-    costs exactly C dispatches.  ``staging="dma"`` lowers each chip
-    through :func:`attn_fused_staged`; ``span``/``cspan`` may be
-    per-chip tuples (see ``spmm_ell_fused._staged_dispatch``).
+    the sharded workspace's GLOBAL ``inv_perm`` gather.
+    ``staging="dma"`` lowers each chip through
+    :func:`attn_fused_staged`; ``span``/``cspan`` may be per-chip
+    tuples (see ``staging.staged_dispatch``).
     """
+    if cont is None:
+        cont = jnp.zeros((blk_L.shape[0], blk_L.shape[1] // mw), jnp.int32)
     fn = _sharded_callable(mesh, bm, bk, interpret, staging,
-                           _chip_windows(span, mesh.size),
-                           _chip_windows(cspan, mesh.size), mw)
-    return fn(blk_tag, blk_off, blk_coff, blk_L, cols_flat, vals_flat,
-              q_ws, k, v)
+                           st.chip_windows(span, mesh.size),
+                           st.chip_windows(cspan, mesh.size), mw)
+    return fn(blk_tag, blk_off, blk_coff, blk_L, cont, cols_flat,
+              vals_flat, q_ws, k, v)
 
 
 @functools.lru_cache(maxsize=32)
@@ -414,7 +376,7 @@ def _sharded_callable(mesh, bm: int, bk: int, interpret: bool,
                       cspans: tuple = (0,), mw: int = 1):
     """jit-wrapped shard_map closure, memoized per (mesh, bm, bk,
     interpret, staging, spans, cspans, mw) — same lifecycle as the SpMM
-    twins; evicted by ``core.jit_cache.clear_global_cache``."""
+    twin; evicted by ``core.jit_cache.clear_global_cache``."""
     (axis,) = mesh.axis_names
 
     if staging == "dma":
@@ -422,20 +384,15 @@ def _sharded_callable(mesh, bm: int, bk: int, interpret: bool,
             return functools.partial(attn_fused_staged, span=sp,
                                      cspan=cs, bm=bm, bk=bk, mw=mw,
                                      interpret=interpret)
-        kernel = _staged_dispatch(axis, spans, cspans, call)
+        kernel = st.staged_dispatch(axis, spans, cspans, call)
     else:
         kernel = functools.partial(attn_fused, bm=bm, bk=bk, mw=mw,
                                    interpret=interpret)
 
     shard = P(axis)
 
-    def per_chip(tag, off, coff, L, cols, vals, q, kk, vv):
+    def per_chip(tag, off, coff, L, cont, cols, vals, q, kk, vv):
         return kernel(tag[0], off[0], coff[0], L[0], cols[0], vals[0],
-                      q[0], kk, vv)[None]
+                      q[0], kk, vv, cont[0])[None]
 
-    specs = dict(in_specs=(shard,) * 7 + (P(), P()), out_specs=shard)
-    try:
-        fn = _shard_map(per_chip, mesh=mesh, check_rep=False, **specs)
-    except TypeError:      # jax >= 0.7 renamed the replication check
-        fn = _shard_map(per_chip, mesh=mesh, check_vma=False, **specs)
-    return jax.jit(fn)
+    return st.shard(per_chip, mesh, (shard,) * 8 + (P(), P()), shard)

@@ -1,16 +1,17 @@
 """jit'd wrappers around the Pallas kernels.
 
-``interpret`` defaults to True on CPU (this container) and False when a
-real TPU backend is present — the kernels themselves are written for the
-TPU target and only *validated* in interpret mode here.
+``interpret`` resolves to False (native Mosaic kernels) on a TPU
+backend and to True elsewhere: off the chip the kernels run in the
+Pallas interpreter, which checks results, not speed.
 
-Every wrapper records a dispatch in ``DISPATCH_COUNTS`` (a plain host
-counter, incremented once per ``pallas_call`` issued from Python).  The
-fused-path tests use it to assert the Table IV invariant: one dispatch
-per (matrix, d) instance, regardless of segment count — and on the
-sharded path exactly ``n_chips`` dispatches per forward (``shard_map``
-traces the body once and SPMD-replicates it, so each of the C devices
-executes one ``pallas_call``; the wrapper counts all C).
+Every wrapper records its launches in ``DISPATCH_COUNTS`` (a plain host
+counter, incremented when the wrapper is traced).  The fused-path tests
+use it to assert the Table IV invariant: one dispatch per (matrix, d)
+instance, regardless of segment count — and on the sharded path
+``n_chips`` per forward (``shard_map`` traces the body once and
+SPMD-replicates it; the wrapper counts all C).  A staged stream whose
+descriptor tables exceed one call's SMEM is issued as several calls,
+and each counts.
 """
 from __future__ import annotations
 
@@ -20,11 +21,12 @@ import jax
 
 from .attn_fused import attn_fused, attn_fused_sharded, attn_fused_staged
 from .spmm_csr import spmm_ell_segment
-from .spmm_ell_fused import (_chip_windows, spmm_ell_fused,
-                             spmm_ell_fused_sharded, spmm_ell_fused_staged)
+from .spmm_ell_fused import (spmm_ell_fused, spmm_ell_fused_sharded,
+                             spmm_ell_fused_staged)
 from .spmm_bcsr import spmm_bcsr
 from .spmm_bcsr_fused import (spmm_bcsr_fused, spmm_bcsr_fused_sharded,
                               spmm_bcsr_fused_staged)
+from .staging import chip_windows, num_calls
 
 # name -> number of pallas_call dispatches issued (host-side; jit tracing
 # reuses the compiled kernel but each op wrapper call is one dispatch)
@@ -64,10 +66,11 @@ def record_build_seconds(kind: str, seconds: float) -> None:
     BUILD_SECONDS[kind] += float(seconds)
 
 # fused-dispatch operand staging modes (DESIGN.md §7.7):
-#   resident  whole flat slot buffer + X panel live in VMEM — the
-#             interpret-mode default and the bit-identity micro-oracle
-#   dma       double-buffered per-block panel DMA from HBM — the
-#             production TPU default
+#   resident  streams scalar-prefetched into SMEM, X in VMEM — the
+#             interpret-mode default, the bit-identity oracle, and on a
+#             chip only for instances that fit its fast memories
+#   dma       double-buffered per-trip window DMA from HBM — the
+#             TPU default
 STAGING_MODES = ("resident", "dma")
 
 
@@ -130,8 +133,15 @@ def spmm_ell_segment_op(cols_pad_flat, vals_pad, x, *, bm: int = 8,
                             interpret=interpret)
 
 
-def spmm_ell_fused_op(blk_off, blk_L, cols_flat, vals_flat, x, *,
-                      bm: int = 8, mw: int = 1, interpret=None,
+def _launches(staging: str, num_blocks: int, mw: int) -> int:
+    """``pallas_call`` launches one fused forward issues per chip: one,
+    or for a staged stream longer than one call's SMEM tables, one per
+    call (``staging.issue_in_calls``)."""
+    return num_calls(num_blocks, mw) if staging == "dma" else 1
+
+
+def spmm_ell_fused_op(blk_off, blk_L, cols_flat, vals_flat, x, cont=None,
+                      *, bm: int = 8, mw: int = 1, interpret=None,
                       staging=None, span: int = 0, cspan: int = 0):
     """ONE dispatch for the whole plan, either staging mode; staged
     launches additionally count under ``ell_fused_dma`` so tests can
@@ -139,20 +149,21 @@ def spmm_ell_fused_op(blk_off, blk_L, cols_flat, vals_flat, x, *,
     (``mw > 1``) under ``ell_fused_merged``."""
     interpret = resolve_interpret(interpret)
     staging = _resolve_op_staging(staging, interpret, span, cspan)
-    DISPATCH_COUNTS["ell_fused"] += 1
+    n = _launches(staging, blk_off.shape[0], mw)
+    DISPATCH_COUNTS["ell_fused"] += n
     if mw > 1:
-        DISPATCH_COUNTS["ell_fused_merged"] += 1
+        DISPATCH_COUNTS["ell_fused_merged"] += n
     if staging == "dma":
-        DISPATCH_COUNTS["ell_fused_dma"] += 1
+        DISPATCH_COUNTS["ell_fused_dma"] += n
         return spmm_ell_fused_staged(blk_off, blk_L, cols_flat, vals_flat,
-                                     x, span=span, cspan=cspan, bm=bm,
-                                     mw=mw, interpret=interpret)
-    return spmm_ell_fused(blk_off, blk_L, cols_flat, vals_flat, x,
+                                     x, cont, span=span, cspan=cspan,
+                                     bm=bm, mw=mw, interpret=interpret)
+    return spmm_ell_fused(blk_off, blk_L, cols_flat, vals_flat, x, cont,
                           bm=bm, mw=mw, interpret=interpret)
 
 
-def spmm_ell_fused_sharded_op(blk_off, blk_L, cols_flat, vals_flat, x, *,
-                              mesh, bm: int = 8, mw: int = 1,
+def spmm_ell_fused_sharded_op(blk_off, blk_L, cols_flat, vals_flat, x,
+                              cont=None, *, mesh, bm: int = 8, mw: int = 1,
                               interpret=None,
                               staging=None, span=0, cspan=0,
                               x_sharding: str = "replicated",
@@ -164,24 +175,25 @@ def spmm_ell_fused_sharded_op(blk_off, blk_L, cols_flat, vals_flat, x, *,
     under ``ell_fused_xshard`` when X is row-sharded (the fetch-table
     exchange path; ``span``/``cspan`` accept per-chip tuples)."""
     interpret = resolve_interpret(interpret)
-    span = _chip_windows(span, mesh.size)
-    cspan = _chip_windows(cspan, mesh.size)
+    span = chip_windows(span, mesh.size)
+    cspan = chip_windows(cspan, mesh.size)
     staging = _resolve_op_staging(staging, interpret, min(span),
                                   min(cspan))
-    DISPATCH_COUNTS["ell_fused"] += mesh.size
+    n = mesh.size * _launches(staging, blk_off.shape[1], mw)
+    DISPATCH_COUNTS["ell_fused"] += n
     DISPATCH_COUNTS["ell_fused_sharded"] += 1
     if mw > 1:
-        DISPATCH_COUNTS["ell_fused_merged"] += mesh.size
+        DISPATCH_COUNTS["ell_fused_merged"] += n
     if x_sharding == "rows":
-        DISPATCH_COUNTS["ell_fused_xshard"] += mesh.size
+        DISPATCH_COUNTS["ell_fused_xshard"] += n
     if staging == "dma":
-        DISPATCH_COUNTS["ell_fused_dma"] += mesh.size
+        DISPATCH_COUNTS["ell_fused_dma"] += n
     else:
         span = cspan = (0,) * mesh.size   # resident ignores the windows:
                                           # keep them out of the memoized
                                           # shard_map cache key
     return spmm_ell_fused_sharded(blk_off, blk_L, cols_flat, vals_flat, x,
-                                  mesh=mesh, bm=bm, mw=mw,
+                                  cont, mesh=mesh, bm=bm, mw=mw,
                                   interpret=interpret,
                                   staging=staging, span=span, cspan=cspan,
                                   x_sharding=x_sharding, x_send=x_send,
@@ -189,7 +201,8 @@ def spmm_ell_fused_sharded_op(blk_off, blk_L, cols_flat, vals_flat, x, *,
 
 
 def attn_fused_op(blk_tag, blk_off, blk_coff, blk_L, cols_flat,
-                  vals_flat, q_ws, k, v, *, bm: int = 8, bk: int = 8,
+                  vals_flat, q_ws, k, v, cont=None, *, bm: int = 8,
+                  bk: int = 8,
                   mw: int = 1, interpret=None, staging=None,
                   span: int = 0, cspan: int = 0):
     """ONE dispatch for the whole sparse-attention sandwich (SDDMM →
@@ -205,16 +218,17 @@ def attn_fused_op(blk_tag, blk_off, blk_coff, blk_L, cols_flat,
     if staging == "dma":
         DISPATCH_COUNTS["attn_fused_dma"] += 1
         return attn_fused_staged(blk_tag, blk_off, blk_coff, blk_L,
-                                 cols_flat, vals_flat, q_ws, k, v,
+                                 cols_flat, vals_flat, q_ws, k, v, cont,
                                  span=span, cspan=cspan, bm=bm, bk=bk,
                                  mw=mw, interpret=interpret)
     return attn_fused(blk_tag, blk_off, blk_coff, blk_L, cols_flat,
-                      vals_flat, q_ws, k, v, bm=bm, bk=bk, mw=mw,
+                      vals_flat, q_ws, k, v, cont, bm=bm, bk=bk, mw=mw,
                       interpret=interpret)
 
 
 def attn_fused_sharded_op(blk_tag, blk_off, blk_coff, blk_L, cols_flat,
-                          vals_flat, q_ws, k, v, *, mesh, bm: int = 8,
+                          vals_flat, q_ws, k, v, cont=None, *, mesh,
+                          bm: int = 8,
                           bk: int = 8, mw: int = 1, interpret=None,
                           staging=None, span=0, cspan=0):
     """One fused attention dispatch per chip: counts ``mesh.size``
@@ -222,8 +236,8 @@ def attn_fused_sharded_op(blk_tag, blk_off, blk_coff, blk_L, cols_flat,
     wrapper call, ``mesh.size`` under ``attn_fused_dma`` when staged —
     K/V are replicated, so there is no ``_xshard`` variant here."""
     interpret = resolve_interpret(interpret)
-    span = _chip_windows(span, mesh.size)
-    cspan = _chip_windows(cspan, mesh.size)
+    span = chip_windows(span, mesh.size)
+    cspan = chip_windows(cspan, mesh.size)
     staging = _resolve_op_staging(staging, interpret, min(span),
                                   min(cspan))
     DISPATCH_COUNTS["attn_fused"] += mesh.size
@@ -235,7 +249,7 @@ def attn_fused_sharded_op(blk_tag, blk_off, blk_coff, blk_L, cols_flat,
     else:
         span = cspan = (0,) * mesh.size   # resident ignores the windows
     return attn_fused_sharded(blk_tag, blk_off, blk_coff, blk_L,
-                              cols_flat, vals_flat, q_ws, k, v,
+                              cols_flat, vals_flat, q_ws, k, v, cont,
                               mesh=mesh, bm=bm, bk=bk, mw=mw,
                               interpret=interpret, staging=staging,
                               span=span, cspan=cspan)
@@ -250,7 +264,7 @@ def spmm_bcsr_op(block_cols_pad, block_vals_pad, x, *, kmax: int,
 
 
 def spmm_bcsr_fused_op(blk_tag, blk_off, blk_coff, blk_L, cols_flat,
-                       vals_flat, x, *, bm: int = 8, bk: int = 8,
+                       vals_flat, x, cont=None, *, bm: int = 8, bk: int = 8,
                        mw: int = 1, interpret=None, staging=None,
                        span: int = 0, cspan: int = 0):
     """ONE dispatch for a whole mixed VPU/MXU plan (Table IV invariant,
@@ -259,22 +273,23 @@ def spmm_bcsr_fused_op(blk_tag, blk_off, blk_coff, blk_L, cols_flat,
     ``bcsr_fused_merged``."""
     interpret = resolve_interpret(interpret)
     staging = _resolve_op_staging(staging, interpret, span, cspan)
-    DISPATCH_COUNTS["bcsr_fused"] += 1
+    n = _launches(staging, blk_off.shape[0], mw)
+    DISPATCH_COUNTS["bcsr_fused"] += n
     if mw > 1:
-        DISPATCH_COUNTS["bcsr_fused_merged"] += 1
+        DISPATCH_COUNTS["bcsr_fused_merged"] += n
     if staging == "dma":
-        DISPATCH_COUNTS["bcsr_fused_dma"] += 1
+        DISPATCH_COUNTS["bcsr_fused_dma"] += n
         return spmm_bcsr_fused_staged(blk_tag, blk_off, blk_coff, blk_L,
-                                      cols_flat, vals_flat, x, span=span,
-                                      cspan=cspan, bm=bm, bk=bk, mw=mw,
-                                      interpret=interpret)
+                                      cols_flat, vals_flat, x, cont,
+                                      span=span, cspan=cspan, bm=bm, bk=bk,
+                                      mw=mw, interpret=interpret)
     return spmm_bcsr_fused(blk_tag, blk_off, blk_coff, blk_L, cols_flat,
-                           vals_flat, x, bm=bm, bk=bk, mw=mw,
+                           vals_flat, x, cont, bm=bm, bk=bk, mw=mw,
                            interpret=interpret)
 
 
 def spmm_bcsr_fused_sharded_op(blk_tag, blk_off, blk_coff, blk_L,
-                               cols_flat, vals_flat, x, *, mesh,
+                               cols_flat, vals_flat, x, cont=None, *, mesh,
                                bm: int = 8, bk: int = 8, mw: int = 1,
                                interpret=None,
                                staging=None, span=0, cspan=0,
@@ -286,22 +301,23 @@ def spmm_bcsr_fused_sharded_op(blk_tag, blk_off, blk_coff, blk_L,
     ELL sharded path, with ``bcsr_fused_dma`` tracking staged chips and
     ``bcsr_fused_xshard`` tracking row-sharded-X chips."""
     interpret = resolve_interpret(interpret)
-    span = _chip_windows(span, mesh.size)
-    cspan = _chip_windows(cspan, mesh.size)
+    span = chip_windows(span, mesh.size)
+    cspan = chip_windows(cspan, mesh.size)
     staging = _resolve_op_staging(staging, interpret, min(span),
                                   min(cspan))
-    DISPATCH_COUNTS["bcsr_fused"] += mesh.size
+    n = mesh.size * _launches(staging, blk_off.shape[1], mw)
+    DISPATCH_COUNTS["bcsr_fused"] += n
     DISPATCH_COUNTS["bcsr_fused_sharded"] += 1
     if mw > 1:
-        DISPATCH_COUNTS["bcsr_fused_merged"] += mesh.size
+        DISPATCH_COUNTS["bcsr_fused_merged"] += n
     if x_sharding == "rows":
-        DISPATCH_COUNTS["bcsr_fused_xshard"] += mesh.size
+        DISPATCH_COUNTS["bcsr_fused_xshard"] += n
     if staging == "dma":
-        DISPATCH_COUNTS["bcsr_fused_dma"] += mesh.size
+        DISPATCH_COUNTS["bcsr_fused_dma"] += n
     else:
         span = cspan = (0,) * mesh.size   # resident ignores the windows
     return spmm_bcsr_fused_sharded(blk_tag, blk_off, blk_coff, blk_L,
-                                   cols_flat, vals_flat, x, mesh=mesh,
+                                   cols_flat, vals_flat, x, cont, mesh=mesh,
                                    bm=bm, bk=bk, mw=mw,
                                    interpret=interpret,
                                    staging=staging, span=span, cspan=cspan,

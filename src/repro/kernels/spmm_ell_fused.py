@@ -1,4 +1,4 @@
-"""Fused multi-segment CCM SpMM kernel — the whole plan in ONE dispatch.
+"""Fused multi-segment CCM SpMM — the whole ELL plan in ONE dispatch.
 
 The per-segment kernel (``spmm_csr.spmm_ell_segment``) pays one
 ``pallas_call`` plus one output scatter per ELL segment, so a
@@ -7,387 +7,52 @@ multi-bucket ``nnz_split`` plan multiplies launch overhead — exactly the
 instance design (§IV-A, Table IV) eliminates.  Here the planner packs
 every segment into a single flat slot array and emits a per-row-block
 **descriptor table** (``blk_off``, ``blk_L``), and the whole plan runs
-as one ``pallas_call`` over a static ``(row-blocks, d-tiles)`` grid —
-the same one-kernel-many-rows shape GE-SpMM uses on GPU.
+as one ``pallas_call`` over a static ``(row-blocks, d-tiles)`` grid.
 
-Per grid step, the descriptor is read from SMEM (scalar prefetch): the
-block's slot offset and its segment's padded row length ``L``.  The nnz
-loop trip count is that structure-derived ``L`` — data-dependent
-branching is still gone (padding removed it at plan time); only the
-trip count varies per block, carried in the scalar register file like
-the paper's ``r10/r11`` row bounds.
-
-Operand staging (DESIGN.md §7.3/§7.5/§7.7) comes in two modes:
-
-  resident  X is a resident (n, dt) column panel and the gathered value
-            slots are a resident flat VMEM buffer — the whole-panel
-            staging the per-segment kernel used.  Kept as the
-            interpret-mode default and the micro-oracle the staged path
-            is held bit-identical to.
-  dma       ``spmm_ell_fused_staged``: the slot and column streams stay
-            in HBM (``memory_space=ANY``) and each row-block's panel —
-            the contiguous ``[off, off + span)`` window its descriptor
-            names — is DMA'd into one of two VMEM/SMEM buffers, with
-            block N+1's panels prefetched by async copy while block N
-            computes (double buffering, DESIGN.md §7.7).  VMEM then
-            holds 2·max_span slots instead of the whole flat buffer.
-            The X column panel stays resident here (the scalar-row
-            gather touches arbitrary X rows); the mixed kernel's MXU
-            path streams X too (see spmm_bcsr_fused).
-
-The kernel writes workspace rows (segment order, padded); the caller
-maps them back to output rows with ONE inverse-permutation gather
-instead of one scatter per segment.
-
-Multi-chip (``spmm_ell_fused_sharded``): the planner's
-``ShardedFusedWorkspace`` stacks one descriptor table per chip row
-range, and ``shard_map`` over a 1-D ``("chips",)`` mesh runs the SAME
-single-dispatch kernel on every chip — one ``pallas_call`` per chip per
-forward, descriptor/slot arrays sharded on their leading chip axis, X
-either replicated or row-sharded with a plan-time exact-panel exchange
-(``x_sharding="rows"``, DESIGN.md §7.8).  Staged DMA windows are per
-chip (``_staged_dispatch``) so a hot shard sizes only its own ring.
+A pure-ELL plan is the mixed plan with every block tagged VPU and the
+column stream slot-parallel (``coff == off``), so these entry points run
+the mixed kernel of :mod:`.spmm_bcsr_fused` with exactly that table —
+one kernel body, one set of lowerings (resident, dma, sharded), and the
+same VPU arithmetic the ELL kernel always had.
 """
 from __future__ import annotations
 
-import functools
-
-import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
-from jax.sharding import PartitionSpec as P
 
-try:                                   # jax >= 0.6 promotes it to jax.*
-    from jax import shard_map as _shard_map
-except ImportError:                    # jax 0.4/0.5
-    from jax.experimental.shard_map import shard_map as _shard_map
+from .spmm_bcsr_fused import (spmm_bcsr_fused, spmm_bcsr_fused_sharded,
+                              spmm_bcsr_fused_staged)
 
 
-def _kernel(off_ref, L_ref, cols_ref, vals_ref, x_ref, y_ref, *,
-            bm: int, dt: int, mw: int = 1):
-    """One grid step = one merged trip of ``mw`` consecutive block-row
-    descriptors (CGCM, DESIGN.md §7.9; ``mw == 1`` is the classic
-    one-block step).  The sub-blocks unroll statically — each keeps its
-    own descriptor, trip loop, and (bm, dt) accumulator slice, so every
-    row still reduces its lanes separately in-register and the result
-    is bit-identical to the unmerged grid."""
-    g = pl.program_id(0)
-
-    def sub_block(off, L):
-        def nnz_step(nz, acc):
-            # bm independent gather+FMA chains (static unroll == ILP)
-            xs, vs = [], []
-            for rr in range(bm):
-                s = off + rr * L + nz
-                k = cols_ref[s]                      # SMEM scalar read
-                xs.append(x_ref[pl.ds(k, 1), :])     # (1, dt) CCM row
-                vs.append(vals_ref[pl.ds(s, 1)])     # (1,) slot value
-            xg = jnp.concatenate(xs, axis=0)         # (bm, dt)
-            v = jnp.concatenate(vs, axis=0)          # (bm,)
-            return acc + (v[:, None].astype(jnp.float32)
-                          * xg.astype(jnp.float32))
-        acc = jnp.zeros((bm, dt), dtype=jnp.float32)  # vxorps analogue
-        return jax.lax.fori_loop(0, L, nnz_step, acc)  # structure trips
-
-    accs = [sub_block(off_ref[g * mw + w], L_ref[g * mw + w])
-            for w in range(mw)]
-    acc = accs[0] if mw == 1 else jnp.concatenate(accs, axis=0)
-    y_ref[...] = acc.astype(y_ref.dtype)             # one store per step
+def spmm_ell_fused(blk_off, blk_L, cols_flat, vals_flat, x, cont=None, *,
+                   bm: int = 8, mw: int = 1, interpret: bool = True):
+    """Y_ws (B*bm, d_pad) = plan · X for a pure-ELL descriptor table
+    (see :func:`~.spmm_bcsr_fused.spmm_bcsr_fused`)."""
+    return spmm_bcsr_fused(jnp.zeros_like(blk_off), blk_off, blk_off,
+                           blk_L, cols_flat, vals_flat, x, cont, bm=bm,
+                           mw=mw, interpret=interpret)
 
 
-def _staged_kernel(off_ref, L_ref, cols_ref, vals_ref, x_ref, y_ref,
-                   cbuf, vbuf, csem, vsem, *, bm: int, dt: int,
-                   span: int, cspan: int, mw: int = 1):
-    """Double-buffered twin of :func:`_kernel` (DESIGN.md §7.7).
-
-    ``cols_ref``/``vals_ref`` live in HBM; each merged trip's panel is
-    the fixed window ``[off, off + span)`` starting at the trip's FIRST
-    descriptor (the planner sizes ``span`` to the merged extent and
-    tail-pads the flat streams so it is always in bounds — the member
-    blocks' slots are contiguous, so one copy covers all ``mw``
-    sub-blocks).  Panels for trip ``g + 1`` start copying into the
-    alternate buffer while trip ``g`` computes; the descriptor stream
-    itself stays scalar-prefetched.  Each DMA is started exactly once
-    (at the trip's first d-tile) and waited exactly once (at the
-    consumer trip's first d-tile).
-    """
-    g = pl.program_id(0)
-    j = pl.program_id(1)
-    ng = pl.num_programs(0)
-
-    def panel_dmas(slot, grp):
-        off = off_ref[grp * mw]
-        return (
-            pltpu.make_async_copy(cols_ref.at[pl.ds(off, cspan)],
-                                  cbuf.at[slot], csem.at[slot]),
-            pltpu.make_async_copy(vals_ref.at[pl.ds(off, span)],
-                                  vbuf.at[slot], vsem.at[slot]),
-        )
-
-    @pl.when((g == 0) & (j == 0))
-    def _warmup():
-        for dma in panel_dmas(0, 0):
-            dma.start()
-
-    @pl.when((j == 0) & (g + 1 < ng))
-    def _prefetch_next():
-        for dma in panel_dmas((g + 1) % 2, g + 1):
-            dma.start()
-
-    @pl.when(j == 0)
-    def _arrive():
-        for dma in panel_dmas(g % 2, g):
-            dma.wait()
-
-    slot = g % 2
-
-    def sub_block(base, L):
-        def nnz_step(nz, acc):
-            # identical accumulation order to the resident kernel — the
-            # staged path must stay BIT-identical, only the operand
-            # source moves from a resident flat buffer to the panel
-            xs, vs = [], []
-            for rr in range(bm):
-                s = base + rr * L + nz               # panel-local slot
-                k = cbuf[slot, s]                    # SMEM scalar read
-                xs.append(x_ref[pl.ds(k, 1), :])     # (1, dt) CCM row
-                vs.append(vbuf[slot, pl.ds(s, 1)])   # (1,) slot value
-            xg = jnp.concatenate(xs, axis=0)         # (bm, dt)
-            v = jnp.concatenate(vs, axis=0)          # (bm,)
-            return acc + (v[:, None].astype(jnp.float32)
-                          * xg.astype(jnp.float32))
-        return jax.lax.fori_loop(0, L, nnz_step,
-                                 jnp.zeros((bm, dt), jnp.float32))
-
-    # sub-block w's slots sit at its descriptor's offset relative to the
-    # trip's window start (0 when unmerged — no extra scalar math)
-    accs = [sub_block(0 if mw == 1
-                      else off_ref[g * mw + w] - off_ref[g * mw],
-                      L_ref[g * mw + w])
-            for w in range(mw)]
-    acc = accs[0] if mw == 1 else jnp.concatenate(accs, axis=0)
-    y_ref[...] = acc.astype(y_ref.dtype)
+def spmm_ell_fused_staged(blk_off, blk_L, cols_flat, vals_flat, x,
+                          cont=None, *, span: int, cspan: int, bm: int = 8,
+                          mw: int = 1, interpret: bool = True):
+    """The DMA-staged twin (DESIGN.md §7.7), bit-identical output."""
+    return spmm_bcsr_fused_staged(
+        jnp.zeros_like(blk_off), blk_off, blk_off, blk_L, cols_flat,
+        vals_flat, x, cont, span=span, cspan=cspan, bm=bm, mw=mw,
+        interpret=interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("bm", "mw", "interpret"))
-def spmm_ell_fused(blk_off: jax.Array, blk_L: jax.Array,
-                   cols_flat: jax.Array, vals_flat: jax.Array,
-                   x: jax.Array, *, bm: int = 8, mw: int = 1,
-                   interpret: bool = True) -> jax.Array:
-    """Compute ALL plan segments: Y_ws (ws_rows, d_pad) = plan · X.
-
-    blk_off   : (B,) int32 — first slot of each row-block (descriptor)
-    blk_L     : (B,) int32 — padded nnz/row of each row-block
-    cols_flat : (S,) int32 — slot -> X row, scalar-prefetched structure
-    vals_flat : (S,) float — slot values, zero on padding slots
-    x         : (n, d_pad) float — d already padded to the lane tile
-    mw        : CGCM merge width (DESIGN.md §7.9) — descriptors per
-                grid step; the planner pads B to a multiple of it
-
-    Returns workspace-ordered rows; the caller applies the plan's
-    ``inv_perm`` gather to recover output row order.
-    """
-    from ..core.ccm import kernel_lane_tile  # lazy: core imports kernels
-
-    num_blocks = blk_off.shape[0]
-    assert num_blocks % mw == 0, (num_blocks, mw)
-    (S,) = vals_flat.shape
-    n, d_pad = x.shape
-    dt = kernel_lane_tile(d_pad)
-    grid = (num_blocks // mw, d_pad // dt)
-
-    return pl.pallas_call(
-        functools.partial(_kernel, bm=bm, dt=dt, mw=mw),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((S, ), lambda g, j, off, L, cols: (0,)),
-                pl.BlockSpec((n, dt), lambda g, j, off, L, cols: (0, j)),
-            ],
-            out_specs=pl.BlockSpec((mw * bm, dt),
-                                   lambda g, j, off, L, cols: (g, j)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((num_blocks * bm, d_pad),
-                                       jnp.float32),
-        interpret=interpret,
-    )(blk_off, blk_L, cols_flat, vals_flat, x)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("bm", "mw", "span", "cspan", "interpret"))
-def spmm_ell_fused_staged(blk_off: jax.Array, blk_L: jax.Array,
-                          cols_flat: jax.Array, vals_flat: jax.Array,
-                          x: jax.Array, *, span: int, cspan: int,
-                          bm: int = 8, mw: int = 1,
-                          interpret: bool = True) -> jax.Array:
-    """The DMA-staged fused dispatch (DESIGN.md §7.7) — same contract as
-    :func:`spmm_ell_fused` and BIT-identical output.
-
-    ``span``/``cspan`` are the workspace's ``max_span``/``max_cspan``:
-    the static per-merged-trip DMA window over the slot/column streams
-    (per block when ``mw == 1``).  The streams keep
-    ``memory_space=ANY`` (HBM on TPU) and only two ``span``-slot panels
-    are resident per buffer — the production answer to the resident
-    path's whole-flat-buffer VMEM footprint.
-    """
-    from ..core.ccm import kernel_lane_tile  # lazy: core imports kernels
-
-    num_blocks = blk_off.shape[0]
-    assert num_blocks % mw == 0, (num_blocks, mw)
-    n, d_pad = x.shape
-    dt = kernel_lane_tile(d_pad)
-    grid = (num_blocks // mw, d_pad // dt)
-
-    return pl.pallas_call(
-        functools.partial(_staged_kernel, bm=bm, dt=dt, span=span,
-                          cspan=cspan, mw=mw),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec(memory_space=pltpu.ANY),     # cols (HBM)
-                pl.BlockSpec(memory_space=pltpu.ANY),     # vals (HBM)
-                pl.BlockSpec((n, dt), lambda g, j, off, L: (0, j)),
-            ],
-            out_specs=pl.BlockSpec((mw * bm, dt),
-                                   lambda g, j, off, L: (g, j)),
-            scratch_shapes=[
-                pltpu.SMEM((2, cspan), jnp.int32),        # cols panels
-                pltpu.VMEM((2, span), jnp.float32),       # value panels
-                pltpu.SemaphoreType.DMA((2,)),
-                pltpu.SemaphoreType.DMA((2,)),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((num_blocks * bm, d_pad),
-                                       jnp.float32),
-        interpret=interpret,
-    )(blk_off, blk_L, cols_flat, vals_flat, x)
-
-
-def _chip_windows(v, n_chips: int) -> tuple:
-    """Normalize a DMA window argument to a per-chip tuple: ints (the
-    uniform/legacy spelling) broadcast; sequences — tuple/list/ndarray,
-    e.g. ``ShardedFusedWorkspace.chip_span`` — pass through."""
-    if hasattr(v, "__len__"):
-        if len(v) != n_chips:
-            raise ValueError(
-                f"per-chip DMA windows need one entry per chip: got "
-                f"{len(v)} for {n_chips} chips")
-        return tuple(int(s) for s in v)
-    return (int(v),) * n_chips
-
-
-def _staged_dispatch(axis: str, spans: tuple, cspans: tuple, call):
-    """Per-chip staged-kernel specialization (the hot-shard window fix).
-
-    Chips are grouped by distinct (span, cspan) window and each group
-    gets its own staged kernel with a scratch ring sized for THAT
-    window; ``lax.switch`` on the chip axis index picks the group, so a
-    cold chip's VMEM ring no longer scales with the hottest shard's
-    span.  Each chip still executes exactly one ``pallas_call`` (with a
-    uniform window the switch collapses to a direct call and the traced
-    body keeps a single pallas_call, as before).
-
-    ``call(span, cspan)`` must return the kernel callable for one
-    window; returns a function of the per-chip operands.
-    """
-    groups = sorted(set(zip(spans, cspans)))
-    if len(groups) == 1:
-        return call(*groups[0])
-    idx = [groups.index(w) for w in zip(spans, cspans)]
-
-    def dispatch(*operands):
-        branch = jnp.asarray(idx, jnp.int32)[jax.lax.axis_index(axis)]
-        return jax.lax.switch(branch, [call(*g) for g in groups],
-                              *operands)
-    return dispatch
-
-
-def spmm_ell_fused_sharded(blk_off: jax.Array, blk_L: jax.Array,
-                           cols_flat: jax.Array, vals_flat: jax.Array,
-                           x: jax.Array, *, mesh, bm: int = 8,
-                           mw: int = 1, interpret: bool = True,
-                           staging: str = "resident", span=0,
-                           cspan=0, x_sharding: str = "replicated",
-                           x_send=None, x_recv=None) -> jax.Array:
-    """Run one fused dispatch per chip under ``shard_map``.
-
-    blk_off/blk_L     : (C, B) int32 — per-chip descriptor tables
-    cols_flat         : (C, S) int32 — per-chip slot -> X row (LOCAL
-                        panel-space rows when ``x_sharding="rows"``)
-    vals_flat         : (C, S) float — per-chip slot values
-    x                 : the dense operand, in the layout ``x_sharding``
-                        demands — (n, d_pad) replicated, or the stacked
-                        (C, P, bk, d_pad) owned-panel strips for "rows"
-    mesh              : 1-D mesh of C devices (axis name is free)
-
-    Returns (C, B*bm, d_pad) workspace rows, sharded over the chip axis;
-    the caller flattens and applies the sharded workspace's GLOBAL
-    ``inv_perm`` gather to recover output row order.
-
-    The body is traced once and SPMD-replicated: each of the C devices
-    executes exactly one ``pallas_call`` over its own descriptor shard,
-    so a forward costs C dispatches total — the multi-chip extension of
-    the one-artifact-per-instance invariant (paper Table IV).
-
-    ``staging="dma"`` lowers each chip's dispatch through
-    :func:`spmm_ell_fused_staged`; ``span``/``cspan`` may be per-chip
-    tuples (see :func:`_staged_dispatch`).  ``x_sharding="rows"``
-    assembles each chip's compact X workspace from the owning chips via
-    the planner's exact-panel exchange (``x_send``/``x_recv`` tables,
-    DESIGN.md §7.8) before the kernel runs — one collective plus one
-    ``pallas_call`` per chip, bit-identical to the replicated path.
-    """
-    fn = _sharded_callable(mesh, bm, interpret, staging,
-                           _chip_windows(span, mesh.size),
-                           _chip_windows(cspan, mesh.size), x_sharding,
-                           mw)
-    if x_sharding == "rows":
-        return fn(blk_off, blk_L, cols_flat, vals_flat, x, x_send, x_recv)
-    return fn(blk_off, blk_L, cols_flat, vals_flat, x)
-
-
-@functools.lru_cache(maxsize=32)
-def _sharded_callable(mesh, bm: int, interpret: bool,
-                      staging: str = "resident", spans: tuple = (0,),
-                      cspans: tuple = (0,),
-                      x_sharding: str = "replicated", mw: int = 1):
-    """jit-wrapped shard_map closure, memoized per (mesh, bm, interpret,
-    staging, spans, cspans, x_sharding, mw) so repeated forwards reuse
-    one compiled executable instead of rebuilding and retracing the
-    shard_map every call (Mesh is hashable; input-shape specialization
-    is jit's usual cache).  Bounded, and evicted by
-    ``core.jit_cache.clear_global_cache`` so compiled state and device
-    handles don't outlive the caches that reference them."""
-    from ..distributed.collectives import exact_panel_exchange
-
-    (axis,) = mesh.axis_names
-
-    if staging == "dma":
-        def call(sp, cs):
-            return functools.partial(spmm_ell_fused_staged, span=sp,
-                                     cspan=cs, bm=bm, mw=mw,
-                                     interpret=interpret)
-        kernel = _staged_dispatch(axis, spans, cspans, call)
-    else:
-        kernel = functools.partial(spmm_ell_fused, bm=bm, mw=mw,
-                                   interpret=interpret)
-
-    shard = P(axis)
-    if x_sharding == "rows":
-        def per_chip(off, L, cols, vals, xo, xs, xr):
-            xp = exact_panel_exchange(xo[0], xs[0], xr[0], axis)
-            return kernel(off[0], L[0], cols[0], vals[0], xp)[None]
-        specs = dict(in_specs=(shard,) * 7, out_specs=shard)
-    else:
-        def per_chip(off, L, cols, vals, xp):
-            return kernel(off[0], L[0], cols[0], vals[0], xp)[None]
-        specs = dict(in_specs=(shard, shard, shard, shard, P()),
-                     out_specs=shard)
-    try:
-        fn = _shard_map(per_chip, mesh=mesh, check_rep=False, **specs)
-    except TypeError:      # jax >= 0.7 renamed the replication check
-        fn = _shard_map(per_chip, mesh=mesh, check_vma=False, **specs)
-    return jax.jit(fn)
+def spmm_ell_fused_sharded(blk_off, blk_L, cols_flat, vals_flat, x,
+                           cont=None, *, mesh, bm: int = 8, mw: int = 1,
+                           interpret: bool = True,
+                           staging: str = "resident", span=0, cspan=0,
+                           x_sharding: str = "replicated", x_send=None,
+                           x_recv=None):
+    """One fused dispatch per chip under ``shard_map`` over (C, B)
+    per-chip tables (see
+    :func:`~.spmm_bcsr_fused.spmm_bcsr_fused_sharded`)."""
+    return spmm_bcsr_fused_sharded(
+        jnp.zeros_like(blk_off), blk_off, blk_off, blk_L, cols_flat,
+        vals_flat, x, cont, mesh=mesh, bm=bm, mw=mw, interpret=interpret,
+        staging=staging, span=span, cspan=cspan, x_sharding=x_sharding,
+        x_send=x_send, x_recv=x_recv)

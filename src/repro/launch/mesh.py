@@ -6,6 +6,14 @@ state (the dry-run must set XLA_FLAGS before any jax initialization).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """A mesh whose axes all use GSPMD's automatic sharding propagation
+    (``jax.make_mesh`` now defaults to explicit axes, under which a
+    gather on a sharded array must name its output sharding)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -13,14 +21,14 @@ def make_production_mesh(*, multi_pod: bool = False):
     "pod" axis (the DCN dimension)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(*, data: int = 1, model: int = 1):
     """Small mesh over whatever devices exist (tests on CPU)."""
     n = len(jax.devices())
     assert data * model <= n, (data, model, n)
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def make_chip_mesh(n_chips: int):

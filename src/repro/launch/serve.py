@@ -66,6 +66,7 @@ from ..core.spmm import (FUSED_BACKENDS, PlanVerificationError,
 from ..data.pipeline import DeviceStage
 from ..kernels.ops import resolve_interpret
 from ..models.model import Model
+from ..platform import use_compile_cache
 
 
 # -- LM generate driver ------------------------------------------------------
@@ -774,7 +775,7 @@ def run_spmm_smoke() -> int:
     response must match the ref backend, and the scheduler's outputs
     must be bit-identical to the direct rounds — exit 0 on success."""
     from ..core.spmm import spmm
-    server = SpmmServer(interpret=True, max_batch=4)
+    server = SpmmServer(max_batch=4)
     requests = _smoke_requests()
     t0 = time.perf_counter()
     first = server.serve(requests)
@@ -853,6 +854,7 @@ def main() -> int:
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen", type=int, default=16)
     args = ap.parse_args()
+    use_compile_cache()
     if args.arch is not None:
         return _run_lm(args)
     if not args.smoke:

@@ -24,6 +24,7 @@ from ..ft import checkpoint as ckpt
 from ..ft.watchdog import StepTimeout, Watchdog
 from ..models.model import Model
 from ..optim.adamw import AdamW, warmup_cosine
+from ..platform import use_compile_cache
 from ..train.train_step import make_train_step
 from .mesh import make_chip_mesh, make_host_mesh
 
@@ -253,6 +254,7 @@ def main():
                          "DESIGN.md §11) and validates + caches the "
                          "winning config")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

@@ -392,3 +392,67 @@ def test_sattn_layer_matches_dense_masked_attention():
     want = x + jnp.einsum("bshk,hkd->bsd", out, p["wo"])
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("mixed", (False, True))
+def test_split_blocks_keep_the_softmax_exact(mixed, pack_with_window):
+    """Blocks split into piece trips carry the softmax state (acc, m, l)
+    from trip to trip: at a tiny staging window both lowerings
+    reproduce the unsplit plan bit for bit."""
+    from repro.core import (build_fused_workspace, build_mixed_plan,
+                            build_plan, workspace_row_map)
+    a = _mask(m=40, n=48, density=0.4, seed=3)
+    q, k, v = _qkv(40, 48, 16, 16, seed=4)
+    build = build_mixed_plan if mixed else build_plan
+    plan = build(a.row_ptr, a.col_indices, a.shape, 16)
+
+    def run(ws, staging):
+        vals = jnp.concatenate([a.vals, jnp.zeros((1,), jnp.float32)])
+        q_ext = jnp.pad(q * 16 ** -0.5, ((0, 1), (0, 112)))
+        row_map = jnp.asarray(workspace_row_map(
+            ws.inv_perm, ws.ws_rows, ws.blk_cont,
+            ws.merge_width * ws.row_block))
+        y = ops.attn_fused_op(
+            jnp.asarray(ws.blk_tag), jnp.asarray(ws.blk_off),
+            jnp.asarray(ws.blk_coff), jnp.asarray(ws.blk_L),
+            jnp.asarray(ws.cols_flat), vals[jnp.asarray(ws.gather_flat)],
+            q_ext[row_map], jnp.pad(k, ((0, 0), (0, 112))),
+            jnp.pad(v, ((0, 0), (0, 112))), jnp.asarray(ws.blk_cont),
+            bk=ws.bk, mw=ws.merge_width, interpret=True, staging=staging,
+            span=ws.max_span, cspan=ws.max_cspan)
+        return np.asarray(y[jnp.asarray(ws.inv_perm), :16])
+
+    ws0 = build_fused_workspace(plan)
+    ws = pack_with_window(plan, 32)
+    assert ws0.blk_cont.sum() == 0 < ws.blk_cont.sum()
+    y0 = run(ws0, "resident")
+    np.testing.assert_allclose(y0, _dense_oracle(a, a.vals, q, k, v),
+                               rtol=1e-4, atol=1e-4)
+    for staging in ("resident", "dma"):
+        assert np.array_equal(run(ws, staging), y0)
+
+
+@pytest.mark.parametrize("sharded", (False, True))
+def test_split_blocks_through_compile_sparse_attention(sharded,
+                                                       monkeypatch):
+    """The artifact stages Q into every piece of a split block (the
+    row map repeats the last piece's rows) and matches its unsplit twin
+    bit for bit, single-chip and chip-stacked."""
+    from repro import platform
+    from repro.core import plan as plan_mod
+    a = _mask(m=40, n=48, density=0.4, seed=5)
+    q, k, v = _qkv(40, 48, 16, 16, seed=6)
+
+    def run():
+        art = compile_sparse_attention(
+            a, 16, backend="pallas_bcsr", interpret=True, staging="dma",
+            n_chips=MAX_CHIPS if sharded else None, cache=JitCache())
+        consts = art._sharded if sharded else art._fused
+        return np.asarray(art(a.vals, q, k, v)), int(consts.cont.sum())
+
+    y0, pieces0 = run()
+    monkeypatch.setattr(plan_mod, "stage_limits", lambda: platform.StageLimits(
+        window=32, descs=platform.stage_limits().descs))
+    y, pieces = run()
+    assert pieces0 == 0 < pieces
+    assert np.array_equal(y, y0)

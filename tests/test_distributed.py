@@ -79,11 +79,11 @@ def test_distributed_spmm_row_partition():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.core import random_csr, partition_rows_for_chips
         from repro.kernels.ref import spmm_dense_ref
 
-        mesh = jax.make_mesh((8,), ("chips",))
+        mesh = jax.make_mesh((8,), ("chips",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         a = random_csr(64, 40, density=0.2, family="powerlaw", seed=3)
         x = jnp.asarray(np.random.default_rng(0).standard_normal((40, 16)),
                         jnp.float32)
@@ -99,9 +99,9 @@ def test_distributed_spmm_row_partition():
         def chip_fn(a_local, x_full):
             return (a_local[0] @ x_full)[None]
 
-        y_sh = shard_map(chip_fn, mesh=mesh,
-                         in_specs=(P("chips", None, None), P(None, None)),
-                         out_specs=P("chips", None, None))(
+        y_sh = jax.shard_map(chip_fn, mesh=mesh,
+                             in_specs=(P("chips", None, None), P(None, None)),
+                             out_specs=P("chips", None, None))(
             jnp.asarray(a_pad), x)
         y = np.concatenate([np.asarray(y_sh[c, : bounds[c+1]-bounds[c]])
                             for c in range(8)])

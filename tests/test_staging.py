@@ -27,10 +27,11 @@ import numpy as np
 import pytest
 
 from repro.core import (CSRMatrix, MXU_TAG, build_mixed_plan,
-                        build_fused_workspace, build_sharded_workspace,
+                        build_fused_workspace, build_plan,
+                        build_sharded_workspace, choose_merge_width,
                         compile_spmm, random_csr, spmm)
 from repro.core.jit_cache import JitCache
-from repro.core.plan import STRATEGIES, STAGE_TILE
+from repro.core.plan import LANE, STRATEGIES, STAGE_TILE
 from repro.kernels import ops
 from repro.kernels.ops import resolve_staging
 
@@ -84,6 +85,105 @@ def test_staged_bit_identical_on_skewed_powerlaw(backend):
     y_dma = spmm(a, x, backend=backend, interpret=True,
                  staging="dma", cache=JitCache())
     assert np.array_equal(np.asarray(y_dma), np.asarray(y_res))
+
+
+def _run_workspace(ws, a, x, staging):
+    """Dispatch a hand-packed workspace through the op layer."""
+    vals = jnp.concatenate([jnp.asarray(a.vals, jnp.float32),
+                            jnp.zeros((1,), jnp.float32)])
+    x_pad = jnp.pad(x, ((0, 0), (0, -x.shape[1] % 128)))
+    y = ops.spmm_bcsr_fused_op(
+        jnp.asarray(ws.blk_tag), jnp.asarray(ws.blk_off),
+        jnp.asarray(ws.blk_coff), jnp.asarray(ws.blk_L),
+        jnp.asarray(ws.cols_flat), vals[jnp.asarray(ws.gather_flat)], x_pad,
+        jnp.asarray(ws.blk_cont), bk=ws.bk, mw=ws.merge_width,
+        interpret=True, staging=staging, span=ws.max_span,
+        cspan=ws.max_cspan)
+    return np.asarray(y[jnp.asarray(ws.inv_perm), :x.shape[1]])
+
+
+@pytest.mark.parametrize("mixed", (False, True))
+@pytest.mark.parametrize("threshold", (0, 16))
+def test_split_blocks_bit_identical_to_unsplit(mixed, threshold,
+                                              pack_with_window):
+    """A block wider than the platform's staging window is packed as
+    piece trips that carry the accumulator.  At a tiny window every
+    long block splits, and both lowerings still reproduce the unsplit
+    result bit for bit."""
+    a = _mixed_csr(seed=10)
+    x = _x(a.n, 20, seed=11)
+    build = build_mixed_plan if mixed else build_plan
+    plan = build(a.row_ptr, a.col_indices, a.shape, 20)
+    mw = choose_merge_width(a.row_ptr, merge_threshold=threshold)
+    ws0 = build_fused_workspace(plan, merge_width=mw)
+    ws = pack_with_window(plan, 32, merge_width=mw)
+    assert ws0.blk_cont.sum() == 0 < ws.blk_cont.sum()
+    assert ws.max_span < ws0.max_span or ws0.max_span <= 2 * STAGE_TILE
+    y0 = _run_workspace(ws0, a, x, "resident")
+    for staging in ("resident", "dma"):
+        assert np.array_equal(_run_workspace(ws, a, x, staging), y0)
+
+
+@pytest.mark.parametrize("path", ("solo", "sharded", "batched", "grad"))
+def test_split_blocks_through_the_entry_points(path, monkeypatch):
+    """Piece trips reach every dispatch the entry points build — solo,
+    chip-stacked, request-stacked, and the transposed artifact of the
+    gradient — and each still matches its unsplit twin bit for bit."""
+    from repro import platform
+    from repro.core import compile_batched_spmm
+    from repro.core import plan as plan_mod
+    a = _mixed_csr(seed=14)
+    x = _x(a.n, 16, seed=15)
+    vals = jnp.asarray(a.vals)
+
+    def run():
+        """(output, piece trips in the dispatched tables)"""
+        if path == "batched":
+            c = compile_batched_spmm([a, a], 16, backend="pallas_bcsr",
+                                     interpret=True, staging="dma",
+                                     cache=JitCache())
+            y = c([vals, vals], [x, x])[1]
+            return np.asarray(y), int(c._consts.cont.sum())
+        c = compile_spmm(a, 16, backend="pallas_bcsr", interpret=True,
+                         staging="dma", cache=JitCache(),
+                         n_chips=MAX_CHIPS if path == "sharded" else None)
+        if path == "grad":
+            y = jax.grad(lambda v, xx: jnp.sum(c(v, xx) ** 2),
+                         argnums=1)(vals, x)
+            return np.asarray(y), int(c._transpose._fused.cont.sum())
+        consts = c._sharded if path == "sharded" else c._fused
+        return np.asarray(c(vals, x)), int(consts.cont.sum())
+
+    y0, pieces0 = run()
+    monkeypatch.setattr(plan_mod, "stage_limits", lambda: platform.StageLimits(
+        window=32, descs=platform.stage_limits().descs))
+    y, pieces = run()
+    assert pieces0 == 0 < pieces
+    assert np.array_equal(y, y0)
+
+
+def test_long_stream_issued_as_calls_bit_identical(monkeypatch,
+                                                  pack_with_window):
+    """A stream with more descriptors than one call's SMEM tables hold
+    runs as a sequence of calls, a split block continuing across a call
+    boundary; the output equals the single call's bit for bit and every
+    call counts as a launch."""
+    from repro import platform
+    from repro.kernels import staging as staging_mod
+    a = _mixed_csr(seed=12)
+    x = _x(a.n, 16, seed=13)
+    plan = build_mixed_plan(a.row_ptr, a.col_indices, a.shape, 16)
+    ws = pack_with_window(plan, 32)
+    per = int(np.flatnonzero(ws.blk_cont)[0])   # a piece opens call 2
+    assert per > 0 and ws.num_blocks > per
+    y1 = _run_workspace(ws, a, x, "dma")
+    monkeypatch.setattr(staging_mod, "stage_limits",
+                        lambda: platform.StageLimits(window=32, descs=per))
+    jax.clear_caches()
+    ops.reset_dispatch_counts()
+    y = _run_workspace(ws, a, x, "dma")
+    assert ops.DISPATCH_COUNTS["bcsr_fused_dma"] == -(-ws.num_blocks // per)
+    assert np.array_equal(y, y1)
 
 
 @pytest.mark.parametrize("backend", FUSED)
@@ -262,13 +362,15 @@ def test_workspace_staging_metadata_invariants():
     plan = build_mixed_plan(a.row_ptr, a.col_indices, a.shape, 16)
     ws = build_fused_workspace(plan)
     assert np.any(ws.blk_tag == MXU_TAG)
-    bm, bk = ws.row_block, ws.bk
+    bm = ws.row_block
     L = ws.blk_L.astype(np.int64)
     mxu = ws.blk_tag == MXU_TAG
+    # MXU value panels are lane-padded to (bm, LANE) aligned tiles
     np.testing.assert_array_equal(
-        ws.blk_span, np.where(mxu, L * bm * bk, bm * L))
+        ws.blk_span, np.where(mxu, L * bm * LANE, bm * L))
     np.testing.assert_array_equal(
         ws.blk_cspan, np.where(mxu, L, bm * L))
+    assert np.all(ws.blk_off[mxu] % STAGE_TILE == 0)
     assert ws.max_span % STAGE_TILE == 0
     assert ws.max_cspan % STAGE_TILE == 0
     assert ws.max_span >= int(ws.blk_span.max(initial=0))
@@ -287,7 +389,7 @@ def test_sharded_workspace_windows_cover_every_chip():
         # and max_span stays the cross-chip max for introspection
         L = sw.blk_L.astype(np.int64)
         spans = np.where(sw.blk_tag == MXU_TAG,
-                         L * sw.row_block * sw.bk, sw.row_block * L)
+                         L * sw.row_block * LANE, sw.row_block * L)
         cspans = np.where(sw.blk_tag == MXU_TAG, L, sw.row_block * L)
         chip_span = np.asarray(sw.chip_span)
         chip_cspan = np.asarray(sw.chip_cspan)

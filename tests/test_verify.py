@@ -19,8 +19,10 @@ from repro.analysis.verify import (VALIDATE_MODES, PlanVerificationError,
                                    verify_attention_contract,
                                    verify_workspace)
 from repro.core.csr import CSRMatrix, random_csr
-from repro.core.plan import (SPARSE_ATTN_EINSUM, build_batched_workspace,
-                             build_sharded_workspace, build_workspace)
+from repro.core.plan import (MXU_TAG, SPARSE_ATTN_EINSUM,
+                             build_batched_workspace, build_fused_workspace,
+                             build_mixed_plan, build_sharded_workspace,
+                             build_workspace)
 
 
 def _kinds(violations):
@@ -51,6 +53,17 @@ def _batched(R=3, m=24, n=32, *, d=16, seed=2):
             for r in range(R)]
     structures = [(a.row_ptr, a.col_indices, a.shape) for a in mats]
     return mats, build_batched_workspace(structures, d)
+
+
+def _mixed_csr_for_verify(m=48, n=64, seed=0):
+    """Two dense block-rows (MXU) over a ragged sparse tail (VPU)."""
+    rng = np.random.default_rng(seed)
+    dense = np.zeros((m, n), np.float32)
+    for i in range(16):
+        dense[i, (i // 8) * 16:(i // 8) * 16 + 16] = 1.0
+    for i in range(16, m):
+        dense[i, rng.choice(n, size=2, replace=False)] = 1.0
+    return CSRMatrix.from_dense(dense)
 
 
 # -- mutation tests: one corruption per invariant class ----------------------
@@ -142,6 +155,19 @@ def test_dma_window_undersized():
     assert span > 1, "need a real extent wider than the shrunk window"
     bad = dataclasses.replace(ws, max_span=1)
     assert "dma_window" in _kinds(verify_workspace(bad, n_cols=a.n))
+
+
+def test_mxu_alignment_unaligned_panels():
+    a = _mixed_csr_for_verify()
+    plan = build_mixed_plan(a.row_ptr, a.col_indices, a.shape, 16)
+    ws = build_fused_workspace(plan)
+    assert _kinds(verify_workspace(ws, n_cols=a.n)) == set()
+    mxu = np.flatnonzero(ws.blk_tag == MXU_TAG)
+    assert mxu.size
+    off = ws.blk_off.copy()
+    off[mxu] -= 8              # still monotone and in bounds, unaligned
+    bad = dataclasses.replace(ws, blk_off=off)
+    assert "mxu_alignment" in _kinds(verify_workspace(bad, n_cols=a.n))
 
 
 def test_merge_alignment_width_not_dividing_table():
