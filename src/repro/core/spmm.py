@@ -52,7 +52,8 @@ from .plan import (SPARSE_ATTN_EINSUM, SPARSE_ATTN_MIXED_EINSUM,
                    sharded_workspace_row_maps, workspace_row_map)
 from ..analysis.verify import (PlanVerificationError, check_workspace,
                                resolve_validate)
-from ..kernels.ops import resolve_interpret, resolve_staging
+from ..kernels import ops as kops
+from ..kernels.ops import resolve_interpret, resolve_staging, span
 from ..platform import STAGE_TILE, resident_fits
 
 __all__ = [
@@ -444,8 +445,10 @@ class CompiledSpmm:
 
         def _apply_bwd(res, dy):
             vals, x = res
-            dvals = self._sddmm(dy, x).astype(vals.dtype)
-            dx = self._transpose_apply(vals, dy).astype(x.dtype)
+            with span("spmm.sddmm"):
+                dvals = self._sddmm(dy, x).astype(vals.dtype)
+            with span("spmm.transpose"):
+                dx = self._transpose_apply(vals, dy).astype(x.dtype)
             return dvals, dx
 
         _apply.defvjp(_apply_fwd, _apply_bwd)
@@ -505,81 +508,58 @@ class CompiledSpmm:
             return jax.ops.segment_sum(prod, self._expanded_rows(),
                                        num_segments=m,
                                        indices_are_sorted=True)
-        vals_ext = jnp.concatenate(
-            [vals.astype(jnp.float32), jnp.zeros((1,), jnp.float32)])
-        x_pad = ccm.pad_cols(x, self.d_tiling.d_pad)
-        if backend == "pallas_ell":
-            if self._sharded is not None:
-                from ..kernels.ops import spmm_ell_fused_sharded_op
-                sw = self._sharded
-                if sw.num_blocks == 0:
-                    return jnp.zeros((m, d), jnp.float32)
-                # one dispatch PER CHIP for the whole plan: shard_map
-                # splits the stacked descriptor tables on the chip axis
-                vals_flat = vals_ext[sw.gather_flat]
-                xarg = (self._x_row_strips(x_pad)
-                        if sw.x_sharding == "rows" else x_pad)
-                y_ws = spmm_ell_fused_sharded_op(
-                    sw.blk_off, sw.blk_L, sw.cols_flat, vals_flat, xarg,
-                    sw.cont, mesh=sw.mesh, bm=self.bm, mw=sw.merge_width,
-                    interpret=self.interpret,
-                    staging=self.staging, span=sw.chip_span,
-                    cspan=sw.chip_cspan, x_sharding=sw.x_sharding,
-                    x_send=sw.x_send, x_recv=sw.x_recv)
-                # sharded inverse-permutation gather over the flattened
-                # (n_chips * ws_rows) workspace recovers row order
-                y_flat = y_ws.reshape(sw.n_chips * sw.ws_rows, -1)
-                return y_flat[sw.inv_perm, :d]
-            from ..kernels.ops import spmm_ell_fused_op
-            fw = self._fused
-            if fw.num_blocks == 0:
-                return jnp.zeros((m, d), jnp.float32)
-            # one dispatch for the whole plan, whatever the segment count
+        sharded = self._sharded is not None
+        fw = self._sharded if sharded else self._fused
+        if fw.num_blocks == 0:
+            return jnp.zeros((m, d), jnp.float32)
+        # one dispatch (per chip) for the whole plan, whatever the
+        # segment count, between the slot-value gather and one
+        # inverse-permutation gather that recovers row order
+        with span("spmm.stage_vals"):
+            vals_ext = jnp.concatenate(
+                [vals.astype(jnp.float32), jnp.zeros((1,), jnp.float32)])
             vals_flat = vals_ext[fw.gather_flat]
-            y_ws = spmm_ell_fused_op(
-                fw.blk_off, fw.blk_L, fw.cols_flat, vals_flat, x_pad,
-                fw.cont, bm=self.bm, mw=fw.merge_width,
-                interpret=self.interpret, staging=self.staging,
-                span=fw.max_span, cspan=fw.max_cspan)
-            # single inverse-permutation gather replaces N scatters
-            return y_ws[fw.inv_perm, :d]
-        if backend == "pallas_bcsr":
-            # the mixed VPU/MXU plan lowers through the same descriptor-
-            # table machinery as pallas_ell — one dispatch (per chip)
-            if x_pad.shape[0] < self._x_rows_pad:
+        with span("spmm.stage_operands"):
+            x_pad = ccm.pad_cols(x, self.d_tiling.d_pad)
+            if backend == "pallas_bcsr" and (
+                    x_pad.shape[0] < self._x_rows_pad):
                 x_pad = jnp.pad(
                     x_pad,
                     ((0, self._x_rows_pad - x_pad.shape[0]), (0, 0)))
-            if self._sharded is not None:
-                from ..kernels.ops import spmm_bcsr_fused_sharded_op
-                sw = self._sharded
-                if sw.num_blocks == 0:
-                    return jnp.zeros((m, d), jnp.float32)
-                vals_flat = vals_ext[sw.gather_flat]
-                xarg = (self._x_row_strips(x_pad)
-                        if sw.x_sharding == "rows" else x_pad)
-                y_ws = spmm_bcsr_fused_sharded_op(
-                    sw.blk_tag, sw.blk_off, sw.blk_coff, sw.blk_L,
-                    sw.cols_flat, vals_flat, xarg, sw.cont, mesh=sw.mesh,
-                    bm=self.bm, bk=self.bk, mw=sw.merge_width,
-                    interpret=self.interpret,
-                    staging=self.staging, span=sw.chip_span,
-                    cspan=sw.chip_cspan, x_sharding=sw.x_sharding,
-                    x_send=sw.x_send, x_recv=sw.x_recv)
-                y_flat = y_ws.reshape(sw.n_chips * sw.ws_rows, -1)
-                return y_flat[sw.inv_perm, :d]
-            from ..kernels.ops import spmm_bcsr_fused_op
-            fw = self._fused
-            if fw.num_blocks == 0:
-                return jnp.zeros((m, d), jnp.float32)
-            vals_flat = vals_ext[fw.gather_flat]
-            y_ws = spmm_bcsr_fused_op(
-                fw.blk_tag, fw.blk_off, fw.blk_coff, fw.blk_L,
-                fw.cols_flat, vals_flat, x_pad, fw.cont, bm=self.bm,
-                bk=self.bk, mw=fw.merge_width, interpret=self.interpret,
-                staging=self.staging, span=fw.max_span,
-                cspan=fw.max_cspan)
+            if sharded and fw.x_sharding == "rows":
+                x_pad = self._x_row_strips(x_pad)
+        with span("spmm.kernel"):
+            y_ws = self._kernel(fw, vals_flat, x_pad)
+        with span("spmm.unpermute"):
+            if sharded:
+                # the GLOBAL inv_perm indexes the flattened
+                # (n_chips * ws_rows) workspace
+                y_ws = y_ws.reshape(fw.n_chips * fw.ws_rows, -1)
             return y_ws[fw.inv_perm, :d]
+
+    def _kernel(self, fw, vals_flat, x_pad):
+        """The fused dispatch of the plan's constants ``fw``: one
+        pallas_call, or one per chip under shard_map."""
+        knobs = dict(bm=self.bm, mw=fw.merge_width,
+                     interpret=self.interpret, staging=self.staging)
+        if self._sharded is not None:
+            knobs.update(mesh=fw.mesh, span=fw.chip_span,
+                         cspan=fw.chip_cspan, x_sharding=fw.x_sharding,
+                         x_send=fw.x_send, x_recv=fw.x_recv)
+            ell, mixed = (kops.spmm_ell_fused_sharded_op,
+                          kops.spmm_bcsr_fused_sharded_op)
+        else:
+            knobs.update(span=fw.max_span, cspan=fw.max_cspan)
+            ell, mixed = kops.spmm_ell_fused_op, kops.spmm_bcsr_fused_op
+        if self.backend == "pallas_ell":
+            return ell(fw.blk_off, fw.blk_L, fw.cols_flat, vals_flat,
+                       x_pad, fw.cont, **knobs)
+        if self.backend == "pallas_bcsr":
+            # the mixed VPU/MXU plan lowers through the same descriptor-
+            # table machinery as pallas_ell
+            return mixed(fw.blk_tag, fw.blk_off, fw.blk_coff, fw.blk_L,
+                         fw.cols_flat, vals_flat, x_pad, fw.cont,
+                         bk=self.bk, **knobs)
         raise ValueError(self.backend)
 
     # -- gradients ----------------------------------------------------------
@@ -590,7 +570,7 @@ class CompiledSpmm:
         rows, cols = self._expanded_rows(), jnp.asarray(self._col_indices)
         nnz = cols.shape[0]
         n_chunks = max(-(-nnz // _SDDMM_CHUNK), 1)
-        size = -(-nnz // n_chunks)
+        size = max(-(-nnz // n_chunks), 1)     # one padded entry if empty
         pad = n_chunks * size - nnz
 
         def chunk(rc):
@@ -626,7 +606,8 @@ class CompiledSpmm:
         return self._transpose._forward(vals_t, dy)
 
     def __call__(self, vals, x):
-        return self._apply(vals, x)
+        with span("spmm.call"):
+            return self._apply(vals, x)
 
 
 def compile_spmm(a: CSRMatrix, d: int, *, strategy: str = "nnz_split",
@@ -838,16 +819,19 @@ class CompiledBatchedSpmm:
         """``vals``: per-request value vectors (or one pre-concatenated
         array); ``xs``: per-request operands (or the pre-stacked array
         from :meth:`stack_inputs`).  Returns per-request ``(m_r, d)``
-        outputs in request order."""
-        if isinstance(vals, (list, tuple)):
-            vals = jnp.concatenate(
-                [jnp.asarray(v, jnp.float32).ravel() for v in vals])
-        if isinstance(xs, (list, tuple)):
-            xs = jnp.asarray(self.stack_inputs(xs))
-        y = self._jit_forward(vals, xs)
-        rs = self._row_splits
-        return [y[rs[r]:rs[r + 1], :self.d]
-                for r in range(self.n_requests)]
+        outputs in request order.  The forward is one jitted program,
+        so the call has one span and no stage spans: a span inside a
+        trace would time the tracing."""
+        with span("spmm_batched.call"):
+            if isinstance(vals, (list, tuple)):
+                vals = jnp.concatenate(
+                    [jnp.asarray(v, jnp.float32).ravel() for v in vals])
+            if isinstance(xs, (list, tuple)):
+                xs = jnp.asarray(self.stack_inputs(xs))
+            y = self._jit_forward(vals, xs)
+            rs = self._row_splits
+            return [y[rs[r]:rs[r + 1], :self.d]
+                    for r in range(self.n_requests)]
 
 
 def _normalize_batch_merge_threshold(merge_threshold, n_requests: int):
@@ -1119,13 +1103,11 @@ class CompiledSparseAttention:
             num_segments=m)
         return out / jnp.where(denom > 0, denom, 1.0)[:, None]
 
-    def _operands(self, vals, q, k, v):
+    def _operands(self, q, k, v):
         """Stage the dense operands for the kernel: scale folded into
         Q, lane padding on both widths, K/V rows padded to the
-        block-column grid, and the extended (+ one zero row / slot)
-        forms the sentinel gathers rely on."""
-        vals_ext = jnp.concatenate(
-            [vals.astype(jnp.float32), jnp.zeros((1,), jnp.float32)])
+        block-column grid, and Q extended by the zero row the sentinel
+        gather relies on."""
         q_pad = ccm.pad_cols(q.astype(jnp.float32) * self.sm_scale,
                              self._dh_pad)
         q_ext = jnp.concatenate(
@@ -1137,7 +1119,7 @@ class CompiledSparseAttention:
             grow = self._kv_rows_pad - k_pad.shape[0]
             k_pad = jnp.pad(k_pad, ((0, grow), (0, 0)))
             v_pad = jnp.pad(v_pad, ((0, grow), (0, 0)))
-        return vals_ext, q_ext, k_pad, v_pad
+        return q_ext, k_pad, v_pad
 
     # -- forward -----------------------------------------------------------
     def _forward(self, vals, q, k, v):
@@ -1147,39 +1129,37 @@ class CompiledSparseAttention:
         assert v.shape == (n, self.dv), (v.shape, n, self.dv)
         if self.backend == "ref":
             return self._ref_forward(vals, q, k, v)
-        vals_ext, q_ext, k_pad, v_pad = self._operands(vals, q, k, v)
-        if self._sharded is not None:
-            from ..kernels.ops import attn_fused_sharded_op
-            sw = self._sharded
-            if sw.num_blocks == 0:
-                return jnp.zeros((m, self.dv), jnp.float32)
-            vals_flat = vals_ext[sw.gather_flat]
-            q_ws = q_ext[self._row_map]       # (C, ws_rows, dh_pad)
-            y_ws = attn_fused_sharded_op(
-                sw.blk_tag, sw.blk_off, sw.blk_coff, sw.blk_L,
-                sw.cols_flat, vals_flat, q_ws, k_pad, v_pad, sw.cont,
-                mesh=sw.mesh, bm=self.bm, bk=self.bk,
-                mw=sw.merge_width, interpret=self.interpret,
-                staging=self.staging, span=sw.chip_span,
-                cspan=sw.chip_cspan)
-            y_flat = y_ws.reshape(sw.n_chips * sw.ws_rows, -1)
-            return y_flat[sw.inv_perm, :self.dv]
-        from ..kernels.ops import attn_fused_op
-        fw = self._fused
+        sharded = self._sharded is not None
+        fw = self._sharded if sharded else self._fused
         if fw.num_blocks == 0:
             return jnp.zeros((m, self.dv), jnp.float32)
-        vals_flat = vals_ext[fw.gather_flat]
-        q_ws = q_ext[self._row_map]           # (ws_rows, dh_pad)
-        y_ws = attn_fused_op(
-            fw.blk_tag, fw.blk_off, fw.blk_coff, fw.blk_L,
-            fw.cols_flat, vals_flat, q_ws, k_pad, v_pad, fw.cont,
-            bm=self.bm,
-            bk=self.bk, mw=fw.merge_width, interpret=self.interpret,
-            staging=self.staging, span=fw.max_span, cspan=fw.max_cspan)
-        return y_ws[fw.inv_perm, :self.dv]
+        with span("attn.stage_vals"):
+            vals_ext = jnp.concatenate(
+                [vals.astype(jnp.float32), jnp.zeros((1,), jnp.float32)])
+            vals_flat = vals_ext[fw.gather_flat]
+        with span("attn.stage_operands"):
+            q_ext, k_pad, v_pad = self._operands(q, k, v)
+            q_ws = q_ext[self._row_map]   # ([C,] ws_rows, dh_pad)
+        with span("attn.kernel"):
+            tables = (fw.blk_tag, fw.blk_off, fw.blk_coff, fw.blk_L,
+                      fw.cols_flat, vals_flat, q_ws, k_pad, v_pad, fw.cont)
+            knobs = dict(bm=self.bm, bk=self.bk, mw=fw.merge_width,
+                         interpret=self.interpret, staging=self.staging)
+            if sharded:
+                y_ws = kops.attn_fused_sharded_op(
+                    *tables, mesh=fw.mesh, span=fw.chip_span,
+                    cspan=fw.chip_cspan, **knobs)
+            else:
+                y_ws = kops.attn_fused_op(*tables, span=fw.max_span,
+                                          cspan=fw.max_cspan, **knobs)
+        with span("attn.unpermute"):
+            if sharded:
+                y_ws = y_ws.reshape(fw.n_chips * fw.ws_rows, -1)
+            return y_ws[fw.inv_perm, :self.dv]
 
     def __call__(self, vals, q, k, v):
-        return self._apply(vals, q, k, v)
+        with span("attn.call"):
+            return self._apply(vals, q, k, v)
 
 
 def compile_sparse_attention(a: CSRMatrix, dh: int,

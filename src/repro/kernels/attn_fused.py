@@ -276,6 +276,7 @@ def attn_fused(blk_tag: jax.Array, blk_off: jax.Array,
         out_shape=jax.ShapeDtypeStruct((num_blocks * bm, dv_pad),
                                        jnp.float32),
         interpret=interpret,
+        name="attn_fused",
     )(blk_tag, blk_off, blk_coff, blk_L, cont, cols_flat,
       vals_flat.astype(jnp.float32), vals2, q_ws, k, v)
 
@@ -335,6 +336,7 @@ def attn_fused_staged(blk_tag: jax.Array, blk_off: jax.Array,
         out_shape=jax.ShapeDtypeStruct((num_blocks * bm, dv_pad),
                                        jnp.float32),
         interpret=interpret,
+        name="attn_fused_staged",
     )(blk_tag, blk_off, blk_coff, blk_L, cont, st.stream_rows(cols_flat),
       st.stream_rows(vals_flat.astype(jnp.float32)), q_ws, k, v)
 

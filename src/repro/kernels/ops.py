@@ -4,14 +4,16 @@
 backend and to True elsewhere: off the chip the kernels run in the
 Pallas interpreter, which checks results, not speed.
 
-Every wrapper records its launches in ``DISPATCH_COUNTS`` (a plain host
-counter, incremented when the wrapper is traced).  The fused-path tests
-use it to assert the Table IV invariant: one dispatch per (matrix, d)
-instance, regardless of segment count — and on the sharded path
-``n_chips`` per forward (``shard_map`` traces the body once and
-SPMD-replicates it; the wrapper counts all C).  A staged stream whose
-descriptor tables exceed one call's SMEM is issued as several calls,
-and each counts.
+Every wrapper records its launches in ``DISPATCH_COUNTS``, a plain host
+counter incremented each time the wrapper's Python body runs: once per
+call when it is called eagerly, and once per trace when a jitted caller
+traces it (the caller's cached executions then count nothing).  The
+fused-path tests use it to assert the Table IV invariant: one dispatch
+per (matrix, d) instance, regardless of segment count — and on the
+sharded path ``n_chips`` per forward (``shard_map`` traces the body
+once and SPMD-replicates it; the wrapper counts all C).  A staged
+stream whose descriptor tables exceed one call's SMEM is issued as
+several calls, and each counts.
 """
 from __future__ import annotations
 
@@ -44,11 +46,9 @@ DISPATCH_KEYS = frozenset({
     "ell_segment", "ell_fused", "bcsr", "bcsr_fused", "attn_fused",
     "sddmm",
     # lowering-variant keys: WHICH path served a forward
-    "ell_fused_merged", "ell_fused_dma", "ell_fused_sharded",
-    "ell_fused_xshard",
-    "bcsr_fused_merged", "bcsr_fused_dma", "bcsr_fused_sharded",
-    "bcsr_fused_xshard",
-    "attn_fused_merged", "attn_fused_dma", "attn_fused_sharded",
+    "ell_fused_dma", "ell_fused_sharded", "ell_fused_xshard",
+    "bcsr_fused_dma", "bcsr_fused_sharded", "bcsr_fused_xshard",
+    "attn_fused_dma", "attn_fused_sharded",
 })
 
 # kind -> accumulated host seconds spent building plans/packings (the
@@ -64,6 +64,16 @@ def record_build_seconds(kind: str, seconds: float) -> None:
     """Accumulate host-side build cost under ``kind`` (see
     :data:`BUILD_SECONDS`)."""
     BUILD_SECONDS[kind] += float(seconds)
+
+
+def span(name: str) -> jax.profiler.TraceAnnotation:
+    """A host span named ``name`` around one stage of a call, for the
+    JAX profiler to record when it traces: ``with span("spmm.kernel"):``.
+    Names are fixed strings (``spmm.*``, ``attn.*``, ``spmm_batched.*``)
+    so nothing is formatted per call; with the profiler off a span
+    costs about a microsecond."""
+    return jax.profiler.TraceAnnotation(name)
+
 
 # fused-dispatch operand staging modes (DESIGN.md §7.7):
 #   resident  streams scalar-prefetched into SMEM, X in VMEM — the
@@ -145,14 +155,11 @@ def spmm_ell_fused_op(blk_off, blk_L, cols_flat, vals_flat, x, cont=None,
                       staging=None, span: int = 0, cspan: int = 0):
     """ONE dispatch for the whole plan, either staging mode; staged
     launches additionally count under ``ell_fused_dma`` so tests can
-    assert WHICH lowering served a forward, and CGCM-merged launches
-    (``mw > 1``) under ``ell_fused_merged``."""
+    assert WHICH lowering served a forward."""
     interpret = resolve_interpret(interpret)
     staging = _resolve_op_staging(staging, interpret, span, cspan)
     n = _launches(staging, blk_off.shape[0], mw)
     DISPATCH_COUNTS["ell_fused"] += n
-    if mw > 1:
-        DISPATCH_COUNTS["ell_fused_merged"] += n
     if staging == "dma":
         DISPATCH_COUNTS["ell_fused_dma"] += n
         return spmm_ell_fused_staged(blk_off, blk_L, cols_flat, vals_flat,
@@ -182,8 +189,6 @@ def spmm_ell_fused_sharded_op(blk_off, blk_L, cols_flat, vals_flat, x,
     n = mesh.size * _launches(staging, blk_off.shape[1], mw)
     DISPATCH_COUNTS["ell_fused"] += n
     DISPATCH_COUNTS["ell_fused_sharded"] += 1
-    if mw > 1:
-        DISPATCH_COUNTS["ell_fused_merged"] += n
     if x_sharding == "rows":
         DISPATCH_COUNTS["ell_fused_xshard"] += n
     if staging == "dma":
@@ -207,14 +212,11 @@ def attn_fused_op(blk_tag, blk_off, blk_coff, blk_L, cols_flat,
                   span: int = 0, cspan: int = 0):
     """ONE dispatch for the whole sparse-attention sandwich (SDDMM →
     masked softmax → SpMM, DESIGN.md §13); staged launches also count
-    under ``attn_fused_dma``, CGCM-merged ones under
-    ``attn_fused_merged`` — the same accounting shape as the SpMM
+    under ``attn_fused_dma`` — the same accounting shape as the SpMM
     wrappers so the Table IV invariant tests extend unchanged."""
     interpret = resolve_interpret(interpret)
     staging = _resolve_op_staging(staging, interpret, span, cspan)
     DISPATCH_COUNTS["attn_fused"] += 1
-    if mw > 1:
-        DISPATCH_COUNTS["attn_fused_merged"] += 1
     if staging == "dma":
         DISPATCH_COUNTS["attn_fused_dma"] += 1
         return attn_fused_staged(blk_tag, blk_off, blk_coff, blk_L,
@@ -242,8 +244,6 @@ def attn_fused_sharded_op(blk_tag, blk_off, blk_coff, blk_L, cols_flat,
                                   min(cspan))
     DISPATCH_COUNTS["attn_fused"] += mesh.size
     DISPATCH_COUNTS["attn_fused_sharded"] += 1
-    if mw > 1:
-        DISPATCH_COUNTS["attn_fused_merged"] += mesh.size
     if staging == "dma":
         DISPATCH_COUNTS["attn_fused_dma"] += mesh.size
     else:
@@ -269,14 +269,11 @@ def spmm_bcsr_fused_op(blk_tag, blk_off, blk_coff, blk_L, cols_flat,
                        span: int = 0, cspan: int = 0):
     """ONE dispatch for a whole mixed VPU/MXU plan (Table IV invariant,
     now covering the MXU block-rows as well); staged launches also
-    count under ``bcsr_fused_dma``, CGCM-merged ones under
-    ``bcsr_fused_merged``."""
+    count under ``bcsr_fused_dma``."""
     interpret = resolve_interpret(interpret)
     staging = _resolve_op_staging(staging, interpret, span, cspan)
     n = _launches(staging, blk_off.shape[0], mw)
     DISPATCH_COUNTS["bcsr_fused"] += n
-    if mw > 1:
-        DISPATCH_COUNTS["bcsr_fused_merged"] += n
     if staging == "dma":
         DISPATCH_COUNTS["bcsr_fused_dma"] += n
         return spmm_bcsr_fused_staged(blk_tag, blk_off, blk_coff, blk_L,
@@ -308,8 +305,6 @@ def spmm_bcsr_fused_sharded_op(blk_tag, blk_off, blk_coff, blk_L,
     n = mesh.size * _launches(staging, blk_off.shape[1], mw)
     DISPATCH_COUNTS["bcsr_fused"] += n
     DISPATCH_COUNTS["bcsr_fused_sharded"] += 1
-    if mw > 1:
-        DISPATCH_COUNTS["bcsr_fused_merged"] += n
     if x_sharding == "rows":
         DISPATCH_COUNTS["bcsr_fused_xshard"] += n
     if staging == "dma":

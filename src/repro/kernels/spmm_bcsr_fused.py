@@ -264,6 +264,7 @@ def spmm_bcsr_fused(blk_tag: jax.Array, blk_off: jax.Array,
         out_shape=jax.ShapeDtypeStruct((num_blocks * bm, d_pad),
                                        jnp.float32),
         interpret=interpret,
+        name="bcsr_fused",
     )(blk_tag, blk_off, blk_coff, blk_L, cont, cols_flat,
       vals_flat.astype(jnp.float32), vals2, x)
 
@@ -326,6 +327,7 @@ def spmm_bcsr_fused_staged(blk_tag: jax.Array, blk_off: jax.Array,
             ),
             out_shape=jax.ShapeDtypeStruct((B * bm, d_pad), jnp.float32),
             interpret=interpret,
+            name="bcsr_fused_staged",
         )(*tables, cont, cols2, vals2, x, carry_in)
 
     return st.issue_in_calls(call, [blk_tag, blk_off, blk_coff, blk_L],

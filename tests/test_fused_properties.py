@@ -327,6 +327,22 @@ def test_merged_gradient_bit_matches_unmerged(a, d, strategy, backend):
     assert np.array_equal(np.asarray(grads[0][1]), np.asarray(grads[1][1]))
 
 
+@pytest.mark.parametrize("backend", ["pallas_ell", "pallas_bcsr", "ref"])
+def test_gradient_of_a_matrix_without_nonzeros(backend):
+    """The shrunk example hypothesis found for the property above: a
+    (1, 1) matrix with no nonzero.  The chunked SDDMM must not divide
+    its zero nonzeros into chunks of size zero."""
+    a = CSRMatrix((1, 1), np.zeros(2, np.int64), np.zeros(0, np.int32),
+                  np.zeros(0, np.float32))
+    c = compile_spmm(a, 1, strategy="row_split", backend=backend,
+                     interpret=True, cache=JitCache())
+    dvals, dx = jax.grad(lambda v, xx: jnp.sum(c(v, xx) ** 2),
+                         argnums=(0, 1))(jnp.zeros((0,), jnp.float32),
+                                         jnp.ones((1, 1), jnp.float32))
+    assert dvals.shape == (0,)
+    assert np.array_equal(np.asarray(dx), np.zeros((1, 1), np.float32))
+
+
 @settings(max_examples=40, deadline=None)
 @given(a=csr_cases(), d=st.integers(1, 32),
        strategy=st.sampled_from(STRATEGIES),
