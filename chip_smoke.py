@@ -109,6 +109,29 @@ def power_law_graph(seed: int, log2_n: int = 20):
     return random_csr(n, n, density=16 / n, family="powerlaw", seed=seed)
 
 
+def describe_fused(c) -> str:
+    """One line on a one-chip fused artifact: how it resolved, its
+    trips and window, the slot-value stream's slots and the elements
+    its staging gathers, its descriptors and piece trips."""
+    fw = c._fused
+    tags = np.asarray(fw.blk_tag)[np.asarray(fw.blk_L) > 0]
+    return (f"backend={c.backend} staging={c.staging} "
+            f"interpret={c.interpret} trips={fw.num_blocks} "
+            f"window={fw.max_span} slots={c.vals_slots} "
+            f"gathered={c.vals_gather_elems}; VPU/MXU descriptors "
+            f"{int((tags == 0).sum())} / {int((tags == 1).sum())}; "
+            f"piece trips {int(np.asarray(fw.cont).sum())}")
+
+
+def slot_table_devices(c) -> list:
+    """For each per-chip slot-value table of a sharded artifact (the
+    element-gathered indices and, on a plan with MXU panels, the live
+    lanes), the number of devices it sits on."""
+    vals = c._sharded.vals
+    return [len({s.device for s in t.addressable_shards})
+            for t in (vals.gather, vals.lanes) if t is not None]
+
+
 def phase_spmm(seed: int):
     import jax
     import jax.numpy as jnp
@@ -125,15 +148,8 @@ def phase_spmm(seed: int):
     vals = jnp.asarray(a.vals)
 
     c, t_plan = timed(lambda: compile_spmm(a, d))
-    ws = c._fused
-    tags = np.asarray(ws.blk_tag)[np.asarray(ws.blk_L) > 0]
     log("spmm", f"compile_spmm (plan + pack + device tables) {t_plan:.2f}s:"
-                f" backend={c.backend} staging={c.staging} "
-                f"interpret={c.interpret} trips={ws.num_blocks} "
-                f"window={ws.max_span} slots={int(ws.gather_flat.shape[0])}")
-    log("spmm", f"VPU/MXU descriptors: {int((tags == 0).sum())} / "
-                f"{int((tags == 1).sum())}; piece trips "
-                f"{int(np.asarray(ws.cont).sum())}")
+                f" {describe_fused(c)}")
     if (c.backend, c.staging, c.interpret) != ("pallas_bcsr", "dma", False):
         raise AssertionError(f"main path resolved to {c.backend}/"
                              f"{c.staging}/interpret={c.interpret}")
@@ -260,13 +276,14 @@ def phase_four_chips(seed: int):
     vals = jnp.asarray(a.vals)
     c4, t_plan = timed(lambda: compile_spmm(a, d, backend="pallas_bcsr",
                                             n_chips=4))
-    sw = c4._sharded
-    placed = {s.device for s in sw.gather_flat.addressable_shards}
+    placed = slot_table_devices(c4)
     log("chips4", f"compile_spmm n_chips=4 {t_plan:.2f}s: "
                   f"staging={c4.staging} x_sharding={c4.x_sharding} "
-                  f"interpret={c4.interpret} chip windows {sw.chip_span}; "
-                  f"slot tables on {len(placed)} devices")
-    if len(placed) != 4 or c4.interpret:
+                  f"interpret={c4.interpret} chip windows "
+                  f"{c4._sharded.chip_span}; slots={c4.vals_slots} "
+                  f"gathered={c4.vals_gather_elems}; slot-value tables "
+                  f"on {placed} devices")
+    if any(n != 4 for n in placed) or c4.interpret:
         raise AssertionError("per-chip tables are not on four chips")
     y4, t_first = timed(lambda: jax.block_until_ready(c4(vals, x)))
     _, t_warm = timed(lambda: jax.block_until_ready(c4(vals, x)))

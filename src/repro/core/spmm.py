@@ -32,18 +32,20 @@ once and baked into the jit-cache key like ``interpret``.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 from jax.sharding import Mesh
 
 from . import ccm
 from .csr import CSRMatrix
 from .jit_cache import GLOBAL_CACHE, JitCache, mesh_fingerprint
-from .plan import (SPARSE_ATTN_EINSUM, SPARSE_ATTN_MIXED_EINSUM,
+from .plan import (MXU_TAG, SPARSE_ATTN_EINSUM, SPARSE_ATTN_MIXED_EINSUM,
                    BatchedFusedWorkspace, MixedPlan,
                    ShardedFusedWorkspace, SpmmPlan,
                    build_batched_workspace, build_einsum_workspace,
@@ -54,7 +56,7 @@ from ..analysis.verify import (PlanVerificationError, check_workspace,
                                resolve_validate)
 from ..kernels import ops as kops
 from ..kernels.ops import resolve_interpret, resolve_staging, span
-from ..platform import STAGE_TILE, resident_fits
+from ..platform import LANE, STAGE_TILE, resident_fits
 
 __all__ = [
     "BACKENDS", "FUSED_BACKENDS", "X_SHARDING_MODES",
@@ -211,7 +213,7 @@ class _FusedConsts:
     blk_off: jax.Array       # (B,) int32 — first slot per row-block
     blk_L: jax.Array         # (B,) int32 — loop trips per row-block
     cols_flat: jax.Array     # (Sc,) int32 — X row / block-column stream
-    gather_flat: jax.Array   # (S,) int   — slot -> concat(vals,[0]) index
+    vals: "_SlotValues"      # how the (S,) slot-value stream is staged
     inv_perm: jax.Array      # (m,) int32 — output row -> workspace row
     num_blocks: int
     blk_tag: Optional[jax.Array] = None   # (B,) int32 — VPU/MXU tag
@@ -220,6 +222,13 @@ class _FusedConsts:
     max_cspan: int = 0       # staged-DMA cols window
     merge_width: int = 1     # CGCM width (DESIGN.md §7.9)
     cont: Optional[jax.Array] = None   # (B//W,) int32 piece trips
+
+    @property
+    def gather_flat(self) -> jax.Array:
+        """``concat(vals,[0])`` indices of the element-gathered prefix
+        of the slot stream: the whole stream only when ``vals.lanes``
+        is None (a plan without MXU panels); see ``vals.slots``."""
+        return self.vals.gather
 
 
 def _require_resident_fit(staging: str, interpret: bool, ws,
@@ -238,23 +247,136 @@ def _require_resident_fit(staging: str, interpret: bool, ws,
             f"staging='dma' (the default on a TPU)")
 
 
-def _stream(a: np.ndarray, fill, sharding=None) -> jax.Array:
-    """Device copy of a flat stream (or a per-chip stack of them) padded
-    to whole tiles: the kernels view streams as (rows, LANE) arrays."""
+def _tiles(a: np.ndarray, fill) -> np.ndarray:
+    """A flat stream (or a per-chip stack of them) padded to whole
+    tiles: the kernels view streams as (rows, LANE) arrays."""
     pad = -a.shape[-1] % STAGE_TILE
-    a = np.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, pad)],
-               constant_values=fill)
+    return np.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, pad)],
+                  constant_values=fill)
+
+
+def _put(a: np.ndarray, sharding=None) -> jax.Array:
     return jax.device_put(a.astype(np.int32) if a.dtype == np.int64 else a,
                           sharding)
 
 
-def _fused_consts(ws, nnz: int) -> "_FusedConsts":
+def _stream(a: np.ndarray, fill, sharding=None) -> jax.Array:
+    """Device copy of a stream padded to whole tiles."""
+    return _put(_tiles(a, fill), sharding)
+
+
+# lax.gather of single elements of a 1-D operand at (..., 1) indices
+_TAKE = lax.GatherDimensionNumbers(offset_dims=(), collapsed_slice_dims=(0,),
+                                   start_index_map=(0,))
+
+
+@dataclasses.dataclass
+class _SlotValues:
+    """How a call stages the kernel's slot-value stream, which equals
+    ``concat(vals, [0])[gather_flat]`` bit for bit (DESIGN.md §7.7).
+
+    A plan with MXU panels stores each ``(bm, bk)`` value panel
+    lane-padded to ``(bm, LANE)``: lanes ``bk..LANE-1`` name the zero
+    sentinel.  Each member's stream (the plan's, a chip's, a request's)
+    is viewed as ``(rows, LANE)``: the slots before the first row after
+    which no row has a live lane past ``bk`` are one element gather,
+    the rows from there to the last row with a live lane gather their
+    first ``bk`` lanes alone and are padded back to ``LANE`` with zeros,
+    and the all-sentinel rows after them are zeros.  A plan without MXU
+    panels is one element gather of the whole stream."""
+    gather: jax.Array                 # ([M,] P) int32 element-gathered
+    lanes: Optional[jax.Array] = None  # ([M,] R, bk, 1) int32 live lanes
+    zero_rows: int = 0                # all-sentinel rows after ``lanes``
+    shape: tuple = ()                 # the stream's shape
+
+    @property
+    def gather_elems(self) -> int:
+        """Elements gathered per call."""
+        return self.gather.size + (0 if self.lanes is None
+                                   else self.lanes.size)
+
+    @property
+    def slots(self) -> int:
+        return int(np.prod(self.shape))
+
+    def stage(self, vals) -> jax.Array:
+        vals_ext = jnp.concatenate(
+            [vals.astype(jnp.float32), jnp.zeros((1,), jnp.float32)])
+        if self.lanes is None:
+            return vals_ext[self.gather]
+        tiles = _lane_tiles(vals_ext, self.lanes, self.zero_rows)
+        if self.gather.shape[-1]:
+            tiles = jnp.concatenate([vals_ext[self.gather], tiles], axis=-1)
+        return tiles.reshape(self.shape)
+
+
+@functools.partial(jax.jit, static_argnums=2)
+def _lane_tiles(vals_ext, lanes, zero_rows: int) -> jax.Array:
+    """``([M,] R, bk, 1)`` live lanes of lane-padded rows gathered from
+    ``vals_ext``, padded back to ``LANE`` lanes and by ``zero_rows``
+    rows of zeros, flat per member: one program, so the pad and the
+    flattening need no pass of their own."""
+    # in range by construction: no index normalisation
+    live = lax.gather(vals_ext, lanes, _TAKE, slice_sizes=(1,),
+                      mode=lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+    lead, bk = live.shape[:-2], live.shape[-1]
+    tiles = jnp.pad(live, [(0, 0)] * len(lead)
+                    + [(0, zero_rows), (0, LANE - bk)])
+    return tiles.reshape(*lead, -1)
+
+
+def _slot_values(ws, nnz: int, members: int = 1,
+                 sharding=None) -> _SlotValues:
+    """The device side of :class:`_SlotValues` for a workspace whose
+    ``gather_flat`` is one stream, ``members`` equal-width streams laid
+    end to end (the request axis), or a per-chip ``(C, S)`` stack; the
+    split comes from the host stream itself."""
+    g = _tiles(ws.gather_flat, nnz)
+    if not np.any(ws.blk_tag == MXU_TAG):
+        return _SlotValues(gather=_put(g, sharding), shape=g.shape)
+    lead = g.shape[:-1] or ((members,) if members > 1 else ())
+    rows = g.reshape(*lead, -1, LANE)
+    n_rows = rows.shape[-2]
+    # per row, over every member: a live lane past bk / any live lane
+    wide = (rows[..., ws.bk:] != nnz).any(-1).reshape(-1, n_rows).any(0)
+    live = (rows != nnz).any(-1).reshape(-1, n_rows).any(0)
+    p = int(np.flatnonzero(wide)[-1]) + 1 if wide.any() else 0
+    e = max(int(np.flatnonzero(live)[-1]) + 1 if live.any() else 0, p)
+    return _SlotValues(
+        gather=_put(rows[..., :p, :].reshape(*lead, -1), sharding),
+        lanes=_put(rows[..., p:e, :ws.bk, None], sharding),
+        zero_rows=n_rows - e, shape=g.shape)
+
+
+class _SlotValueCounts:
+    """``vals_gather_elems`` and ``vals_slots`` of an artifact: the
+    elements its slot-value staging gathers per call and the slots of
+    the stream it stages, fixed when it is built; None off the fused
+    backends."""
+
+    def _staged(self) -> Optional[_SlotValues]:
+        fw = self._sharded or self._fused
+        return None if fw is None else fw.vals
+
+    @property
+    def vals_gather_elems(self) -> Optional[int]:
+        sv = self._staged()
+        return None if sv is None else sv.gather_elems
+
+    @property
+    def vals_slots(self) -> Optional[int]:
+        sv = self._staged()
+        return None if sv is None else sv.slots
+
+
+def _fused_consts(ws, nnz: int, members: int = 1) -> "_FusedConsts":
     """Device constants of a solo or request-batched workspace; ``nnz``
-    is the gather stream's zero-slot sentinel."""
+    is the gather stream's zero-slot sentinel, ``members`` the number
+    of requests laid end to end in its streams."""
     return _FusedConsts(
         blk_off=jnp.asarray(ws.blk_off), blk_L=jnp.asarray(ws.blk_L),
         cols_flat=_stream(ws.cols_flat, 0),
-        gather_flat=_stream(ws.gather_flat, nnz),
+        vals=_slot_values(ws, nnz, members),
         inv_perm=jnp.asarray(ws.inv_perm), num_blocks=ws.num_blocks,
         blk_tag=jnp.asarray(ws.blk_tag), blk_coff=jnp.asarray(ws.blk_coff),
         max_span=ws.max_span, max_cspan=ws.max_cspan,
@@ -270,7 +392,7 @@ class _ShardedConsts:
     blk_off: jax.Array       # (C, B) int32
     blk_L: jax.Array         # (C, B) int32
     cols_flat: jax.Array     # (C, Sc) int32
-    gather_flat: jax.Array   # (C, S) int — slot -> GLOBAL concat(vals,[0])
+    vals: _SlotValues        # how the (C, S) slot-value stream is staged
     inv_perm: jax.Array      # (m,) int32 into flattened workspace rows
     ws_rows: int             # per-chip workspace rows
     num_blocks: int          # common per-chip block count B
@@ -307,7 +429,7 @@ def _sharded_consts(sw: ShardedFusedWorkspace, mesh: Mesh
     return _ShardedConsts(
         blk_off=put(sw.blk_off), blk_L=put(sw.blk_L),
         cols_flat=_stream(sw.cols_flat, 0, on_chips),
-        gather_flat=_stream(sw.gather_flat, sw.nnz, on_chips),
+        vals=_slot_values(sw, sw.nnz, sharding=on_chips),
         inv_perm=jnp.asarray(sw.inv_perm), ws_rows=sw.ws_rows,
         num_blocks=sw.num_blocks, n_chips=sw.n_chips, mesh=mesh,
         blk_tag=put(sw.blk_tag), blk_coff=put(sw.blk_coff),
@@ -320,7 +442,7 @@ def _sharded_consts(sw: ShardedFusedWorkspace, mesh: Mesh
         cont=put(sw.blk_cont))
 
 
-class CompiledSpmm:
+class CompiledSpmm(_SlotValueCounts):
     """The "jit-function": structure-specialized, value-generic,
     differentiable SpMM."""
 
@@ -516,9 +638,7 @@ class CompiledSpmm:
         # segment count, between the slot-value gather and one
         # inverse-permutation gather that recovers row order
         with span("spmm.stage_vals"):
-            vals_ext = jnp.concatenate(
-                [vals.astype(jnp.float32), jnp.zeros((1,), jnp.float32)])
-            vals_flat = vals_ext[fw.gather_flat]
+            vals_flat = fw.vals.stage(vals)
         with span("spmm.stage_operands"):
             x_pad = ccm.pad_cols(x, self.d_tiling.d_pad)
             if backend == "pallas_bcsr" and (
@@ -707,7 +827,7 @@ def compile_spmm(a: CSRMatrix, d: int, *, strategy: str = "nnz_split",
         priority=cache_priority)
 
 
-class CompiledBatchedSpmm:
+class CompiledBatchedSpmm(_SlotValueCounts):
     """Request-axis batched jit-function for the serving tier
     (DESIGN.md §12): R structure-specialized instances stacked
     block-diagonally (:func:`build_batched_workspace`) into ONE fused
@@ -765,7 +885,7 @@ class CompiledBatchedSpmm:
             self.staging, self.interpret, bw,
             4 * bw.n_requests * bw.x_rows_pad * self.d_tiling.dt,
             "compile_batched_spmm")
-        self._consts = _fused_consts(bw, bw.nnz)
+        self._consts = _fused_consts(bw, bw.nnz, bw.n_requests)
         _record_build(sum(p.plan_seconds for p in bw.request_plans),
                       bw.pack_seconds)
         self._row_splits = [int(v) for v in bw.row_splits]
@@ -773,6 +893,9 @@ class CompiledBatchedSpmm:
         # once here instead of per request (shapes are fixed by the
         # artifact, so this never retraces after warmup)
         self._jit_forward = jax.jit(self._forward)
+
+    def _staged(self) -> _SlotValues:
+        return self._consts.vals
 
     @property
     def n_requests(self) -> int:
@@ -793,10 +916,8 @@ class CompiledBatchedSpmm:
 
     def _forward(self, vals, x):
         fw = self._consts
-        vals_ext = jnp.concatenate(
-            [vals.astype(jnp.float32), jnp.zeros((1,), jnp.float32)])
         x_pad = ccm.pad_cols(x, self.d_tiling.d_pad)
-        vals_flat = vals_ext[fw.gather_flat]
+        vals_flat = fw.vals.stage(vals)
         if self.backend == "pallas_ell":
             from ..kernels.ops import spmm_ell_fused_op
             y_ws = spmm_ell_fused_op(
@@ -914,7 +1035,7 @@ def spmm(a: CSRMatrix, x, *, strategy: str = "nnz_split",
     return compiled(jnp.asarray(a.vals), x)
 
 
-class CompiledSparseAttention:
+class CompiledSparseAttention(_SlotValueCounts):
     """Structure-specialized sparse attention: out = softmax(mask ⊙
     (Q·Kᵀ)) · V, lowered as ONE fused pallas_call (per chip) through
     the same descriptor stream as SpMM (DESIGN.md §13).
@@ -1134,9 +1255,7 @@ class CompiledSparseAttention:
         if fw.num_blocks == 0:
             return jnp.zeros((m, self.dv), jnp.float32)
         with span("attn.stage_vals"):
-            vals_ext = jnp.concatenate(
-                [vals.astype(jnp.float32), jnp.zeros((1,), jnp.float32)])
-            vals_flat = vals_ext[fw.gather_flat]
+            vals_flat = fw.vals.stage(vals)
         with span("attn.stage_operands"):
             q_ext, k_pad, v_pad = self._operands(q, k, v)
             q_ws = q_ext[self._row_map]   # ([C,] ws_rows, dh_pad)

@@ -456,3 +456,32 @@ def test_split_blocks_through_compile_sparse_attention(sharded,
     y, pieces = run()
     assert pieces0 == 0 < pieces
     assert np.array_equal(y, y0)
+
+
+@pytest.mark.parametrize("backend", FUSED)
+@pytest.mark.parametrize("staging", ("resident", "dma"))
+def test_live_lane_staging_matches_the_plain_gather(backend, staging):
+    """On a window+global mask the MXU plan stages its slot values from
+    the live lanes of its lane-padded panels: the output equals the
+    same artifact fed the whole element gather ``concat(vals,[0])
+    [gather_flat]`` bit for bit, and the oracle within tolerance."""
+    import dataclasses
+    from repro.core.spmm import _SlotValues, _tiles
+    from repro.models.sparse_attention import sparse_attention_mask
+    a = sparse_attention_mask(96, window=24, num_global=4)
+    q, k, v = _qkv(a.m, a.n, 16, 16, seed=22)
+    art = compile_sparse_attention(a, 16, backend=backend,
+                                   interpret=True, staging=staging,
+                                   cache=JitCache())
+    vals = jnp.asarray(a.vals)
+    y = np.asarray(art(vals, q, k, v))
+    ws = art.workspace
+    compact = backend == "pallas_bcsr"
+    assert (art._fused.vals.lanes is not None) == compact
+    assert (art.vals_gather_elems < art.vals_slots) == compact
+    g = _tiles(ws.gather_flat, a.nnz)
+    art._fused = dataclasses.replace(art._fused, vals=_SlotValues(
+        gather=jnp.asarray(g.astype(np.int32)), shape=g.shape))
+    assert np.array_equal(np.asarray(art(vals, q, k, v)), y)
+    np.testing.assert_allclose(y, _dense_oracle(a, a.vals, q, k, v),
+                               rtol=1e-5, atol=1e-5)
