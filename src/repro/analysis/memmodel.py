@@ -102,8 +102,9 @@ def hbm_traffic(cfg, shape, *, multi_pod: bool, remat: str = "full",
     if cfg.moe:
         n_moe = sum(1 for i in range(cfg.period_len)
                     if cfg.ffn_kind(i) == "moe") * cfg.num_periods
-        C = max(cfg.top_k, int(cfg.capacity_factor * S * cfg.top_k
-                               / cfg.num_experts))
+        C = S if cfg.capacity_factor is None else max(
+            cfg.top_k, int(cfg.capacity_factor * S * cfg.top_k
+                           / cfg.num_experts))
         e_l = max(cfg.num_experts // tp, 1)
         buf = Bl * e_l * C * D * BF16 * 2
         t["moe_buffers"] = n_moe * buf * (3 if kind == "train" else 1)

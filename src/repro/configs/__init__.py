@@ -5,7 +5,7 @@ _ARCH_MODULES = (
     "qwen2_5_32b", "llama3_405b", "qwen3_14b", "qwen1_5_32b",
     "llama4_scout_17b_a16e", "mixtral_8x7b", "llama_3_2_vision_11b",
     "musicgen_large", "jamba_1_5_large_398b", "rwkv6_1_6b",
-    "longformer_1_4b",
+    "longformer_1_4b", "mellum2_12b_a2_5b",
 )
 
 _loaded = False

@@ -13,6 +13,17 @@ REGISTRY: Dict[str, "ArchConfig"] = {}
 
 
 @dataclasses.dataclass(frozen=True)
+class YarnRope:
+    """YaRN RoPE scaling (Peng et al., arXiv:2309.00071), with the keys
+    of a Hugging Face ``rope_parameters`` entry of ``rope_type`` yarn."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: Optional[float] = None   # None: 0.1 ln(factor) + 1
+
+
+@dataclasses.dataclass(frozen=True)
 class ArchConfig:
     name: str
     family: str                      # dense | moe | vlm | audio | hybrid | ssm
@@ -28,6 +39,10 @@ class ArchConfig:
     qk_norm: bool = False
     sliding_window: Optional[int] = None
     rope_theta: float = 1e4
+    # YaRN scaling of the full-attention ("attn") slots' RoPE; "sattn"
+    # slots keep plain RoPE at ``rope_theta`` (a per-layer-kind
+    # ``rope_parameters``, as Mellum's sliding/full split)
+    rope_yarn: Optional[YarnRope] = None
     # sparse attention ("sattn" slots): causal local window plus
     # longformer-style global key columns, lowered through the fused
     # descriptor-stream sandwich (DESIGN.md §13).  Distinct from
@@ -42,7 +57,12 @@ class ArchConfig:
     num_experts: int = 0
     top_k: int = 0
     moe_every: int = 1               # MoE FFN on layers where idx%every==every-1
-    capacity_factor: float = 1.25
+    # None: dropless routing (every routed token reaches its expert)
+    capacity_factor: Optional[float] = 1.25
+    # expert parallelism: the contiguous block (first, count) of the
+    # ``num_experts`` this chip holds; the router keeps all of them and
+    # the layer adds only its own experts' part.  None: all held.
+    experts_held: Optional[Tuple[int, int]] = None
     # mamba (hybrid)
     mamba_state: int = 16
     mamba_conv: int = 4
@@ -197,8 +217,9 @@ def reduced(cfg: ArchConfig) -> ArchConfig:
         num_experts=E,
         top_k=min(cfg.top_k, 2) if cfg.moe else 0,
         # generous capacity so train/decode routing agree (no drops) in
-        # consistency tests; production keeps 1.25
-        capacity_factor=4.0,
+        # consistency tests; production keeps 1.25; dropless stays so
+        capacity_factor=None if cfg.capacity_factor is None else 4.0,
+        experts_held=None,
         sliding_window=8 if cfg.sliding_window else None,
         sparse_attn_window=8 if cfg.sparse_attn_window else None,
         sparse_attn_global=min(cfg.sparse_attn_global, 2),

@@ -9,10 +9,12 @@ never materialized — required for the 32k prefill cells to fit HBM.
 """
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 NEG_INF = -1e30
 
@@ -46,12 +48,51 @@ def rope_freqs(head_dim: int, theta: float):
                             / head_dim))
 
 
-def apply_rope(x, positions, theta: float):
-    """x (..., S, H, hd); positions (..., S) int32."""
+def yarn_freqs(head_dim: int, theta: float, yarn):
+    """YaRN inverse frequencies and the factor cos and sin are scaled
+    by, as Hugging Face's ``_compute_yarn_parameters`` computes them
+    (float32, ``truncate`` on): dimensions that turn fewer than
+    ``beta_slow`` times over ``original_max_position`` positions are
+    interpolated by ``factor``, those that turn more than ``beta_fast``
+    times are kept, and a linear ramp blends the ones between."""
+    dim = head_dim
+
+    def correction_dim(rotations):
+        return (dim * math.log(yarn.original_max_position
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(yarn.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    pos_freqs = np.float32(theta) ** (
+        np.arange(0, dim, 2, dtype=np.float32) / np.float32(dim))
+    extrapolation = np.float32(1.0) / pos_freqs
+    interpolation = np.float32(1.0) / (np.float32(yarn.factor) * pos_freqs)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - np.float32(low))
+                   / np.float32(high - low), 0, 1).astype(np.float32)
+    keep = np.float32(1.0) - ramp
+    inv_freq = interpolation * (1 - keep) + extrapolation * keep
+    scale = (yarn.attention_factor if yarn.attention_factor is not None
+             else (0.1 * math.log(yarn.factor) + 1.0
+                   if yarn.factor > 1 else 1.0))
+    return inv_freq.astype(np.float32), float(scale)
+
+
+def apply_rope(x, positions, theta: float, yarn=None):
+    """x (..., S, H, hd); positions (..., S) int32.  ``yarn`` (a
+    ``configs.base.YarnRope``) switches to YaRN's frequencies and
+    scales cos and sin by its attention factor."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)                       # (hd/2,)
+    if yarn is None:
+        freqs, scale = rope_freqs(hd, theta), None       # (hd/2,)
+    else:
+        freqs, scale = yarn_freqs(hd, theta, yarn)
     ang = positions[..., None].astype(jnp.float32) * freqs  # (..., S, hd/2)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if scale is not None:
+        cos, sin = cos * scale, sin * scale
     cos = cos[..., None, :]                             # (..., S, 1, hd/2)
     sin = sin[..., None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
@@ -193,8 +234,9 @@ def attn_project_qkv(p, x, cfg_heads, cfg_kv_heads, head_dim, *, qk_norm,
 
 
 def self_attention_layer(p, x, *, positions, head_dim, num_heads,
-                         num_kv_heads, rope_theta, causal=True,
-                         window=None, qk_norm=False, norm_eps=1e-5,
+                         num_kv_heads, rope_theta, rope_yarn=None,
+                         causal=True, window=None, qk_norm=False,
+                         norm_eps=1e-5,
                          kv_override=None, chunk_q: int = 512,
                          unroll_chunks: bool = False,
                          causal_skip: bool = False):
@@ -205,9 +247,9 @@ def self_attention_layer(p, x, *, positions, head_dim, num_heads,
     h = rms_norm(x, p["ln"], norm_eps)
     q, k, v = attn_project_qkv(p, h, num_heads, num_kv_heads, head_dim,
                                qk_norm=qk_norm, norm_eps=norm_eps)
-    q = apply_rope(q, positions, rope_theta)
+    q = apply_rope(q, positions, rope_theta, rope_yarn)
     if kv_override is None:
-        k = apply_rope(k, positions, rope_theta)
+        k = apply_rope(k, positions, rope_theta, rope_yarn)
         kv_positions = positions
     else:
         k, v, kv_positions = kv_override(k, v)

@@ -33,43 +33,68 @@ def _c(x, shard_ctx, spec):
 
 
 def moe_capacity(seq: int, top_k: int, num_experts: int,
-                 capacity_factor: float = 1.25) -> int:
+                 capacity_factor=1.25) -> int:
+    """Slots per expert in one routing group of ``seq`` tokens.  A
+    token picks an expert at most once, so ``capacity_factor=None``
+    (dropless) gives every expert ``seq`` slots: none overflows."""
+    if capacity_factor is None:
+        return seq
     return max(top_k, int(capacity_factor * seq * top_k / num_experts))
 
 
 def moe_ffn(p: Dict, x: jax.Array, *, num_experts: int, top_k: int,
-            capacity_factor: float = 1.25, norm_eps: float = 1e-5,
+            capacity_factor=1.25, experts_held=None,
+            norm_eps: float = 1e-5,
             shard_ctx=None) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Pre-norm MoE SwiGLU FFN: x + combine(experts(dispatch(norm(x)))).
 
-    p: router (D,E), w_gate/w_up (E,D,F), w_down (E,F,D), ln (D,)
+    p: router (D,E), w_gate/w_up (n,D,F), w_down (n,F,D), ln (D,)
     x: (B, S, D).  Returns (out, aux_losses).
+
+    The router scores all ``num_experts`` (E) experts; a token goes to
+    its top_k, gates renormalised over them.  ``experts_held`` =
+    (first, n) says this chip holds experts first .. first+n-1 (the
+    weights' leading axis; None: all E): the layer adds their part of
+    the result alone, and what the absent experts would add is left
+    out, so the parts of all the chips sum to the whole layer.
+    ``capacity_factor`` None routes dropless.
     """
     B, S, D = x.shape
+    first, n_held = (0, num_experts) if experts_held is None \
+        else experts_held
     h = rms_norm(x, p["ln"], norm_eps)
-    logits = jnp.einsum("bsd,de->bse", h.astype(jnp.float32),
-                        p["router"].astype(jnp.float32))
-    C = moe_capacity(S, top_k, num_experts, capacity_factor)
+    with jax.named_scope("moe.route"):
+        logits = jnp.einsum("bsd,de->bse", h.astype(jnp.float32),
+                            p["router"].astype(jnp.float32))
+        C = moe_capacity(S, top_k, num_experts, capacity_factor)
 
-    route = jax.vmap(lambda lg: moe_spmm.topk_routing(lg, top_k, C))
-    gates, expert_ids, slots = route(logits)            # (B,S,k) each
-    # renormalize gates over the chosen k (mixtral-style)
-    gates = gates / jnp.clip(jnp.sum(gates, -1, keepdims=True), 1e-9)
+        route = jax.vmap(lambda lg: moe_spmm.topk_routing(lg, top_k, C))
+        gates, expert_ids, slots = route(logits)        # (B,S,k) each
+        # renormalize gates over the chosen k (mixtral-style)
+        gates = gates / jnp.clip(jnp.sum(gates, -1, keepdims=True), 1e-9)
+        # pairs routed to experts held elsewhere take the dropped slot
+        local = expert_ids - first
+        held = (local >= 0) & (local < n_held)
+        local = jnp.where(held, local, 0)
+        slots = jnp.where(held, slots, C)
 
-    disp = jax.vmap(
-        lambda t, e, s: moe_spmm.dispatch(t, e, s, num_experts, C))
-    xe = disp(h, expert_ids, slots)                     # (B,E,C,D)
-    xe = _c(xe, shard_ctx, ("DP", "model", None, None))
+        disp = jax.vmap(
+            lambda t, e, s: moe_spmm.dispatch(t, e, s, n_held, C))
+        xe = disp(h, local, slots)                      # (B,n,C,D)
+        xe = _c(xe, shard_ctx, ("DP", "model", None, None))
 
-    g = jnp.einsum("becd,edf->becf", xe, p["w_gate"].astype(xe.dtype))
-    u = jnp.einsum("becd,edf->becf", xe, p["w_up"].astype(xe.dtype))
-    act = jax.nn.silu(g.astype(jnp.float32)).astype(xe.dtype) * u
-    oe = jnp.einsum("becf,efd->becd", act, p["w_down"].astype(xe.dtype))
-    oe = _c(oe, shard_ctx, ("DP", "model", None, None))
+    with jax.named_scope("moe.experts"):
+        g = jnp.einsum("becd,edf->becf", xe, p["w_gate"].astype(xe.dtype))
+        u = jnp.einsum("becd,edf->becf", xe, p["w_up"].astype(xe.dtype))
+        act = jax.nn.silu(g.astype(jnp.float32)).astype(xe.dtype) * u
+        oe = jnp.einsum("becf,efd->becd", act,
+                        p["w_down"].astype(xe.dtype))
+        oe = _c(oe, shard_ctx, ("DP", "model", None, None))
 
-    comb = jax.vmap(moe_spmm.combine)
-    out = comb(oe, gates.astype(oe.dtype), expert_ids, slots)  # (B,S,D)
-    out = _c(out, shard_ctx, ("DP", None, None))
+    with jax.named_scope("moe.combine"):
+        comb = jax.vmap(moe_spmm.combine)
+        out = comb(oe, gates.astype(oe.dtype), local, slots)  # (B,S,D)
+        out = _c(out, shard_ctx, ("DP", None, None))
 
     # aux losses: switch load-balance + router z-loss
     probs = jax.nn.softmax(logits, axis=-1)             # (B,S,E)

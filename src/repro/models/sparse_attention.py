@@ -19,8 +19,10 @@ follow-up for folding B·H into the descriptor table itself).
 from __future__ import annotations
 
 import functools
+import time
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -33,25 +35,26 @@ def sparse_attention_mask(seq_len: int, window: int, num_global: int = 0):
     Row i (query) sees key j iff ``j <= i`` and (``i - j < window`` or
     ``j < num_global``).  The diagonal is always present (window >= 1),
     so no row is empty and the fused kernel's softmax-over-present-
-    entries semantics coincide with dense masked softmax.
+    entries semantics coincide with dense masked softmax.  Columns are
+    sorted within each row: the globals before the window, then the
+    window ``lo .. i``.
     """
     from ..core import CSRMatrix
     assert window >= 1, window
     S = int(seq_len)
     g = min(int(num_global), S)
+    i = np.arange(S, dtype=np.int64)
+    lo = np.maximum(i - window + 1, 0)      # the window's first key
+    n_glob = np.minimum(lo, g)              # globals left of the window
+    length = n_glob + i - lo + 1
     row_ptr = np.zeros(S + 1, np.int64)
-    cols = []
-    for i in range(S):
-        lo = max(0, i - window + 1)
-        local = range(lo, i + 1)
-        if g and lo > g:
-            row_cols = list(range(g)) + list(local)
-        else:
-            row_cols = list(range(min(lo, g))) + list(local)
-        cols.extend(row_cols)
-        row_ptr[i + 1] = len(cols)
-    col_indices = np.asarray(cols, np.int32)
-    vals = jnp.ones((len(cols),), jnp.float32)
+    np.cumsum(length, out=row_ptr[1:])
+    nnz = int(row_ptr[-1])
+    row = np.repeat(i, length)
+    pos = np.arange(nnz, dtype=np.int64) - row_ptr[row]
+    col_indices = np.where(pos < n_glob[row], pos,
+                           lo[row] + pos - n_glob[row]).astype(np.int32)
+    vals = jnp.ones((nnz,), jnp.float32)
     return CSRMatrix((S, S), row_ptr, col_indices, vals)
 
 
@@ -59,9 +62,9 @@ def sparse_attention_mask(seq_len: int, window: int, num_global: int = 0):
 def _mask_and_artifact(seq_len: int, head_dim: int, window: int,
                        num_global: int, backend: str,
                        interpret: Optional[bool]):
-    import jax
-
     from ..core import compile_sparse_attention
+    from ..kernels.ops import record_build_seconds
+    t0 = time.perf_counter()
     # the first call usually happens INSIDE a trace (the layer runs
     # under lax.scan); the artifact's descriptor tables are constants
     # cached across traces, so they must be concrete, not trace-staged
@@ -70,31 +73,26 @@ def _mask_and_artifact(seq_len: int, head_dim: int, window: int,
         art = compile_sparse_attention(a, head_dim, head_dim,
                                        backend=backend,
                                        interpret=interpret)
+    record_build_seconds("sattn_mask", time.perf_counter() - t0)
     return a, art
 
 
-def sparse_self_attention_layer(p, x, *, positions, head_dim, num_heads,
-                                num_kv_heads, window, num_global=0,
-                                rope_theta=1e4, qk_norm=False,
-                                norm_eps=1e-5, backend="auto",
-                                interpret=None):
-    """Pre-norm sparse self-attention block: x + sattn(norm(x)).
+def sparse_attend(q, k, v, *, window, num_global=0, backend="auto",
+                  interpret=None):
+    """The attend step of a sattn slot: softmax over the causal
+    window+global mask of the scaled scores ``q . k``, times ``v``,
+    through the fused artifact, one call per (batch, head) with GQA
+    head sharing (kv head = h // (H // KV)).
 
-    Same residual shape as :func:`~repro.models.layers.
-    self_attention_layer`; the attend step runs the fused artifact per
-    (batch, head) with GQA head sharing (kv head = h // (H // KV)).
+    q (B, S, H, hd), k/v (B, S, KV, hd), already projected and rotated;
+    returns (B, S, H, hd) in q's dtype.  The train forward and the
+    serving prefill both attend through here.
     """
-    B, S, _ = x.shape
-    h = layers.rms_norm(x, p["ln"], norm_eps)
-    q, k, v = layers.attn_project_qkv(p, h, num_heads, num_kv_heads,
-                                      head_dim, qk_norm=qk_norm,
-                                      norm_eps=norm_eps)
-    q = layers.apply_rope(q, positions, rope_theta)
-    k = layers.apply_rope(k, positions, rope_theta)
+    B, S, num_heads, head_dim = q.shape
+    G = num_heads // k.shape[2]
     a, art = _mask_and_artifact(S, head_dim, int(window), int(num_global),
                                 backend, interpret)
     vals = jnp.ones((a.nnz,), jnp.float32)
-    G = num_heads // num_kv_heads
     outs = []
     for b in range(B):
         per_head = [
@@ -105,6 +103,26 @@ def sparse_self_attention_layer(p, x, *, positions, head_dim, num_heads,
             for hh in range(num_heads)
         ]
         outs.append(jnp.stack(per_head, axis=1))        # (S, H, hd)
-    out = jnp.stack(outs, axis=0).astype(x.dtype)       # (B, S, H, hd)
+    return jnp.stack(outs, axis=0).astype(q.dtype)      # (B, S, H, hd)
+
+
+def sparse_self_attention_layer(p, x, *, positions, head_dim, num_heads,
+                                num_kv_heads, window, num_global=0,
+                                rope_theta=1e4, qk_norm=False,
+                                norm_eps=1e-5, backend="auto",
+                                interpret=None):
+    """Pre-norm sparse self-attention block: x + sattn(norm(x)).
+
+    Same residual shape as :func:`~repro.models.layers.
+    self_attention_layer`; the attend step is :func:`sparse_attend`.
+    """
+    h = layers.rms_norm(x, p["ln"], norm_eps)
+    q, k, v = layers.attn_project_qkv(p, h, num_heads, num_kv_heads,
+                                      head_dim, qk_norm=qk_norm,
+                                      norm_eps=norm_eps)
+    q = layers.apply_rope(q, positions, rope_theta)
+    k = layers.apply_rope(k, positions, rope_theta)
+    out = sparse_attend(q, k, v, window=window, num_global=num_global,
+                        backend=backend, interpret=interpret)
     out = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
     return x + out

@@ -138,15 +138,16 @@ def _init_dense_ffn(cfg: ArchConfig, rng, dt):
 
 def _init_moe_ffn(cfg: ArchConfig, rng, dt):
     D, F, E = cfg.d_model, cfg.d_ff, cfg.num_experts
+    n = E if cfg.experts_held is None else cfg.experts_held[1]
     ks = jax.random.split(rng, 4)
     s = 0.02
     so = 0.02 / (2 * cfg.num_layers) ** 0.5
     return {
         "ln": jnp.ones((D,), dt),
         "router": jax.random.normal(ks[0], (D, E), jnp.float32) * s,
-        "w_gate": jax.random.normal(ks[1], (E, D, F), dt) * s,
-        "w_up": jax.random.normal(ks[2], (E, D, F), dt) * s,
-        "w_down": jax.random.normal(ks[3], (E, F, D), dt) * so,
+        "w_gate": jax.random.normal(ks[1], (n, D, F), dt) * s,
+        "w_up": jax.random.normal(ks[2], (n, D, F), dt) * s,
+        "w_down": jax.random.normal(ks[3], (n, F, D), dt) * so,
     }
 
 
@@ -204,8 +205,8 @@ def _init_rwkv(cfg: ArchConfig, rng, dt):
 
 
 # "sattn" (sparse attention, DESIGN.md §13) reuses the attn projection
-# stack verbatim — only the attend step differs (fused descriptor-stream
-# sandwich in train, dense masked fallback in serve)
+# stack verbatim — only the attend step differs (the fused descriptor-
+# stream sandwich in train and prefill, dense masked in decode)
 _SLOT_INIT = {"attn": _init_attn, "xattn": _init_xattn,
               "sattn": _init_attn,
               "mamba": _init_mamba, "rwkv": _init_rwkv}
@@ -249,6 +250,7 @@ def _apply_ffn(cfg, slot_params, x, shard_ctx=None):
         x, aux = moe.moe_ffn(slot_params["ffn_moe"], x,
                              num_experts=cfg.num_experts, top_k=cfg.top_k,
                              capacity_factor=cfg.capacity_factor,
+                             experts_held=cfg.experts_held,
                              norm_eps=cfg.norm_eps, shard_ctx=shard_ctx)
     return x, aux
 
@@ -258,22 +260,25 @@ def _apply_slot_train(cfg: ArchConfig, kind: str, slot_params, x, positions,
                       unroll_chunks=False, shard_ctx=None,
                       causal_skip=False):
     if kind == "attn":
-        x = layers.self_attention_layer(
-            slot_params["attn"], x, positions=positions,
-            head_dim=cfg.head_dim, num_heads=cfg.num_heads,
-            num_kv_heads=cfg.num_kv_heads, rope_theta=cfg.rope_theta,
-            causal=True, window=cfg.sliding_window, qk_norm=cfg.qk_norm,
-            norm_eps=cfg.norm_eps, chunk_q=chunk_q,
-            unroll_chunks=unroll_chunks, causal_skip=causal_skip)
+        with jax.named_scope("attn"):
+            x = layers.self_attention_layer(
+                slot_params["attn"], x, positions=positions,
+                head_dim=cfg.head_dim, num_heads=cfg.num_heads,
+                num_kv_heads=cfg.num_kv_heads, rope_theta=cfg.rope_theta,
+                rope_yarn=cfg.rope_yarn, causal=True,
+                window=cfg.sliding_window, qk_norm=cfg.qk_norm,
+                norm_eps=cfg.norm_eps, chunk_q=chunk_q,
+                unroll_chunks=unroll_chunks, causal_skip=causal_skip)
     elif kind == "sattn":
-        x = sparse_attention.sparse_self_attention_layer(
-            slot_params["sattn"], x, positions=positions,
-            head_dim=cfg.head_dim, num_heads=cfg.num_heads,
-            num_kv_heads=cfg.num_kv_heads,
-            window=cfg.sparse_attn_window,
-            num_global=cfg.sparse_attn_global,
-            rope_theta=cfg.rope_theta, qk_norm=cfg.qk_norm,
-            norm_eps=cfg.norm_eps)
+        with jax.named_scope("sattn"):
+            x = sparse_attention.sparse_self_attention_layer(
+                slot_params["sattn"], x, positions=positions,
+                head_dim=cfg.head_dim, num_heads=cfg.num_heads,
+                num_kv_heads=cfg.num_kv_heads,
+                window=cfg.sparse_attn_window,
+                num_global=cfg.sparse_attn_global,
+                rope_theta=cfg.rope_theta, qk_norm=cfg.qk_norm,
+                norm_eps=cfg.norm_eps)
     elif kind == "xattn":
         x = layers.cross_attention_layer(
             slot_params["xattn"], x, image_embeds, head_dim=cfg.head_dim,
@@ -404,15 +409,16 @@ def init_decode_cache(cfg: ArchConfig, batch: int, cache_len: int):
 # Decode step (one new token against the caches)
 # ---------------------------------------------------------------------------
 
-def _decode_attn(cfg, p, x, cache, pos, *, window=None, num_global=0):
+def _decode_attn(cfg, p, x, cache, pos, *, window=None, num_global=0,
+                 yarn=None):
     B = x.shape[0]
     h = layers.rms_norm(x, p["ln"], cfg.norm_eps)
     q, k, v = layers.attn_project_qkv(p, h, cfg.num_heads, cfg.num_kv_heads,
                                       cfg.head_dim, qk_norm=cfg.qk_norm,
                                       norm_eps=cfg.norm_eps)
     posb = jnp.broadcast_to(pos[None, None], (B, 1)).astype(jnp.int32)
-    q = layers.apply_rope(q, posb, cfg.rope_theta)
-    k = layers.apply_rope(k, posb, cfg.rope_theta)
+    q = layers.apply_rope(q, posb, cfg.rope_theta, yarn)
+    k = layers.apply_rope(k, posb, cfg.rope_theta, yarn)
     T = cache["k"].shape[1]
     idx = (pos % T).astype(jnp.int32)
     ck = jax.lax.dynamic_update_slice(cache["k"], k.astype(cache["k"].dtype),
@@ -460,7 +466,8 @@ def forward_decode(cfg: ArchConfig, params, token, caches, pos, *,
             if kind == "attn":
                 x, nc = _decode_attn(cfg, sp["attn"], x,
                                      cache_p[f"slot{i}"], pos,
-                                     window=cfg.sliding_window)
+                                     window=cfg.sliding_window,
+                                     yarn=cfg.rope_yarn)
             elif kind == "sattn":
                 # serve-side fallback: dense masked attention with the
                 # SAME window+global mask the fused train path encodes
@@ -499,6 +506,51 @@ def forward_decode(cfg: ArchConfig, params, token, caches, pos, *,
 # Prefill (forward + cache build) — serving path
 # ---------------------------------------------------------------------------
 
+def _prefill_attn(cfg: ArchConfig, kind: str, p, x, positions,
+                  cache_len: int, *, chunk_q: int, unroll_chunks: bool,
+                  causal_skip: bool):
+    """One attn or sattn slot over the whole prompt, and its KV cache.
+
+    attn slots attend densely (RoPE with ``cfg.rope_yarn``) and keep
+    the last ``attn_cache_len`` positions; sattn slots attend through
+    the fused sparse-attention artifact (plain RoPE), as the train
+    forward does, and keep a full-length cache: global tokens must
+    survive, so there is no windowed eviction."""
+    B, S = positions.shape
+    h = layers.rms_norm(x, p["ln"], cfg.norm_eps)
+    q, k, v = layers.attn_project_qkv(
+        p, h, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
+        qk_norm=cfg.qk_norm, norm_eps=cfg.norm_eps)
+    yarn = cfg.rope_yarn if kind == "attn" else None
+    q = layers.apply_rope(q, positions, cfg.rope_theta, yarn)
+    k = layers.apply_rope(k, positions, cfg.rope_theta, yarn)
+    if kind == "sattn":
+        out = sparse_attention.sparse_attend(
+            q, k, v, window=cfg.sparse_attn_window,
+            num_global=cfg.sparse_attn_global)
+        T = cache_len
+    else:
+        if causal_skip:
+            out = layers.gqa_attention_causal_skip(
+                q, k, v, q_positions=positions, kv_positions=positions,
+                window=cfg.sliding_window, chunk_q=chunk_q)
+        else:
+            out = layers.gqa_attention(
+                q, k, v, q_positions=positions, kv_positions=positions,
+                causal=True, window=cfg.sliding_window, chunk_q=chunk_q,
+                unroll_chunks=unroll_chunks)
+        T = attn_cache_len(cfg, cache_len)
+    out = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
+    keep = min(S, T)
+    ck = jnp.zeros((B, T) + k.shape[2:], k.dtype
+                   ).at[:, :keep].set(k[:, -keep:])
+    cv = jnp.zeros((B, T) + v.shape[2:], v.dtype
+                   ).at[:, :keep].set(v[:, -keep:])
+    ckpos = jnp.full((B, T), UNFILLED_POS, jnp.int32
+                     ).at[:, :keep].set(positions[:, -keep:])
+    return x + out, {"k": ck, "v": cv, "kpos": ckpos}
+
+
 def prefill(cfg: ArchConfig, params, tokens, cache_len: int, *,
             image_embeds=None, chunk_q: int = 512, ssm_chunk: int = 256,
             scan_unroll: bool = False, unroll_chunks: bool = False,
@@ -514,65 +566,12 @@ def prefill(cfg: ArchConfig, params, tokens, cache_len: int, *,
         new_caches = {}
         for i, kind in enumerate(cfg.pattern):
             sp = period_params[f"slot{i}"]
-            if kind == "attn":
-                p = sp["attn"]
-                h = layers.rms_norm(x, p["ln"], cfg.norm_eps)
-                q, k, v = layers.attn_project_qkv(
-                    p, h, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
-                    qk_norm=cfg.qk_norm, norm_eps=cfg.norm_eps)
-                q = layers.apply_rope(q, positions, cfg.rope_theta)
-                k = layers.apply_rope(k, positions, cfg.rope_theta)
-                if causal_skip:
-                    out = layers.gqa_attention_causal_skip(
-                        q, k, v, q_positions=positions,
-                        kv_positions=positions, window=cfg.sliding_window,
-                        chunk_q=chunk_q)
-                else:
-                    out = layers.gqa_attention(
-                        q, k, v, q_positions=positions,
-                        kv_positions=positions, causal=True,
-                        window=cfg.sliding_window, chunk_q=chunk_q,
-                        unroll_chunks=unroll_chunks)
-                out = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
-                x = x + out
-                T = attn_cache_len(cfg, cache_len)
-                keep = min(S, T)
-                ck = jnp.zeros((B, T) + k.shape[2:], k.dtype
-                               ).at[:, :keep].set(k[:, -keep:])
-                cv = jnp.zeros((B, T) + v.shape[2:], v.dtype
-                               ).at[:, :keep].set(v[:, -keep:])
-                ckpos = jnp.full((B, T), UNFILLED_POS, jnp.int32
-                                 ).at[:, :keep].set(positions[:, -keep:])
-                new_caches[f"slot{i}"] = {"k": ck, "v": cv, "kpos": ckpos}
-            elif kind == "sattn":
-                # dense masked fallback for serving (see _decode_attn's
-                # sattn branch); cache is full-length — global tokens
-                # must survive, so there is no windowed eviction here
-                p = sp["sattn"]
-                h = layers.rms_norm(x, p["ln"], cfg.norm_eps)
-                q, k, v = layers.attn_project_qkv(
-                    p, h, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
-                    qk_norm=cfg.qk_norm, norm_eps=cfg.norm_eps)
-                q = layers.apply_rope(q, positions, cfg.rope_theta)
-                k = layers.apply_rope(k, positions, cfg.rope_theta)
-                out = layers.gqa_attention(
-                    q, k, v, q_positions=positions,
-                    kv_positions=positions, causal=True,
-                    window=cfg.sparse_attn_window,
-                    num_global=cfg.sparse_attn_global, chunk_q=chunk_q,
-                    unroll_chunks=unroll_chunks)
-                out = jnp.einsum("bshk,hkd->bsd", out,
-                                 p["wo"].astype(x.dtype))
-                x = x + out
-                T = cache_len
-                keep = min(S, T)
-                ck = jnp.zeros((B, T) + k.shape[2:], k.dtype
-                               ).at[:, :keep].set(k[:, -keep:])
-                cv = jnp.zeros((B, T) + v.shape[2:], v.dtype
-                               ).at[:, :keep].set(v[:, -keep:])
-                ckpos = jnp.full((B, T), UNFILLED_POS, jnp.int32
-                                 ).at[:, :keep].set(positions[:, -keep:])
-                new_caches[f"slot{i}"] = {"k": ck, "v": cv, "kpos": ckpos}
+            if kind in ("attn", "sattn"):
+                with jax.named_scope(kind):
+                    x, new_caches[f"slot{i}"] = _prefill_attn(
+                        cfg, kind, sp[kind], x, positions, cache_len,
+                        chunk_q=chunk_q, unroll_chunks=unroll_chunks,
+                        causal_skip=causal_skip)
             elif kind == "xattn":
                 p = sp["xattn"]
                 kv = layers.rms_norm(image_embeds, p["ln_kv"], cfg.norm_eps)
