@@ -17,7 +17,11 @@ Phases, all in this one process (a chip belongs to one process):
              against ``ref``.
   attention  ``compile_sparse_attention`` with longformer-1.4b's mask
              (window 512, 64 global columns, head_dim 128) at S=4096,
-             one (Q, K, V) head against ``ref``.
+             one (Q, K, V) head against ``ref``; then the MXU panel each
+             attention cell of the chip benchmark resolves to (Mellum2's
+             causal window of 1,024 at head_dim 128, Longformer-large's
+             two-sided window of 512 with 64 global tokens at 64), with
+             its panels per call and their fill.
   --chips 4  sharded ``pallas_bcsr`` over a 4-chip ``("chips",)`` mesh
              on the 2^20 graph against ``ref`` and against the one-chip
              fused output; the per-chip tables must sit on four devices.
@@ -121,6 +125,37 @@ def describe_fused(c) -> str:
             f"gathered={c.vals_gather_elems}; VPU/MXU descriptors "
             f"{int((tags == 0).sum())} / {int((tags == 1).sum())}; "
             f"piece trips {int(np.asarray(fw.cont).sum())}")
+
+
+def describe_panel(c) -> str:
+    """The MXU panel an attention artifact resolved to, the panels one
+    call multiplies, their fill (nonzeros over slots), and whether its
+    descriptor stream holds VPU trips."""
+    return (f"panel {c.bm}x{c.bk}: {c.mxu_panels} MXU panels per call, "
+            f"fill {c.panel_fill:.4f}, VPU trips {c._vpu}")
+
+
+def benchmark_attention_masks():
+    """The attention cells' masks with their head widths: Mellum2's
+    through the program's own mask builder, Longformer's through the
+    benchmark's generator (loaded by path)."""
+    import importlib.util
+    import jax.numpy as jnp
+    from repro.core import CSRMatrix
+    from repro.models.sparse_attention import sparse_attention_mask
+    path = (Path(__file__).resolve().parent / "chipbench" / "gen"
+            / "longformer_mask.py")
+    spec = importlib.util.spec_from_file_location("longformer_mask", path)
+    gen = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(gen)
+    rp, cols, shape = gen.generate({"seq_len": 4096,
+                                    "attention_window": 512,
+                                    "global_tokens": 64})
+    longformer = CSRMatrix(shape, rp, cols,
+                           jnp.ones((cols.size,), jnp.float32))
+    return {"mellum2.prefill_4k": (sparse_attention_mask(4096, 1024, 0),
+                                   128),
+            "longformer.attn_fwd": (longformer, 64)}
 
 
 def slot_table_devices(c) -> list:
@@ -248,7 +283,8 @@ def phase_attention(seed: int):
                      f"({t_mask:.2f}s); compile {t_plan:.2f}s: "
                      f"backend={art.backend} staging={art.staging} "
                      f"interpret={art.interpret}; VPU/MXU descriptors "
-                     f"{int((tags == 0).sum())} / {int((tags == 1).sum())}")
+                     f"{int((tags == 0).sum())} / {int((tags == 1).sum())}"
+                     f"; {describe_panel(art)}")
     if art.interpret or art.staging != "dma":
         raise AssertionError("attention did not resolve to the staged "
                              "native kernel")
@@ -263,6 +299,11 @@ def phase_attention(seed: int):
     ref = compile_sparse_attention(mask, dh, backend="ref")
     check("attention", "forward", row_scaled_error(
         y, ref(mask.vals, q, k, v)))
+    for cell, (cell_mask, cell_dh) in benchmark_attention_masks().items():
+        c = compile_sparse_attention(cell_mask, cell_dh)
+        log("attention", f"{cell}: nnz {cell_mask.nnz} dh {cell_dh}, "
+                         f"{describe_panel(c)}; slots={c.vals_slots} "
+                         f"gathered={c.vals_gather_elems}")
 
 
 def phase_four_chips(seed: int):

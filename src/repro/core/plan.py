@@ -735,6 +735,93 @@ def tag_block_rows(row_ptr: np.ndarray, col_indices: np.ndarray, shape,
     return mxu_rows, vpu_rows
 
 
+# Square MXU panel edges a sparse-attention plan may take: from the
+# sublane tile to the lane width (a panel's columns are lanes).
+PANEL_EDGES = (8, 16, 32, 64, 128)
+
+# The least share of a panel's slots that must hold a nonzero for the
+# planner to take the next larger edge (DESIGN.md §13.4).  A trip's time
+# on the chip is set per panel (393 ns at 8x8, 1.3 us at 128x128), so
+# larger panels pay until their padding costs more than the panels they
+# save; the share also bounds what an eager call's staging gathers,
+# ``nnz / fill`` elements.  Set between the two benchmark masks'
+# readings on a TPU v5e (PERF.md section 5): at 128x128 Mellum2's
+# window fills 0.89 and its kernel halves, Longformer's fills 0.73 and
+# its gather grows by 5 ms per call against 0.2 ms of kernel saved.
+MIN_PANEL_FILL = 0.8
+
+
+@dataclasses.dataclass(frozen=True)
+class PanelStats:
+    """The MXU side of tagging a mask at one ``(bm, bk)`` panel:
+    ``panels`` and ``nnz`` over the block-rows that go MXU, and whether
+    every block-row does (an empty block-row goes VPU)."""
+    bm: int
+    bk: int
+    panels: int
+    nnz: int
+    all_mxu: bool
+
+    @property
+    def fill(self) -> float:
+        """Nonzeros over the MXU panels' slots (0 without panels)."""
+        return self.nnz / (self.panels * self.bm * self.bk or 1)
+
+
+def panel_stats(row_ptr: np.ndarray, col_indices: np.ndarray, shape,
+                panels, *, mxu_gain: float = 4.0) -> List[PanelStats]:
+    """:class:`PanelStats` of the mask at each ``(bm, bk)`` of
+    ``panels``, by the rule of :func:`tag_block_rows` but without
+    packing.  The occupied panels of a panel that divides the previous
+    one's are coarsened from the previous set, so a run of doubling
+    edges sorts the nonzeros once."""
+    row_ptr = np.asarray(row_ptr, np.int64)
+    cols = np.asarray(col_indices, np.int64)
+    m, n = shape
+    lengths = np.diff(row_ptr)
+    out: List[PanelStats] = []
+    keys = prev = None
+    for bm, bk in panels:
+        nbr, nbc = -(-m // bm), -(-n // bk)
+        if prev is not None and bm % prev[0] == 0 and bk % prev[1] == 0:
+            br, bc = np.divmod(keys, prev[2])
+            keys = np.unique(br // (bm // prev[0]) * nbc
+                             + bc // (bk // prev[1]))
+        else:
+            rows = np.repeat(np.arange(m, dtype=np.int64), lengths)
+            keys = np.unique(rows // bm * nbc + cols // bk)
+        prev = (bm, bk, nbc)
+        K = np.bincount(keys // nbc, minlength=nbr)
+        Lmax = np.pad(lengths, (0, nbr * bm - m)).reshape(nbr, bm).max(1)
+        mxu = (K > 0) & (K * bk <= mxu_gain * Lmax)
+        nnz = row_ptr[np.minimum(np.arange(1, nbr + 1) * bm, m)] \
+            - row_ptr[np.arange(nbr) * bm]
+        out.append(PanelStats(bm=bm, bk=bk, panels=int(K[mxu].sum()),
+                              nnz=int(nnz[mxu].sum()),
+                              all_mxu=bool(mxu.all())))
+    return out
+
+
+def choose_panel_edge(row_ptr: np.ndarray, col_indices: np.ndarray, shape,
+                      *, mxu_gain: float = 4.0, chips: int = 1) -> int:
+    """The square MXU panel edge of a sparse-attention plan, from the
+    mask: the largest of :data:`PANEL_EDGES` up to which each larger
+    edge still tags every block-row MXU, fills at least
+    :data:`MIN_PANEL_FILL` of its panels' slots and gives each of
+    ``chips`` a block-row.  A ragged or random mask, whose block-rows
+    go VPU at a larger edge, keeps the smallest."""
+    m = shape[0]
+    b = PANEL_EDGES[0]
+    for s in panel_stats(row_ptr, col_indices, shape,
+                         [(e, e) for e in PANEL_EDGES],
+                         mxu_gain=mxu_gain)[1:]:
+        if not (s.all_mxu and s.fill >= MIN_PANEL_FILL
+                and -(-m // s.bm) >= chips):
+            break
+        b = s.bm
+    return b
+
+
 def build_mixed_plan(row_ptr: np.ndarray, col_indices: np.ndarray, shape,
                      d: int, *, strategy: str = "nnz_split",
                      row_block: int = 8, bk: int = 8,

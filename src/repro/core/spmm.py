@@ -45,12 +45,14 @@ from jax.sharding import Mesh
 from . import ccm
 from .csr import CSRMatrix
 from .jit_cache import GLOBAL_CACHE, JitCache, mesh_fingerprint
-from .plan import (MXU_TAG, SPARSE_ATTN_EINSUM, SPARSE_ATTN_MIXED_EINSUM,
+from .plan import (MXU_TAG, PANEL_EDGES, SPARSE_ATTN_EINSUM,
+                   SPARSE_ATTN_MIXED_EINSUM, VPU_TAG,
                    BatchedFusedWorkspace, MixedPlan,
                    ShardedFusedWorkspace, SpmmPlan,
                    build_batched_workspace, build_einsum_workspace,
                    build_fused_workspace, build_mixed_plan, build_plan,
                    build_sharded_workspace, choose_merge_width,
+                   choose_panel_edge, panel_stats,
                    sharded_workspace_row_maps, workspace_row_map)
 from ..analysis.verify import (PlanVerificationError, check_workspace,
                                resolve_validate)
@@ -1035,10 +1037,41 @@ def spmm(a: CSRMatrix, x, *, strategy: str = "nnz_split",
     return compiled(jnp.asarray(a.vals), x)
 
 
+def _attention_panel(a: CSRMatrix, backend: str, bm: Optional[int],
+                     bk: Optional[int], mxu_gain: float,
+                     mesh: Optional[Mesh]) -> tuple:
+    """The ``(bm, bk)`` MXU panel of an attention artifact: as the
+    caller gave it (a missing one is 8), or, where neither is given, a
+    square panel derived from the mask on the mixed backend
+    (:func:`~repro.core.plan.choose_panel_edge`, DESIGN.md §13.4).  The
+    other backends have no MXU trip and keep 8x8."""
+    if bm is not None or bk is not None:
+        return (PANEL_EDGES[0] if bm is None else int(bm),
+                PANEL_EDGES[0] if bk is None else int(bk))
+    if backend != "pallas_bcsr":
+        return PANEL_EDGES[0], PANEL_EDGES[0]
+    b = choose_panel_edge(a.row_ptr, a.col_indices, a.shape,
+                          mxu_gain=mxu_gain,
+                          chips=1 if mesh is None else mesh.size)
+    return b, b
+
+
+def _runs_vpu_trips(ws) -> bool:
+    """Whether a (solo or chip-stacked) workspace holds a VPU trip
+    that runs; an inert pad (``L == 0``) runs the same under either
+    trip body."""
+    return bool(np.any((ws.blk_tag == VPU_TAG) & (ws.blk_L > 0)))
+
+
 class CompiledSparseAttention(_SlotValueCounts):
     """Structure-specialized sparse attention: out = softmax(mask ⊙
     (Q·Kᵀ)) · V, lowered as ONE fused pallas_call (per chip) through
     the same descriptor stream as SpMM (DESIGN.md §13).
+
+    Where neither ``bm`` nor ``bk`` is given, the MXU panel is derived
+    from the mask (:func:`_attention_panel`); ``bm``/``bk`` hold the
+    resolved panel, and ``mxu_panels`` / ``panel_fill`` what one call
+    multiplies.
 
     ``a`` is the (m queries × n keys) mask pattern; its values are the
     mask weights ``w`` (1.0 for a plain binary mask), giving
@@ -1064,9 +1097,10 @@ class CompiledSparseAttention(_SlotValueCounts):
 
     def __init__(self, a: CSRMatrix, dh: int, dv: Optional[int] = None,
                  *, strategy: str = "nnz_split", backend: str = "auto",
-                 bm: int = 8, interpret: Optional[bool] = None,
+                 bm: Optional[int] = None,
+                 interpret: Optional[bool] = None,
                  mesh: Optional[Mesh] = None,
-                 n_chips: Optional[int] = None, bk: int = 8,
+                 n_chips: Optional[int] = None, bk: Optional[int] = None,
                  mxu_gain: float = 4.0, staging: Optional[str] = None,
                  merge_threshold: int = 0,
                  sm_scale: Optional[float] = None,
@@ -1079,8 +1113,6 @@ class CompiledSparseAttention(_SlotValueCounts):
                 "sparse attention has no dense backend — use ref as the "
                 "oracle")
         self.strategy = strategy
-        self.bm = bm
-        self.bk = bk
         self.mxu_gain = mxu_gain
         self.merge_threshold = int(merge_threshold)
         self.interpret = resolve_interpret(interpret)
@@ -1094,6 +1126,9 @@ class CompiledSparseAttention(_SlotValueCounts):
                 f"mesh/n_chips sharding is a fused-dispatch feature "
                 f"({'/'.join(FUSED_BACKENDS)}); backend="
                 f"{self.backend!r} is single-device")
+        bm, bk = _attention_panel(a, self.backend, bm, bk, mxu_gain,
+                                  self.mesh)
+        self.bm, self.bk = bm, bk
         self.cache = cache
         self.dh = int(dh)
         self.dv = int(dh) if dv is None else int(dv)
@@ -1114,6 +1149,14 @@ class CompiledSparseAttention(_SlotValueCounts):
         self._fused: Optional[_FusedConsts] = None
         self._sharded: Optional[_ShardedConsts] = None
         self._row_map: Optional[jax.Array] = None   # ws slot -> Q row
+        self._panels = None                          # PanelStats
+        self._vpu = True      # whether the stream holds VPU trips
+        if self.backend in FUSED_BACKENDS:
+            # the ELL backend tags nothing MXU: no panels
+            self._panels = panel_stats(
+                a.row_ptr, a.col_indices, a.shape, [(bm, bk)],
+                mxu_gain=mxu_gain if self.backend == "pallas_bcsr"
+                else 0.0)[0]
         if self.backend in FUSED_BACKENDS and self.mesh is not None:
             sw: ShardedFusedWorkspace = build_sharded_workspace(
                 a.row_ptr, a.col_indices, a.shape, self.dv,
@@ -1135,6 +1178,7 @@ class CompiledSparseAttention(_SlotValueCounts):
                 context=f"compile_sparse_attention[{self.backend}"
                         f"/sharded]")
             self._sharded = _sharded_consts(sw, self.mesh)
+            self._vpu = _runs_vpu_trips(sw)
             self._row_map = jnp.asarray(row_maps)
             _record_build(
                 sum(p.plan_seconds for p in sw.shard_plans),
@@ -1163,6 +1207,7 @@ class CompiledSparseAttention(_SlotValueCounts):
                 vals=np.asarray(a.vals), row_map=row_map,
                 context=f"compile_sparse_attention[{self.backend}]")
             self._fused = _fused_consts(ws, a.nnz)
+            self._vpu = _runs_vpu_trips(ws)
             self._row_map = jnp.asarray(row_map)
             _record_build(0.0, ws.pack_seconds)
         elif self.backend != "ref":
@@ -1186,6 +1231,18 @@ class CompiledSparseAttention(_SlotValueCounts):
 
         _apply.defvjp(_apply_fwd, _apply_bwd)
         self._apply = _apply
+
+    @property
+    def mxu_panels(self) -> Optional[int]:
+        """MXU panels of ``bm x bk`` one call multiplies; None off the
+        fused backends."""
+        return None if self._panels is None else self._panels.panels
+
+    @property
+    def panel_fill(self) -> Optional[float]:
+        """Nonzeros over the slots of those panels (0 without any);
+        None off the fused backends."""
+        return None if self._panels is None else self._panels.fill
 
     def _kv_bytes(self) -> int:
         """VMEM bytes of the resident K and V panels."""
@@ -1263,7 +1320,8 @@ class CompiledSparseAttention(_SlotValueCounts):
             tables = (fw.blk_tag, fw.blk_off, fw.blk_coff, fw.blk_L,
                       fw.cols_flat, vals_flat, q_ws, k_pad, v_pad, fw.cont)
             knobs = dict(bm=self.bm, bk=self.bk, mw=fw.merge_width,
-                         interpret=self.interpret, staging=self.staging)
+                         vpu=self._vpu, interpret=self.interpret,
+                         staging=self.staging)
             if sharded:
                 y_ws = kops.attn_fused_sharded_op(
                     *tables, mesh=fw.mesh, span=fw.chip_span,
@@ -1284,11 +1342,13 @@ class CompiledSparseAttention(_SlotValueCounts):
 def compile_sparse_attention(a: CSRMatrix, dh: int,
                              dv: Optional[int] = None, *,
                              strategy: str = "nnz_split",
-                             backend: str = "auto", bm: int = 8,
+                             backend: str = "auto",
+                             bm: Optional[int] = None,
                              interpret: Optional[bool] = None,
                              mesh: Optional[Mesh] = None,
                              n_chips: Optional[int] = None,
-                             bk: int = 8, mxu_gain: float = 4.0,
+                             bk: Optional[int] = None,
+                             mxu_gain: float = 4.0,
                              staging: Optional[str] = None,
                              merge_threshold: int = 0,
                              sm_scale: Optional[float] = None,
@@ -1300,12 +1360,17 @@ def compile_sparse_attention(a: CSRMatrix, dh: int,
     ``"attn"`` family: the mask fingerprint, BOTH runtime widths
     (head dim and value dim), the softmax scale, and every resolved
     knob join the cache key, so a pattern served at a new head size is
-    a new artifact while repeated (B, H) instances of one layer hit."""
+    a new artifact while repeated (B, H) instances of one layer hit.
+
+    ``bm``/``bk`` pin the MXU panel; where neither is given it is
+    derived from the mask (a square panel of 8 to 128 on the mixed
+    backend, DESIGN.md §13.4), and the resolved panel joins the key."""
     backend = _resolve_backend(
         backend, sharded=mesh is not None or n_chips is not None)
     interpret = resolve_interpret(interpret)
     staging = _resolve_staging_for(backend, staging, interpret)
     mesh = resolve_chip_mesh(mesh, n_chips)
+    bm, bk = _attention_panel(a, backend, bm, bk, mxu_gain, mesh)
     merge_threshold = int(merge_threshold)
     dv = int(dh) if dv is None else int(dv)
     sm_scale = float(dh) ** -0.5 if sm_scale is None else float(sm_scale)
@@ -1324,9 +1389,11 @@ def compile_sparse_attention(a: CSRMatrix, dh: int,
 
 def sparse_attention(a: CSRMatrix, q, k, v, *,
                      strategy: str = "nnz_split", backend: str = "auto",
-                     bm: int = 8, interpret: Optional[bool] = None,
+                     bm: Optional[int] = None,
+                     interpret: Optional[bool] = None,
                      mesh: Optional[Mesh] = None,
-                     n_chips: Optional[int] = None, bk: int = 8,
+                     n_chips: Optional[int] = None,
+                     bk: Optional[int] = None,
                      mxu_gain: float = 4.0,
                      staging: Optional[str] = None,
                      merge_threshold: int = 0,
@@ -1334,7 +1401,9 @@ def sparse_attention(a: CSRMatrix, q, k, v, *,
                      validate: Optional[str] = None,
                      cache: JitCache = GLOBAL_CACHE) -> jax.Array:
     """One-shot convenience: softmax(mask ⊙ (Q·Kᵀ)) · V specialized to
-    the mask's structure and the runtime head/value widths."""
+    the mask's structure and the runtime head/value widths; the MXU
+    panel is derived from the mask where neither ``bm`` nor ``bk`` is
+    given, as in :func:`compile_sparse_attention`."""
     compiled = compile_sparse_attention(
         a, q.shape[1], v.shape[1], strategy=strategy, backend=backend,
         bm=bm, interpret=interpret, mesh=mesh, n_chips=n_chips, bk=bk,

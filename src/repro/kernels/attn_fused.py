@@ -91,12 +91,15 @@ def _scores(q_blk, kp):
 
 
 def _member(w, *, tag, L, cont, carries, j, q_ref, k_ref, v_ref,
-            col, weight, weight_panel, bm: int, bk: int, dt: int):
+            col, weight, weight_panel, bm: int, bk: int, dt: int,
+            vpu: bool):
     """One member descriptor of a merged trip: its own tag dispatch and
     its own ``(acc, m, l)`` softmax carry, started from the previous
     trip's on a piece trip.  ``col(i)``/``weight(i)`` read the i-th
     entry of the member's column/weight stream, ``weight_panel(k)`` its
-    k-th MXU weight panel."""
+    k-th MXU weight panel.  ``vpu`` False (a stream of MXU trips only)
+    lowers the MXU trip alone: no branch on the tag and no VPU trip,
+    whose ``bm`` single-row K/V loads are unrolled."""
     cacc, cm, cl = carries
     rows = pl.ds(w * bm, bm)
     q_blk = q_ref[rows, :].astype(jnp.float32)
@@ -136,6 +139,8 @@ def _member(w, *, tag, L, cont, carries, j, q_ref, k_ref, v_ref,
                 jnp.float32)), weight_panel(kk), vp.astype(jnp.float32))
         return jax.lax.fori_loop(0, L, blk_step, carry0)
 
+    if not vpu:
+        return mxu_block()
     return jax.lax.cond(tag == _VPU, vpu_block, mxu_block)
 
 
@@ -152,7 +157,7 @@ def _finish(results, carries, j, y_ref, mw: int):
 
 def _kernel(tag_ref, off_ref, coff_ref, L_ref, cont_ref, cols_ref,
             vals_s_ref, vals_ref, q_ref, k_ref, v_ref, y_ref, cacc, cm, cl,
-            *, bm: int, bk: int, dt: int, mw: int):
+            *, bm: int, bk: int, dt: int, mw: int, vpu: bool):
     g = pl.program_id(0)
     j = pl.program_id(1)
     carries = (cacc, cm, cl)
@@ -167,7 +172,7 @@ def _kernel(tag_ref, off_ref, coff_ref, L_ref, cont_ref, cols_ref,
             weight=lambda i: vals_s_ref[off + i],
             weight_panel=lambda k: st.panel_at(vals_ref, off // LANE
                                                + k * bm, bm, bk),
-            bm=bm, bk=bk, dt=dt)
+            bm=bm, bk=bk, dt=dt, vpu=vpu)
 
     _finish([member(w) for w in range(mw)], carries, j, y_ref, mw)
 
@@ -175,17 +180,19 @@ def _kernel(tag_ref, off_ref, coff_ref, L_ref, cont_ref, cols_ref,
 def _staged_kernel(tag_ref, off_ref, coff_ref, L_ref, cont_ref, cols_ref,
                    vals_ref, q_ref, k_ref, v_ref, y_ref, cring, vring,
                    mring, cacc, cm, cl, csem, vsem, *, bm: int, bk: int,
-                   dt: int, span: int, cspan: int, mw: int):
+                   dt: int, span: int, cspan: int, mw: int, vpu: bool):
     """Double-buffered twin of :func:`_kernel` (DESIGN.md §7.7/§13):
     the same trip windows and rings as the staged SpMM kernel; Q/K/V
-    stay resident BlockSpec panels."""
+    stay resident BlockSpec panels.  Without VPU trips the SMEM value
+    ring is one tile that no copy fills."""
     g = pl.program_id(0)
     j = pl.program_id(1)
     ng = pl.num_programs(0)
     carries = (cacc, cm, cl)
     st.stage_trip_windows(tag_ref, off_ref, coff_ref, cols_ref, vals_ref,
                           cring, vring, mring, csem, vsem, g=g, j=j, ng=ng,
-                          mw=mw, vrows=span // LANE, crows=cspan // LANE)
+                          mw=mw, vrows=span // LANE, crows=cspan // LANE,
+                          vpu=vpu)
     slot = g % 2
     vbase = st.window_base(off_ref[g * mw]) * LANE
     cbase = st.window_base(coff_ref[g * mw]) * LANE
@@ -200,7 +207,7 @@ def _staged_kernel(tag_ref, off_ref, coff_ref, L_ref, cont_ref, cols_ref,
             weight=lambda i: st.read(vring, slot, loff + i),
             weight_panel=lambda k: st.panel(mring, slot, loff // LANE
                                             + k * bm, bm, bk),
-            bm=bm, bk=bk, dt=dt)
+            bm=bm, bk=bk, dt=dt, vpu=vpu)
 
     _finish([member(w) for w in range(mw)], carries, j, y_ref, mw)
 
@@ -221,12 +228,13 @@ def _prepare(blk_L, k, v, cont, bk: int, mw: int):
     return k, v, cont
 
 
-@functools.partial(jax.jit, static_argnames=("bm", "bk", "mw", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("bm", "bk", "mw", "vpu", "interpret"))
 def attn_fused(blk_tag: jax.Array, blk_off: jax.Array,
                blk_coff: jax.Array, blk_L: jax.Array,
                cols_flat: jax.Array, vals_flat: jax.Array,
                q_ws: jax.Array, k: jax.Array, v: jax.Array, cont=None, *,
-               bm: int = 8, bk: int = 8, mw: int = 1,
+               bm: int = 8, bk: int = 8, mw: int = 1, vpu: bool = True,
                interpret: bool = True) -> jax.Array:
     """Compute the WHOLE sparse-attention plan in one dispatch:
     Y_ws (ws_rows, dv_pad) = softmax(mask ⊙ (Q·Kᵀ)) · V.
@@ -244,6 +252,8 @@ def attn_fused(blk_tag: jax.Array, blk_off: jax.Array,
     v         : (n, dv_pad) float — value dim padded to the lane tile;
                 dv tiles the second grid axis
     cont      : (B // mw,) int32 — piece trips (default: none)
+    vpu       : whether ``blk_tag`` holds VPU blocks; False lowers the
+                MXU trip alone
 
     Returns workspace-ordered rows; the caller applies the plan's
     ``inv_perm`` gather to recover output row order.
@@ -260,7 +270,7 @@ def attn_fused(blk_tag: jax.Array, blk_off: jax.Array,
     nj = dv_pad // dt
 
     return pl.pallas_call(
-        functools.partial(_kernel, bm=bm, bk=bk, dt=dt, mw=mw),
+        functools.partial(_kernel, bm=bm, bk=bk, dt=dt, mw=mw, vpu=vpu),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=7,
             grid=(num_blocks // mw, nj),
@@ -283,13 +293,14 @@ def attn_fused(blk_tag: jax.Array, blk_off: jax.Array,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("bm", "bk", "mw", "span", "cspan", "interpret"))
+    static_argnames=("bm", "bk", "mw", "vpu", "span", "cspan",
+                     "interpret"))
 def attn_fused_staged(blk_tag: jax.Array, blk_off: jax.Array,
                       blk_coff: jax.Array, blk_L: jax.Array,
                       cols_flat: jax.Array, vals_flat: jax.Array,
                       q_ws: jax.Array, k: jax.Array, v: jax.Array,
                       cont=None, *, span: int, cspan: int, bm: int = 8,
-                      bk: int = 8, mw: int = 1,
+                      bk: int = 8, mw: int = 1, vpu: bool = True,
                       interpret: bool = True) -> jax.Array:
     """The DMA-staged fused attention dispatch — same contract as
     :func:`attn_fused` and BIT-identical output.  ``span``/``cspan``
@@ -312,7 +323,7 @@ def attn_fused_staged(blk_tag: jax.Array, blk_off: jax.Array,
 
     return pl.pallas_call(
         functools.partial(_staged_kernel, bm=bm, bk=bk, dt=dt, span=span,
-                          cspan=cspan, mw=mw),
+                          cspan=cspan, mw=mw, vpu=vpu),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(num_blocks // mw, nj),
@@ -326,7 +337,8 @@ def attn_fused_staged(blk_tag: jax.Array, blk_off: jax.Array,
             out_specs=pl.BlockSpec((mw * bm, dt), lambda g, j, *_: (g, j)),
             scratch_shapes=[
                 pltpu.SMEM((2, crows, LANE), jnp.int32),     # cols
-                pltpu.SMEM((2, vrows, LANE), jnp.float32),   # VPU weights
+                pltpu.SMEM((2, vrows if vpu else st.TILE_ROWS, LANE),
+                           jnp.float32),                     # VPU weights
                 pltpu.VMEM((2, vrows, LANE), jnp.float32),   # MXU weights
                 *_carry_scratch(nj, mw * bm, dt),
                 pltpu.SemaphoreType.DMA((2,)),
@@ -346,7 +358,8 @@ def attn_fused_sharded(blk_tag: jax.Array, blk_off: jax.Array,
                        cols_flat: jax.Array, vals_flat: jax.Array,
                        q_ws: jax.Array, k: jax.Array, v: jax.Array,
                        cont=None, *, mesh, bm: int = 8, bk: int = 8,
-                       mw: int = 1, interpret: bool = True,
+                       mw: int = 1, vpu: bool = True,
+                       interpret: bool = True,
                        staging: str = "resident", span=0,
                        cspan=0) -> jax.Array:
     """Run one fused attention dispatch per chip under ``shard_map``.
@@ -367,7 +380,7 @@ def attn_fused_sharded(blk_tag: jax.Array, blk_off: jax.Array,
         cont = jnp.zeros((blk_L.shape[0], blk_L.shape[1] // mw), jnp.int32)
     fn = _sharded_callable(mesh, bm, bk, interpret, staging,
                            st.chip_windows(span, mesh.size),
-                           st.chip_windows(cspan, mesh.size), mw)
+                           st.chip_windows(cspan, mesh.size), mw, vpu)
     return fn(blk_tag, blk_off, blk_coff, blk_L, cont, cols_flat,
               vals_flat, q_ws, k, v)
 
@@ -375,21 +388,22 @@ def attn_fused_sharded(blk_tag: jax.Array, blk_off: jax.Array,
 @functools.lru_cache(maxsize=32)
 def _sharded_callable(mesh, bm: int, bk: int, interpret: bool,
                       staging: str = "resident", spans: tuple = (0,),
-                      cspans: tuple = (0,), mw: int = 1):
+                      cspans: tuple = (0,), mw: int = 1,
+                      vpu: bool = True):
     """jit-wrapped shard_map closure, memoized per (mesh, bm, bk,
-    interpret, staging, spans, cspans, mw) — same lifecycle as the SpMM
-    twin; evicted by ``core.jit_cache.clear_global_cache``."""
+    interpret, staging, spans, cspans, mw, vpu) — same lifecycle as the
+    SpMM twin; evicted by ``core.jit_cache.clear_global_cache``."""
     (axis,) = mesh.axis_names
 
     if staging == "dma":
         def call(sp, cs):
             return functools.partial(attn_fused_staged, span=sp,
                                      cspan=cs, bm=bm, bk=bk, mw=mw,
-                                     interpret=interpret)
+                                     vpu=vpu, interpret=interpret)
         kernel = st.staged_dispatch(axis, spans, cspans, call)
     else:
         kernel = functools.partial(attn_fused, bm=bm, bk=bk, mw=mw,
-                                   interpret=interpret)
+                                   vpu=vpu, interpret=interpret)
 
     shard = P(axis)
 

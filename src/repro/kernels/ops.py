@@ -208,8 +208,8 @@ def spmm_ell_fused_sharded_op(blk_off, blk_L, cols_flat, vals_flat, x,
 def attn_fused_op(blk_tag, blk_off, blk_coff, blk_L, cols_flat,
                   vals_flat, q_ws, k, v, cont=None, *, bm: int = 8,
                   bk: int = 8,
-                  mw: int = 1, interpret=None, staging=None,
-                  span: int = 0, cspan: int = 0):
+                  mw: int = 1, vpu: bool = True, interpret=None,
+                  staging=None, span: int = 0, cspan: int = 0):
     """ONE dispatch for the whole sparse-attention sandwich (SDDMM →
     masked softmax → SpMM, DESIGN.md §13); staged launches also count
     under ``attn_fused_dma`` — the same accounting shape as the SpMM
@@ -222,17 +222,17 @@ def attn_fused_op(blk_tag, blk_off, blk_coff, blk_L, cols_flat,
         return attn_fused_staged(blk_tag, blk_off, blk_coff, blk_L,
                                  cols_flat, vals_flat, q_ws, k, v, cont,
                                  span=span, cspan=cspan, bm=bm, bk=bk,
-                                 mw=mw, interpret=interpret)
+                                 mw=mw, vpu=vpu, interpret=interpret)
     return attn_fused(blk_tag, blk_off, blk_coff, blk_L, cols_flat,
                       vals_flat, q_ws, k, v, cont, bm=bm, bk=bk, mw=mw,
-                      interpret=interpret)
+                      vpu=vpu, interpret=interpret)
 
 
 def attn_fused_sharded_op(blk_tag, blk_off, blk_coff, blk_L, cols_flat,
                           vals_flat, q_ws, k, v, cont=None, *, mesh,
                           bm: int = 8,
-                          bk: int = 8, mw: int = 1, interpret=None,
-                          staging=None, span=0, cspan=0):
+                          bk: int = 8, mw: int = 1, vpu: bool = True,
+                          interpret=None, staging=None, span=0, cspan=0):
     """One fused attention dispatch per chip: counts ``mesh.size``
     pallas_calls under ``attn_fused`` plus one ``attn_fused_sharded``
     wrapper call, ``mesh.size`` under ``attn_fused_dma`` when staged —
@@ -250,7 +250,7 @@ def attn_fused_sharded_op(blk_tag, blk_off, blk_coff, blk_L, cols_flat,
         span = cspan = (0,) * mesh.size   # resident ignores the windows
     return attn_fused_sharded(blk_tag, blk_off, blk_coff, blk_L,
                               cols_flat, vals_flat, q_ws, k, v, cont,
-                              mesh=mesh, bm=bm, bk=bk, mw=mw,
+                              mesh=mesh, bm=bm, bk=bk, mw=mw, vpu=vpu,
                               interpret=interpret, staging=staging,
                               span=span, cspan=cspan)
 

@@ -51,18 +51,24 @@ def window_copy(stream, ring, sems, slot, base, rows):
 
 def stage_trip_windows(tag_ref, off_ref, coff_ref, cols_ref, vals_ref,
                        cring, vring, mring, csem, vsem, *, g, j, ng,
-                       mw: int, vrows: int, crows: int, mxu_tag: int = 1):
+                       mw: int, vrows: int, crows: int, mxu_tag: int = 1,
+                       vpu: bool = True):
     """The trip-window pipeline of the staged kernels: at the first
     d-tile of trip ``g``, start trip ``g + 1``'s windows into the other
     ring slot and wait for trip ``g``'s.  A trip's members share one tag
     (the packer guarantees it), so its value window goes to the SMEM
     ring (VPU scalars) or the VMEM ring (MXU panels), never both.
-    Every copy is started exactly once and waited exactly once."""
+    ``vpu`` False (a stream of MXU trips only) leaves the SMEM ring
+    out.  Every copy is started exactly once and waited exactly once."""
     def trip_dmas(slot, grp, op):
         first = grp * mw
         vbase = window_base(off_ref[first])
         getattr(window_copy(cols_ref, cring, csem, slot,
                             window_base(coff_ref[first]), crows), op)()
+        if not vpu:
+            getattr(window_copy(vals_ref, mring, vsem, slot, vbase,
+                                vrows), op)()
+            return
         mxu = tag_ref[first] == mxu_tag
 
         @pl.when(mxu)

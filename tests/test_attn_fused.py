@@ -17,7 +17,10 @@ Pinned here:
   * the jit-cache key separates every resolved knob,
   * sddmm_csr's interpret auto-resolution (satellite of the same PR),
   * the model-layer bridge: sparse_self_attention_layer == dense GQA
-    attention with the equivalent window+global mask.
+    attention with the equivalent window+global mask,
+  * the MXU panel derived from the mask (DESIGN.md §13.4): every edge
+    matches the reference, the rule's choices, the cache key, and an
+    all-MXU plan's kernel without the VPU branch.
 """
 import os
 import subprocess
@@ -470,7 +473,8 @@ def test_live_lane_staging_matches_the_plain_gather(backend, staging):
     from repro.models.sparse_attention import sparse_attention_mask
     a = sparse_attention_mask(96, window=24, num_global=4)
     q, k, v = _qkv(a.m, a.n, 16, 16, seed=22)
-    art = compile_sparse_attention(a, 16, backend=backend,
+    # the 8x8 layout: 120 of each panel's 128 lanes are padding
+    art = compile_sparse_attention(a, 16, backend=backend, bm=8, bk=8,
                                    interpret=True, staging=staging,
                                    cache=JitCache())
     vals = jnp.asarray(a.vals)
@@ -485,3 +489,165 @@ def test_live_lane_staging_matches_the_plain_gather(backend, staging):
     assert np.array_equal(np.asarray(art(vals, q, k, v)), y)
     np.testing.assert_allclose(y, _dense_oracle(a, a.vals, q, k, v),
                                rtol=1e-5, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# The MXU panel derived from the mask (DESIGN.md §13.4)
+# ---------------------------------------------------------------------------
+
+def _longformer_mask(S, window, global_tokens):
+    """The benchmark's two-sided window + global-token mask."""
+    import importlib.util
+    path = ROOT / "chipbench" / "gen" / "longformer_mask.py"
+    spec = importlib.util.spec_from_file_location("longformer_mask", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.generate({"seq_len": S, "attention_window": window,
+                         "global_tokens": global_tokens})
+
+
+PANEL_MASKS = {
+    "causal_window": lambda: _window(128, 64, 0),
+    "window_global": lambda: _window(128, 48, 8),
+    "ragged_random": lambda: _mask(m=128, n=128, seed=31, density=0.06),
+}
+
+
+def _window(S, window, num_global):
+    from repro.models.sparse_attention import sparse_attention_mask
+    return sparse_attention_mask(S, window, num_global)
+
+
+@pytest.mark.parametrize("mask", sorted(PANEL_MASKS))
+@pytest.mark.parametrize("b", (8, 16, 32, 64, 128))
+@pytest.mark.parametrize("staging", ("resident", "dma"))
+def test_every_panel_edge_matches_the_reference(mask, b, staging):
+    """A pinned square panel of each edge, on masks that go all-MXU and
+    on a ragged one whose block-rows stay VPU: the fused forward
+    matches the artifact's jnp reference."""
+    a = PANEL_MASKS[mask]()
+    q, k, v = _qkv(a.m, a.n, 16, 16, seed=b)
+    c = compile_sparse_attention(a, 16, backend="pallas_bcsr", bm=b, bk=b,
+                                 interpret=True, staging=staging,
+                                 cache=JitCache())
+    assert (c.bm, c.bk) == (b, b)
+    vals = jnp.asarray(a.vals)
+    np.testing.assert_allclose(np.asarray(c(vals, q, k, v)),
+                               np.asarray(c._ref_forward(vals, q, k, v)),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_panel_edge_follows_the_mask():
+    """The rule on the benchmark's two masks at full size and at a
+    quarter of the sequence, on ragged random masks, and with more
+    chips than block-rows."""
+    from repro.core.plan import choose_panel_edge
+
+    def edge(mask, **kw):
+        rp, cols, shape = mask
+        return choose_panel_edge(rp, cols, shape, **kw)
+
+    def csr(a):
+        return a.row_ptr, a.col_indices, a.shape
+
+    for S in (4096, 1024):
+        assert edge(csr(_window(S, 1024, 0))) == 128          # Mellum
+        assert edge(_longformer_mask(S, 512, 64)) == 64       # Longformer
+    for family in ("powerlaw", "uniform", "banded"):
+        a = random_csr(256, 256, density=0.05, family=family, seed=1)
+        assert edge(csr(a)) == 8, family
+    assert edge(csr(PANEL_MASKS["ragged_random"]())) == 8
+    narrow = csr(_window(256, 128, 0))
+    assert edge(narrow) == 32
+    assert edge(narrow, chips=16) == 16      # 8 block-rows of 32
+
+
+def test_derived_panel_through_compile_sparse_attention():
+    """With neither bm nor bk given the mixed backend takes the mask's
+    panel, which joins the cache key; an explicit panel is honoured,
+    and the artifact counts the panels one call multiplies."""
+    from repro.core.plan import MXU_TAG
+    a = _window(256, 64, 0)
+    cache = JitCache()
+    c = compile_sparse_attention(a, 16, backend="pallas_bcsr",
+                                 interpret=True, cache=cache)
+    assert (c.bm, c.bk) == (16, 16) and not c._vpu
+    assert compile_sparse_attention(a, 16, backend="pallas_bcsr", bm=16,
+                                    bk=16, interpret=True,
+                                    cache=cache) is c
+    c8 = compile_sparse_attention(a, 16, backend="pallas_bcsr", bm=8,
+                                  interpret=True, cache=cache)
+    assert (c8.bm, c8.bk) == (8, 8) and c8 is not c
+    ws = c.workspace
+    assert c.mxu_panels == int(ws.blk_L[ws.blk_tag == MXU_TAG].sum())
+    assert c.panel_fill == pytest.approx(a.nnz / (c.mxu_panels * 16 * 16))
+    assert c8.mxu_panels > c.mxu_panels and c8.panel_fill > c.panel_fill
+    ell = compile_sparse_attention(a, 16, backend="pallas_ell",
+                                   interpret=True, cache=cache)
+    assert (ell.bm, ell.bk, ell.mxu_panels) == (8, 8, 0)
+    ref = compile_sparse_attention(a, 16, backend="ref", cache=cache)
+    assert ref.mxu_panels is None and ref.panel_fill is None
+    q, k, v = _qkv(a.m, a.n, 16, 16, seed=33)
+    vals = jnp.asarray(a.vals)
+    y = np.asarray(c(vals, q, k, v))
+    np.testing.assert_allclose(y, np.asarray(c8(vals, q, k, v)),
+                               rtol=1e-5, atol=1e-5)
+    # the chip-stacked lowering takes the same panel, bit for bit
+    cs = compile_sparse_attention(a, 16, backend="pallas_bcsr",
+                                  interpret=True, n_chips=MAX_CHIPS,
+                                  cache=cache)
+    assert (cs.bm, cs.mxu_panels, cs._vpu) == (16, c.mxu_panels, False)
+    assert np.array_equal(np.asarray(cs(vals, q, k, v)), y)
+
+
+def _loops(jaxpr) -> int:
+    """``while`` loops in a jaxpr, through every nested jaxpr."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == "while"
+        for val in eqn.params.values():
+            for inner in (val if isinstance(val, (tuple, list)) else (val,)):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    n += _loops(inner)
+    return n
+
+
+@pytest.mark.parametrize("staging", ("resident", "dma"))
+def test_all_mxu_kernel_holds_no_vpu_branch(staging):
+    """A plan of MXU trips only lowers one trip loop per member; a plan
+    with VPU trips lowers the VPU loop beside it."""
+    def kernel_loops(a):
+        c = compile_sparse_attention(a, 16, backend="pallas_bcsr",
+                                     interpret=True, staging=staging,
+                                     cache=JitCache())
+        q, k, v = _qkv(a.m, a.n, 16, 16)
+        jaxpr = jax.make_jaxpr(c)(jnp.asarray(a.vals), q, k, v)
+        (call,) = [e for e in _iter_eqns(jaxpr.jaxpr)
+                   if e.primitive.name == "pallas_call"]
+        return c, _loops(call.params["jaxpr"])
+
+    c, loops = kernel_loops(PANEL_MASKS["causal_window"]())
+    assert not c._vpu and loops == c._fused.merge_width
+    c, loops = kernel_loops(PANEL_MASKS["ragged_random"]())
+    assert c._vpu and loops == 2 * c._fused.merge_width
+
+
+def test_chip_smoke_logs_each_attention_cells_panel():
+    """``chip_smoke.py`` names the panel each attention cell of the chip
+    benchmark resolves to, with its panels per call and their fill."""
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", ROOT / "chip_smoke.py")
+    smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(smoke)
+    want = {"mellum2.prefill_4k": (128, 252), "longformer.attn_fwd": (64, 674)}
+    for cell, (mask, dh) in smoke.benchmark_attention_masks().items():
+        c = compile_sparse_attention(mask, dh, backend="pallas_bcsr",
+                                     interpret=True, staging="dma",
+                                     cache=JitCache())
+        b, panels = want[cell]
+        assert (c.bm, c.bk, c.mxu_panels) == (b, b, panels), cell
+        line = smoke.describe_panel(c)
+        assert line.startswith(f"panel {b}x{b}: {panels} MXU panels per "
+                               f"call, fill {c.panel_fill:.4f}"), line

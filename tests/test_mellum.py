@@ -86,6 +86,24 @@ def test_prefill_and_train_forward_match_the_reference(small_params,
     assert np.all(np.asarray(c["kpos"][0, :, S:]) == 2 ** 30)
 
 
+def test_prefill_through_the_derived_panel_matches_the_reference(
+        small_params, fused_sattn):
+    """A window wide enough that the mask's own panel is larger than
+    8x8: the sattn slots run through it, and the prefill still agrees
+    with the reference."""
+    cfg = dataclasses.replace(SMALL, sparse_attn_window=64)
+    B, S = 1, 128
+    toks = _tokens(B, S, 2)
+    want = ref.forward(cfg, small_params, toks)
+    with jax.default_matmul_precision("highest"):
+        logits, _ = transformer.prefill(cfg, small_params, toks,
+                                        cache_len=S)
+    _, art = sparse_attention._mask_and_artifact(
+        S, cfg.head_dim, 64, 0, "pallas_bcsr", None)
+    assert (art.bm, art.bk) == (16, 16) and not art._vpu
+    assert _scaled_gap(logits, want) < 1e-5
+
+
 def test_sattn_mask_build_is_counted():
     ops.reset_dispatch_counts()
     from repro.models.sparse_attention import sparse_attend

@@ -133,6 +133,28 @@ def test_attention_dma_compiles_at_4096(one_chip):
              q, kv, kv, t["cont"])
 
 
+@pytest.mark.parametrize("b", (8, 16, 32, 64, 128))
+def test_attention_panel_edges_compile_at_4096(one_chip, b):
+    """Mellum2's causal window of 1,024, head_dim 128, at each square
+    panel edge: an all-MXU plan, lowered without the VPU branch."""
+    S = 4096
+    m = sparse_attention_mask(S, 1024, 0)
+    ws = build_einsum_workspace(SPARSE_ATTN_MIXED_EINSUM, m.row_ptr,
+                                m.col_indices, m.shape, D, row_block=b,
+                                bk=b)
+    assert not np.any(ws.blk_tag == 0)
+    t = _tables(ws, one_chip)
+    q, kv = _attention_operands(ws, S, one_chip)
+    compiled = _compile(
+        lambda tag, off, coff, L, cols, vals, q, k, v, cont:
+        attn_fused_staged(tag, off, coff, L, cols, vals, q, k, v, cont,
+                          span=ws.max_span, cspan=ws.max_cspan, bm=b,
+                          bk=b, vpu=False, interpret=False),
+        t["tag"], t["off"], t["coff"], t["L"], t["cols"], t["vals"],
+        q, kv, kv, t["cont"])
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 @pytest.mark.parametrize("family", ("spmm", "attention"))
 def test_resident_compiles_at_small_size(one_chip, family):
     a = random_csr(256, 256, density=0.05, family="banded", seed=1)

@@ -139,8 +139,8 @@ def test_artifacts_record_their_gathered_elements():
                          cache=JitCache())
     assert mixed.vals_gather_elems < mixed.vals_slots
     attn = compile_sparse_attention(mask, 16, backend="pallas_bcsr",
-                                    mxu_gain=float("inf"), interpret=True,
-                                    cache=JitCache())
+                                    bm=8, bk=8, mxu_gain=float("inf"),
+                                    interpret=True, cache=JitCache())
     assert attn.vals_gather_elems <= attn.vals_slots // 8
     batched = compile_batched_spmm([a, a], 16, backend="pallas_bcsr",
                                    interpret=True, cache=JitCache())
