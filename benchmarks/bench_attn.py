@@ -2,21 +2,13 @@
 in-register segment softmax → S·V through the descriptor stream,
 DESIGN.md §13).
 
-Two fixtures, both small enough for interpret-mode CPU:
-
-  * the longformer mask the ``"sattn"`` model slot actually builds
-    (causal window + global columns, ``models/sparse_attention.py``) —
-    the resident, ``_dma``-staged and 1-chip ``_sharded`` cells;
-  * a skewed long-tail mask (positive weights — the §13 non-negativity
-    contract) where CGCM merging collapses the grid — the ``_merged``
-    cell, with the same must-actually-merge assertion the SpMM bench
-    carries.
+The fixture is the longformer mask the ``"sattn"`` model slot
+actually builds (causal window + global columns,
+``models/sparse_attention.py``), small enough for interpret-mode CPU:
+the resident and ``_dma``-staged cells.
 
 Cell naming follows benchmarks/common.py: the staging axis is the
-``_dma`` bench-name suffix, merging ``_merged``, the skew fixture
-``_skew``; sharded cells are PINNED to 1 chip so record keys never
-depend on visible devices (the mesh8 pytest leg covers real
-multi-chip).  Dispatches per call come from
+``_dma`` bench-name suffix.  Dispatches per call come from
 ``DISPATCH_COUNTS["attn_fused"]`` — the Table IV one-launch-per-chip
 invariant extended to attention — and each staged cell additionally
 asserts it really took the DMA lowering.
@@ -38,24 +30,8 @@ except ImportError:          # plain-script run: python benchmarks/...
 
 from repro.core import CSRMatrix, compile_sparse_attention
 from repro.core.jit_cache import JitCache
-from repro.core.plan import SPARSE_ATTN_EINSUM, build_einsum_workspace
 from repro.kernels import ops
 from repro.models.sparse_attention import sparse_attention_mask
-
-
-def _skewed_mask(seed: int = 17) -> CSRMatrix:
-    """Long tail of 1-nnz rows + a few hot rows, POSITIVE weights (the
-    §13 contract): short block-rows dominate, so CGCM merging collapses
-    most of the grid while the hot rows keep their own trips."""
-    rng = np.random.default_rng(seed)
-    n = 96
-    lengths = np.asarray([1] * 88 + [72] * 8, np.int64)
-    row_ptr = np.concatenate([[0], np.cumsum(lengths)])
-    cols = np.concatenate(
-        [np.sort(rng.choice(n, size=int(ln), replace=False))
-         for ln in lengths]).astype(np.int32)
-    vals = rng.uniform(0.2, 2.0, int(row_ptr[-1])).astype(np.float32)
-    return CSRMatrix((len(lengths), n), row_ptr, cols, vals)
 
 
 def _qkv(a: CSRMatrix, dh: int, dv: int, seed: int):
@@ -65,18 +41,12 @@ def _qkv(a: CSRMatrix, dh: int, dv: int, seed: int):
             jnp.asarray(rng.standard_normal((a.n, dv)), jnp.float32))
 
 
-def _timed_cell(bench, strategy, backend, n_chips, a, q, k, v, *,
-                staging=None, merge_threshold=0):
+def _timed_cell(bench, strategy, backend, a, q, k, v, *, staging=None):
     """One attention smoke cell: compile, time, count launches."""
-    kw = dict(strategy=strategy, backend=backend, interpret=True,
-              cache=JitCache())
-    if n_chips:
-        kw["n_chips"] = n_chips
-    if staging:
-        kw["staging"] = staging
-    if merge_threshold:
-        kw["merge_threshold"] = merge_threshold
-    c = compile_sparse_attention(a, q.shape[1], v.shape[1], **kw)
+    c = compile_sparse_attention(a, q.shape[1], v.shape[1],
+                                 strategy=strategy, backend=backend,
+                                 interpret=True, staging=staging,
+                                 cache=JitCache())
     vals = jnp.asarray(a.vals)
     ops.reset_dispatch_counts()
     # min-of-7 at warmup 2, like the SpMM cells: the gate compares at
@@ -89,52 +59,19 @@ def _timed_cell(bench, strategy, backend, n_chips, a, q, k, v, *,
         assert ops.DISPATCH_COUNTS["attn_fused_dma"] > 0, \
             f"{bench}: staged cell fell back to the resident lowering"
     dispatches = ops.DISPATCH_COUNTS["attn_fused"] / calls
-    return bench_record(bench, strategy, backend, n_chips, us / 1e3,
-                        dispatches)
+    return bench_record(bench, strategy, backend, 0, us / 1e3, dispatches)
 
 
 def smoke_records() -> list:
     """CI bench-smoke cells (schema: benchmarks/common.py) for the
     fused attention hot path: wall per call + pallas launches per call
-    on the resident AND DMA-staged lowerings, single-chip and
-    1-chip-sharded, plus the CGCM-merged skew suite."""
-    records = []
+    on the resident AND DMA-staged lowerings."""
     a = sparse_attention_mask(96, 12, num_global=4)
     q, k, v = _qkv(a, 16, 16, seed=3)
-    for strategy in ("row_split", "nnz_split", "merge_split"):
-        records.append(_timed_cell("attn_fused", strategy, "pallas_ell",
-                                   0, a, q, k, v))
-        records.append(_timed_cell("attn_fused_dma", strategy,
-                                   "pallas_ell", 0, a, q, k, v,
-                                   staging="dma"))
-    records.append(_timed_cell("attn_fused", "nnz_split", "pallas_bcsr",
-                               0, a, q, k, v))
-    records.append(_timed_cell("attn_fused_dma", "nnz_split",
-                               "pallas_bcsr", 0, a, q, k, v,
-                               staging="dma"))
-    records.append(_timed_cell("attn_fused_sharded", "nnz_split",
-                               "pallas_ell", 1, a, q, k, v))
-    records.append(_timed_cell("attn_fused_dma_sharded", "nnz_split",
-                               "pallas_ell", 1, a, q, k, v,
-                               staging="dma"))
-    # merged skew suite: assert the merge stage actually shrank the
-    # grid, so the bench can never silently report an inert merge
-    sk = _skewed_mask()
-    sq, skk, sv = _qkv(sk, 16, 16, seed=5)
-    ws0 = build_einsum_workspace(SPARSE_ATTN_EINSUM, sk.row_ptr,
-                                 sk.col_indices, sk.shape, 16,
-                                 merge_threshold=0)
-    ws1 = build_einsum_workspace(SPARSE_ATTN_EINSUM, sk.row_ptr,
-                                 sk.col_indices, sk.shape, 16,
-                                 merge_threshold=16)
-    assert ws1.num_trips < ws0.num_blocks, \
-        "CGCM must shrink the skewed attention grid (merge stage inert?)"
-    records.append(_timed_cell("attn_fused_skew", "nnz_split",
-                               "pallas_ell", 0, sk, sq, skk, sv))
-    records.append(_timed_cell("attn_fused_skew_merged", "nnz_split",
-                               "pallas_ell", 0, sk, sq, skk, sv,
-                               merge_threshold=16))
-    return records
+    return [_timed_cell("attn_fused", "nnz_split", "pallas_bcsr",
+                        a, q, k, v),
+            _timed_cell("attn_fused_dma", "nnz_split", "pallas_bcsr",
+                        a, q, k, v, staging="dma")]
 
 
 if __name__ == "__main__":

@@ -15,8 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import (TuneConfig, autotune_spmm, build_plan,
-                        compile_spmm, random_csr)
+from repro.core import compile_spmm, random_csr
 from repro.core.jit_cache import JitCache
 from repro.core.plan import build_fused_workspace, build_mixed_plan
 from repro.kernels import ops
@@ -44,8 +43,9 @@ def run() -> list:
         t1 = time.perf_counter()
         compile_spmm(a, 16, backend="ref", cache=cache)
         hit_us = (time.perf_counter() - t1) * 1e6
-        # descriptor-table packing cost for the fused pallas_ell path
-        plan = build_plan(a.row_ptr, a.col_indices, a.shape, 16)
+        # descriptor-table packing cost of the fused all-VPU plan
+        plan = build_mixed_plan(a.row_ptr, a.col_indices, a.shape, 16,
+                                mxu_gain=0.0)
         t2 = time.perf_counter()
         build_fused_workspace(plan)
         ws_ms = (time.perf_counter() - t2) * 1e3
@@ -76,52 +76,17 @@ def smoke_records() -> list:
     records = []
     a = random_csr(512, 512, density=0.02, family="powerlaw", seed=3)
     for strategy in ("row_split", "nnz_split", "merge_split"):
-        ell_ms = med_ms(lambda: build_fused_workspace(build_plan(
-            a.row_ptr, a.col_indices, a.shape, 16, strategy=strategy)))
-        records.append(bench_record("codegen_plan", strategy,
-                                    "pallas_ell", 0, ell_ms, 0))
         mixed_ms = med_ms(lambda: build_fused_workspace(build_mixed_plan(
             a.row_ptr, a.col_indices, a.shape, 16, strategy=strategy)))
         records.append(bench_record("codegen_plan", strategy,
                                     "pallas_bcsr", 0, mixed_ms, 0))
-    # per-key build seconds as the DISPATCH plumbing reports them
-    # (kernels.ops.BUILD_SECONDS, fed by compile_spmm): plan + pack of
-    # one fused compile — the Table IV "codegen" figure users actually
-    # pay, as opposed to the isolated med_ms cells above.  Sub-ms cells
-    # gate on coverage only (min_wall_ms), so noise can't trip them.
+    # validate="off" (the production default on TPU) must contribute
+    # EXACTLY zero host seconds of static verification to the dispatch
+    # path (DESIGN.md §15)
     small = random_csr(256, 256, density=0.03, family="powerlaw", seed=3)
     ops.reset_dispatch_counts()
-    compile_spmm(small, 16, backend="pallas_ell", interpret=True,
-                 cache=JitCache())
-    records.append(bench_record("codegen_build_plan_s", "nnz_split",
-                                "pallas_ell", 0,
-                                ops.BUILD_SECONDS["plan"] * 1e3, 0))
-    records.append(bench_record("codegen_build_pack_s", "nnz_split",
-                                "pallas_ell", 0,
-                                ops.BUILD_SECONDS["pack"] * 1e3, 0))
-    # the static verifier (DESIGN.md §15) runs at validate="full" under
-    # interpret mode, so the compile above already paid it — the cell
-    # keeps the honest cost next to plan/pack in the Table IV story
-    records.append(bench_record("codegen_verify_s", "nnz_split",
-                                "pallas_ell", 0,
-                                ops.BUILD_SECONDS["verify"] * 1e3, 0))
-    # ... and validate="off" (the production default on TPU) must
-    # contribute EXACTLY zero host seconds to the dispatch path
-    ops.reset_dispatch_counts()
-    compile_spmm(small, 16, backend="pallas_ell", interpret=True,
+    compile_spmm(small, 16, backend="pallas_bcsr", interpret=True,
                  validate="off", cache=JitCache())
     assert ops.BUILD_SECONDS["verify"] == 0.0, \
         "validate='off' must never touch the verifier"
-    # the autotune search cost (DESIGN.md §11) on the same fixture —
-    # one predict pass over 4 candidates + 1 measured compile; the
-    # point the cell tracks is that the search stays codegen-sized
-    ops.reset_dispatch_counts()
-    autotune_spmm(small, 16, backend="pallas_ell", interpret=True,
-                  candidates=[TuneConfig(strategy=s, merge_threshold=t)
-                              for s in ("row_split", "nnz_split")
-                              for t in (0, 16)],
-                  top_k=1, measure=lambda c, v, x: 0.0,
-                  cache=JitCache())
-    records.append(bench_record("codegen_tune_s", "auto", "pallas_ell",
-                                0, ops.BUILD_SECONDS["tune"] * 1e3, 0))
     return records

@@ -6,9 +6,9 @@ metric the strategies compete on), and speedup vs the AOT dense
 baseline.  The skewed (powerlaw) family is where nnz/merge-split beat
 row-split — the paper's motivating case.
 
-A second sweep times the fused pallas_ell hot path (interpret mode, so
-a smaller matrix) and reports the Table IV dispatch invariant: one
-pallas_call per instance, whatever the plan's segment count — the
+A second sweep times the fused all-VPU plan (``mxu_gain=0``; interpret
+mode, so a smaller matrix) and reports the Table IV dispatch invariant:
+one pallas_call per instance, whatever the plan's segment count — the
 single-segment row_split cell is the no-regression baseline the fused
 refactor is held to.
 
@@ -36,9 +36,8 @@ except ImportError:          # plain-script run: python benchmarks/...
     sys.path.insert(0, str(_ROOT))           # benchmarks package
     from benchmarks.common import bench_record, csv_row, time_fn
 
-from repro.core import (CSRMatrix, TuneConfig, autotune_spmm_with_result,
-                        build_plan, build_workspace, compile_spmm,
-                        random_csr)
+from repro.core import (TuneConfig, autotune_spmm_with_result, build_plan,
+                        build_workspace, compile_spmm, random_csr)
 from repro.core.jit_cache import JitCache
 from repro.kernels import ops
 
@@ -62,8 +61,9 @@ def _chip_sweep(max_chips: int) -> list:
             rows.append(csv_row(f"sharded_ell_c{n_chips}_m512_d16", 0.0,
                                 f"SKIPPED:only_{avail}_devices"))
             continue
-        c = compile_spmm(a, 16, strategy="nnz_split", backend="pallas_ell",
-                         interpret=True, n_chips=n_chips, cache=JitCache())
+        c = compile_spmm(a, 16, strategy="nnz_split", backend="pallas_bcsr",
+                         mxu_gain=0.0, interpret=True, n_chips=n_chips,
+                         cache=JitCache())
         ops.reset_dispatch_counts()
         warmup, iters = 1, 3
         us = time_fn(c, vals, x, warmup=warmup, iters=iters)
@@ -73,7 +73,7 @@ def _chip_sweep(max_chips: int) -> list:
             f"sharded_ell_c{n_chips}_m512_d16", us,
             f"efficiency={eff:.3f};"
             f"launches_per_call="
-            f"{ops.DISPATCH_COUNTS['ell_fused'] / calls:.0f}"))
+            f"{ops.DISPATCH_COUNTS['bcsr_fused'] / calls:.0f}"))
     return rows
 
 
@@ -99,22 +99,22 @@ def run(n_chips: int = 0) -> list:
                     f"segments={len(plan.segments)};"
                     f"speedup_vs_dense={us_dense/us:.2f}x"))
 
-    # fused pallas_ell dispatch sweep (interpret mode => small instance)
+    # fused all-VPU dispatch sweep (interpret mode => small instance)
     a = random_csr(256, 256, density=0.03, family="powerlaw", seed=7)
     x = jnp.asarray(rng.standard_normal((256, 16)), jnp.float32)
     vals = jnp.asarray(a.vals)
     for strategy in ("row_split", "nnz_split", "merge_split"):
-        c = compile_spmm(a, 16, strategy=strategy, backend="pallas_ell",
-                         interpret=True, cache=JitCache())
+        c = compile_spmm(a, 16, strategy=strategy, backend="pallas_bcsr",
+                         mxu_gain=0.0, interpret=True, cache=JitCache())
         ops.reset_dispatch_counts()
         warmup, iters = 1, 3
         us = time_fn(c, vals, x, warmup=warmup, iters=iters)
         calls = warmup + iters
         rows.append(csv_row(
             f"fused_ell_{strategy}_m256_d16", us,
-            f"segments={len(c.plan.segments)};"
+            f"segments={len(c.mixed_plan.vpu.segments)};"
             f"launches_per_call="
-            f"{ops.DISPATCH_COUNTS['ell_fused'] / calls:.0f}"))
+            f"{ops.DISPATCH_COUNTS['bcsr_fused'] / calls:.0f}"))
 
     if n_chips > 0:
         rows += _chip_sweep(n_chips)
@@ -150,31 +150,12 @@ def _timed_cell(bench, strategy, backend, n_chips, a, x, *, counter,
                         dispatches)
 
 
-def _skewed_csr(seed: int = 13) -> CSRMatrix:
-    """The CGCM motivating fixture: a long tail of 1-nnz rows plus a few
-    hot rows — short block-rows dominate, so merging collapses most of
-    the grid while the hot rows keep their own trips."""
-    rng = np.random.default_rng(seed)
-    n = 128
-    lengths = np.asarray([1] * 120 + [96] * 8, np.int64)
-    row_ptr = np.concatenate([[0], np.cumsum(lengths)])
-    cols = np.concatenate(
-        [np.sort(rng.choice(n, size=int(ln), replace=False))
-         for ln in lengths]).astype(np.int32)
-    vals = rng.standard_normal(int(row_ptr[-1])).astype(np.float32)
-    return CSRMatrix((len(lengths), n), row_ptr, cols, vals)
-
-
-def _tuned_suite(bench, backend, a, x, *, counter, fixed=()):
-    """Autotuned smoke cells (DESIGN.md §11): every candidate — the
-    strategy × merge-threshold grid ⊇ the fixed cells' configs — is
-    MEASURED with the identical min-of-7 timer, so the tuned cell's
-    wall is min over the fixed configs BY CONSTRUCTION.  ``fixed`` is
-    a list of ``(bench_name, TuneConfig)`` sibling cells emitted from
-    the SAME measurement pass (single-timing-pass suites keep that
-    ordering exact instead of noise-approximate).  The tuned record's
-    strategy field is pinned to "auto": the winner's identity may
-    legitimately drift run to run, the record key must not."""
+def _tuned_suite(bench, backend, a, x, *, counter):
+    """The autotuned smoke cell (DESIGN.md §11): every candidate of the
+    strategy × merge-threshold grid is MEASURED with the identical
+    min-of-7 timer.  The tuned record's strategy field is pinned to
+    "auto": the winner's identity may legitimately drift run to run,
+    the record key must not."""
     cands = [TuneConfig(strategy=s, merge_threshold=t)
              for s in ("row_split", "nnz_split", "merge_split")
              for t in (0, 16)]
@@ -186,27 +167,16 @@ def _tuned_suite(bench, backend, a, x, *, counter, fixed=()):
     c, res = autotune_spmm_with_result(
         a, x.shape[1], backend=backend, interpret=True, candidates=cands,
         top_k=len(cands), measure=measure, cache=cache)
-    vals = jnp.asarray(a.vals)
-    records = []
-    for name, cfg in fixed:
-        cc = compile_spmm(a, x.shape[1], backend=backend, interpret=True,
-                          cache=cache, **cfg.compile_kwargs())
-        ops.reset_dispatch_counts()
-        jax.block_until_ready(cc(vals, x))
-        records.append(bench_record(name, cfg.strategy, backend, 0,
-                                    res.measured_s[cfg] * 1e3,
-                                    ops.DISPATCH_COUNTS[counter]))
     ops.reset_dispatch_counts()
-    jax.block_until_ready(c(vals, x))
-    records.append(bench_record(bench, "auto", backend, 0,
-                                res.best_measured_s * 1e3,
-                                ops.DISPATCH_COUNTS[counter]))
-    return records
+    jax.block_until_ready(c(jnp.asarray(a.vals), x))
+    return [bench_record(bench, "auto", backend, 0,
+                         res.best_measured_s * 1e3,
+                         ops.DISPATCH_COUNTS[counter])]
 
 
 def smoke_records() -> list:
     """CI bench-smoke cells (schema: benchmarks/common.py): the fused
-    VPU and mixed VPU/MXU hot paths, unsharded + sharded, on fixtures
+    mixed VPU/MXU hot path, unsharded + sharded, on fixtures
     small enough for interpret-mode CPU.  Tracks the two regression
     axes that matter for the hot path: wall-clock per call and
     pallas_call launches per call (the Table IV fusion invariant)."""
@@ -221,13 +191,8 @@ def smoke_records() -> list:
     # shard_map dispatch path; real multi-chip behavior is covered by
     # the mesh8 pytest leg, not the bench trajectory.
     for strategy in ("row_split", "nnz_split", "merge_split"):
-        records.append(_timed_cell("fused_ell", strategy, "pallas_ell",
-                                   0, a, x, counter="ell_fused"))
         records.append(_timed_cell("fused_mixed", strategy, "pallas_bcsr",
                                    0, a, x, counter="bcsr_fused"))
-    records.append(_timed_cell("fused_ell_sharded", "nnz_split",
-                               "pallas_ell", 1, a, x,
-                               counter="ell_fused"))
     records.append(_timed_cell("fused_mixed_sharded", "nnz_split",
                                "pallas_bcsr", 1, a, x,
                                counter="bcsr_fused"))
@@ -237,15 +202,9 @@ def smoke_records() -> list:
     # wall cells track the emulation's plumbing cost, not TPU overlap;
     # the dispatch counts pin the fusion invariant on the staged path.
     for strategy in ("row_split", "nnz_split", "merge_split"):
-        records.append(_timed_cell("fused_ell_dma", strategy,
-                                   "pallas_ell", 0, a, x,
-                                   counter="ell_fused", staging="dma"))
         records.append(_timed_cell("fused_mixed_dma", strategy,
                                    "pallas_bcsr", 0, a, x,
                                    counter="bcsr_fused", staging="dma"))
-    records.append(_timed_cell("fused_ell_dma_sharded", "nnz_split",
-                               "pallas_ell", 1, a, x,
-                               counter="ell_fused", staging="dma"))
     records.append(_timed_cell("fused_mixed_dma_sharded", "nnz_split",
                                "pallas_bcsr", 1, a, x,
                                counter="bcsr_fused", staging="dma"))
@@ -256,16 +215,9 @@ def smoke_records() -> list:
     # whole exchange path (all_to_all, strip packing, remap); the wall
     # cell tracks its plumbing cost, the dispatch count pins the
     # one-call-per-chip invariant on the x-sharded lowering.
-    records.append(_timed_cell("fused_ell_xshard", "nnz_split",
-                               "pallas_ell", 1, a, x,
-                               counter="ell_fused", x_sharding="rows"))
     records.append(_timed_cell("fused_mixed_xshard", "nnz_split",
                                "pallas_bcsr", 1, a, x,
                                counter="bcsr_fused", x_sharding="rows"))
-    records.append(_timed_cell("fused_ell_dma_xshard", "nnz_split",
-                               "pallas_ell", 1, a, x,
-                               counter="ell_fused", staging="dma",
-                               x_sharding="rows"))
     records.append(_timed_cell("fused_mixed_dma_xshard", "nnz_split",
                                "pallas_bcsr", 1, a, x,
                                counter="bcsr_fused", staging="dma",
@@ -281,31 +233,10 @@ def smoke_records() -> list:
                           merge_threshold=16)
     assert ws1.num_trips < ws0.num_blocks, \
         "CGCM must shrink the powerlaw grid (merge stage inert?)"
-    records.append(_timed_cell("fused_ell_merged", "nnz_split",
-                               "pallas_ell", 0, a, x,
-                               counter="ell_fused", merge_threshold=16))
     records.append(_timed_cell("fused_mixed_merged", "nnz_split",
                                "pallas_bcsr", 0, a, x,
                                counter="bcsr_fused", merge_threshold=16))
-    records.append(_timed_cell("fused_ell_dma_merged", "nnz_split",
-                               "pallas_ell", 0, a, x,
-                               counter="ell_fused", staging="dma",
-                               merge_threshold=16))
-    # autotuned cells (DESIGN.md §11) + the skewed long-tail suite
-    # merging exists for: the skew fixed/merged cells are emitted from
-    # the SAME measurement pass as the search, so tuned ≤ fixed and
-    # tuned ≤ merged hold exactly, not just within timer noise
-    sk = _skewed_csr()
-    xs = jnp.asarray(rng.standard_normal((sk.n, 16)), jnp.float32)
-    records += _tuned_suite(
-        "fused_ell_skew_tuned", "pallas_ell", sk, xs,
-        counter="ell_fused",
-        fixed=[("fused_ell_skew", TuneConfig(strategy="nnz_split",
-                                             merge_threshold=0)),
-               ("fused_ell_skew_merged",
-                TuneConfig(strategy="nnz_split", merge_threshold=16))])
-    records += _tuned_suite("fused_ell_tuned", "pallas_ell", a, x,
-                            counter="ell_fused")
+    # the autotuned cell (DESIGN.md §11)
     records += _tuned_suite("fused_mixed_tuned", "pallas_bcsr", a, x,
                             counter="bcsr_fused")
     return records

@@ -12,14 +12,13 @@ and gate against the checked-in baseline.
   python -m benchmarks.smoke --update-baseline
 
 Record schema and gate semantics: benchmarks/common.py.  Cells come
-from ``bench_strategies.smoke_records`` (fused VPU + mixed VPU/MXU
-dispatch wall/launch counts: resident AND ``_dma``-staged lowerings,
-CGCM-``_merged`` and autotuned ``_tuned`` cells on the powerlaw and
-``_skew`` suites), ``bench_attn.smoke_records`` (the fused
-sparse-attention sandwich, DESIGN.md §13: resident/``_dma``/sharded
-``attn_fused*`` wall + launch cells on the longformer mask plus the
-``_skew``/``_merged`` suite), ``bench_codegen_overhead.smoke_records``
-(plan/pack/tune host cost via ``kernels.ops.BUILD_SECONDS``) and
+from ``bench_strategies.smoke_records`` (fused mixed VPU/MXU dispatch
+wall/launch counts: resident AND ``_dma``-staged lowerings, 1-chip
+``_sharded`` and ``_xshard``, CGCM-``_merged`` and autotuned ``_tuned``
+cells on the powerlaw fixture), ``bench_attn.smoke_records`` (the
+fused sparse-attention sandwich, DESIGN.md §13: resident and ``_dma``
+``attn_fused*`` wall + launch cells on the longformer mask),
+``bench_codegen_overhead.smoke_records`` (plan + pack host cost) and
 ``bench_serve.smoke_records`` (the serving tier's Poisson-stream
 ``serve_p50``/``serve_p99`` latency and ``serve_cache`` miss-count
 cells, DESIGN.md §12, plus the continuous-batching scheduler's

@@ -4,7 +4,7 @@ for node classification; the neighborhood aggregation A_hat·H is our
 spmm with the structure planned once and cached across all steps.
 
   PYTHONPATH=src python examples/gnn_graphconv.py
-  # multi-chip aggregation (sharded fused pallas_ell under shard_map):
+  # multi-chip aggregation (sharded fused pallas_bcsr under shard_map):
   XLA_FLAGS=--xla_force_host_platform_device_count=8 \
       PYTHONPATH=src python examples/gnn_graphconv.py --n-chips 8
 """
@@ -20,7 +20,7 @@ from repro.core.jit_cache import JitCache
 ap = argparse.ArgumentParser()
 ap.add_argument("--n-chips", type=int, default=0,
                 help="shard the A_hat aggregation across this many chips "
-                     "via the fused pallas_ell path (0 = ref backend)")
+                     "via the fused pallas_bcsr path (0 = ref backend)")
 ap.add_argument("--x-sharding", default="auto",
                 choices=["auto", "replicated", "rows"],
                 help="feature-matrix placement on the chip mesh: "
@@ -69,12 +69,12 @@ if args.n_chips:
     if n_chips < args.n_chips:
         print(f"clamping --n-chips {args.n_chips} -> {n_chips} "
               f"(devices present)")
-    agg_kw = dict(backend="pallas_ell", interpret=None, n_chips=n_chips,
+    agg_kw = dict(backend="pallas_bcsr", interpret=None, n_chips=n_chips,
                   x_sharding=args.x_sharding)
 elif args.autotune:
-    # the search needs a fused backend; unsharded pallas_ell is the
-    # single-chip one (interpret-mode on CPU, native on TPU)
-    agg_kw = dict(backend="pallas_ell", interpret=None)
+    # the search needs the fused backend (interpret-mode on CPU,
+    # native on TPU)
+    agg_kw = dict(backend="pallas_bcsr", interpret=None)
 else:
     agg_kw = dict(backend="ref")
 if args.autotune:

@@ -27,9 +27,9 @@ print("Y:", y.shape, "matches dense:",
       bool(jnp.allclose(y, a.to_dense() @ x, atol=1e-3)))
 
 # Pallas TPU kernels, validated on CPU via interpret mode
-y_pl = spmm(a, x, strategy="nnz_split", backend="pallas_ell",
+y_pl = spmm(a, x, strategy="nnz_split", backend="pallas_bcsr",
             interpret=True)
-print("pallas_ell matches:", bool(jnp.allclose(y_pl, y, atol=1e-3)))
+print("pallas_bcsr matches:", bool(jnp.allclose(y_pl, y, atol=1e-3)))
 
 # the jit-function cache (paper Table IV): second call is a pure hit
 compiled = compile_spmm(a, 45, strategy="nnz_split", backend="ref")

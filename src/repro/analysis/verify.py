@@ -69,8 +69,8 @@ tests/test_verify.py):
   splits_malformed              row_splits/val_splits/bounds not
                                 monotone from 0
   attn_mask_negative            an attention mask weight is negative
-  attn_spec                     softmax-state flags inconsistent with
-                                the einsum spec / workspace
+  attn_spec                     a softmax spec without its Q row
+                                operand and K, V column operands
   ============================  ==========================================
 
 Adding an invariant alongside a new plan transform: pick a kind string,
@@ -767,15 +767,13 @@ def verify_batched_workspace(bw: BatchedFusedWorkspace, *,
 
 def verify_attention_contract(spec: SparseEinsumSpec,
                               vals: Optional[np.ndarray] = None, *,
-                              has_mxu: bool = False,
                               level: str = "full"
                               ) -> List[PlanViolation]:
     """The attention instantiation's extra contracts (DESIGN.md §13):
     the segment-softmax spec needs a Q row operand and K AND V column
-    operands, its mixed flag must match the workspace's tagging, and
-    the mask weights ``w`` must be non-negative — ``w <= 0`` entries
-    are treated as absent by the running max, and the cross-trip clamp
-    rescale is only exact under that contract."""
+    operands, and the mask weights ``w`` must be non-negative — ``w <=
+    0`` entries are treated as absent by the running max, and the
+    cross-trip clamp rescale is only exact under that contract."""
     out: List[PlanViolation] = []
     if level == "off":
         return out
@@ -786,11 +784,6 @@ def verify_attention_contract(spec: SparseEinsumSpec,
                 f"segment_softmax needs a row operand (Q) and two "
                 f"column operands (K, V); spec has "
                 f"{spec.row_operands}/{spec.col_operands}"))
-        if not spec.mixed and has_mxu:
-            out.append(PlanViolation(
-                "attn_spec", "blk_tag",
-                "non-mixed softmax spec but the workspace tags MXU "
-                "block-rows"))
     if level == "full" and vals is not None and spec.segment_softmax:
         w = np.asarray(vals)
         bad = ~(w >= 0)          # catches negatives AND NaNs
@@ -833,7 +826,5 @@ def verify_workspace(ws, *, nnz: Optional[int] = None,
             f"verify_workspace: unsupported workspace type "
             f"{type(ws).__name__}")
     if spec is not None:
-        out += verify_attention_contract(
-            spec, vals, has_mxu=bool(getattr(ws, "has_mxu", False)),
-            level=level)
+        out += verify_attention_contract(spec, vals, level=level)
     return out

@@ -3,10 +3,9 @@ from .csr import BCSRMatrix, CSRMatrix, random_csr
 from .ccm import ccm_register_decomposition, plan_d_tiles, DTiling
 from .plan import (SpmmPlan, MixedPlan, MxuBlockRow, FusedEllWorkspace,
                    ShardedFusedWorkspace, BatchedFusedWorkspace,
-                   StackedFusedTables, SparseEinsumSpec, SPMM_EINSUM,
-                   SPMM_MIXED_EINSUM, SPARSE_ATTN_EINSUM,
-                   SPARSE_ATTN_MIXED_EINSUM, build_fused_workspace,
-                   build_einsum_workspace,
+                   StackedFusedTables, SparseEinsumSpec,
+                   SPMM_MIXED_EINSUM, SPARSE_ATTN_MIXED_EINSUM,
+                   build_fused_workspace, build_einsum_workspace,
                    build_mixed_plan, build_sharded_workspace,
                    build_batched_workspace, stack_fused_workspaces,
                    build_plan, build_workspace, choose_merge_width,
@@ -20,8 +19,7 @@ from .spmm import (CompiledSpmm, CompiledBatchedSpmm,
                    CompiledSparseAttention, compile_spmm,
                    compile_batched_spmm, compile_sparse_attention,
                    sparse_attention, spmm, chip_mesh,
-                   resolve_chip_mesh, BACKENDS, FUSED_BACKENDS,
-                   X_SHARDING_MODES)
+                   resolve_chip_mesh, BACKENDS, X_SHARDING_MODES)
 from .autotune import (TuneConfig, TuneResult, autotune_spmm,
                        autotune_spmm_with_result, default_candidates)
 from . import moe_spmm
@@ -31,9 +29,8 @@ __all__ = [
     "ccm_register_decomposition", "plan_d_tiles", "DTiling",
     "SpmmPlan", "MixedPlan", "MxuBlockRow", "FusedEllWorkspace",
     "ShardedFusedWorkspace", "BatchedFusedWorkspace",
-    "StackedFusedTables", "SparseEinsumSpec", "SPMM_EINSUM",
-    "SPMM_MIXED_EINSUM", "SPARSE_ATTN_EINSUM",
-    "SPARSE_ATTN_MIXED_EINSUM",
+    "StackedFusedTables", "SparseEinsumSpec",
+    "SPMM_MIXED_EINSUM", "SPARSE_ATTN_MIXED_EINSUM",
     "build_fused_workspace", "build_einsum_workspace", "build_mixed_plan",
     "build_sharded_workspace", "build_batched_workspace",
     "stack_fused_workspaces",
@@ -46,7 +43,7 @@ __all__ = [
     "compile_spmm",
     "compile_batched_spmm", "compile_sparse_attention",
     "sparse_attention", "spmm", "chip_mesh",
-    "resolve_chip_mesh", "BACKENDS", "FUSED_BACKENDS", "X_SHARDING_MODES",
+    "resolve_chip_mesh", "BACKENDS", "X_SHARDING_MODES",
     "TuneConfig", "TuneResult", "autotune_spmm",
     "autotune_spmm_with_result", "default_candidates",
     "moe_spmm",

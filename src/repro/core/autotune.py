@@ -97,7 +97,7 @@ def default_candidates(*, bm: int = 8, bk: int = 8,
 
 
 def predict_seconds(a: CSRMatrix, d: int, cfg: TuneConfig, *,
-                    mixed: bool = False, native: bool = False) -> float:
+                    native: bool = False) -> float:
     """Analytic forward-time estimate for one candidate: the roofline
     max of compute and HBM terms on the candidate's own packed
     workspace, plus the per-trip launch overhead.  Host-only.  With
@@ -106,7 +106,7 @@ def predict_seconds(a: CSRMatrix, d: int, cfg: TuneConfig, *,
     it is not offered."""
     ws = build_workspace(
         a.row_ptr, a.col_indices, a.shape, d, strategy=cfg.strategy,
-        row_block=cfg.bm, mixed=mixed, bk=cfg.bk, mxu_gain=cfg.mxu_gain,
+        row_block=cfg.bm, bk=cfg.bk, mxu_gain=cfg.mxu_gain,
         merge_threshold=cfg.merge_threshold)
     d_pad = max(-(-d // 128) * 128, 128)
     if native and cfg.staging == "resident" and not resident_fits(
@@ -249,19 +249,18 @@ def autotune_spmm_with_result(
         cache: JitCache = GLOBAL_CACHE) -> Tuple[object, TuneResult]:
     """:func:`autotune_spmm` plus the full :class:`TuneResult` (the
     bench tables report the per-candidate rankings)."""
-    from .spmm import (FUSED_BACKENDS, _resolve_backend,
-                       _resolve_staging_for, _resolve_x_sharding_for,
-                       compile_spmm, resolve_chip_mesh)
+    from .spmm import (_resolve_backend, _resolve_staging_for,
+                       _resolve_x_sharding_for, compile_spmm,
+                       resolve_chip_mesh)
     from ..analysis.verify import resolve_validate
     from ..kernels.ops import record_build_seconds, resolve_interpret
 
     backend = _resolve_backend(
         backend, sharded=mesh is not None or n_chips is not None)
-    if backend not in FUSED_BACKENDS:
+    if backend != "pallas_bcsr":
         raise ValueError(
-            f"autotune searches the fused plan space "
-            f"({'/'.join(FUSED_BACKENDS)}); backend={backend!r} has "
-            f"nothing to tune")
+            f"autotune searches the fused plan space (pallas_bcsr); "
+            f"backend={backend!r} has nothing to tune")
     interpret = resolve_interpret(interpret)
     # validate never joins the tune key: verification cannot change a
     # search's winner (it only gates compilation), so fragmenting the
@@ -278,7 +277,6 @@ def autotune_spmm_with_result(
     if not candidates:
         raise ValueError("autotune needs at least one candidate config")
     measure = measure or _wall_time_measure
-    mixed = backend == "pallas_bcsr"
 
     key = spmm_tune_key(a, d, backend=backend, interpret=interpret,
                         x_sharding=x_sharding, mesh=mesh,
@@ -286,8 +284,7 @@ def autotune_spmm_with_result(
 
     def _search() -> TuneResult:
         t0 = time.perf_counter()
-        predicted = {c: predict_seconds(a, d, c, mixed=mixed,
-                                        native=not interpret)
+        predicted = {c: predict_seconds(a, d, c, native=not interpret)
                      for c in candidates}
         ranked = sorted((c for c in candidates
                          if predicted[c] < float("inf")),

@@ -34,7 +34,7 @@ Plan construction is a **transform pipeline** (DESIGN.md §7.9):
           distribution (:func:`choose_merge_width`) — the paper's
           coarse-grain merging applied to descriptor trips: runs of
           short/empty block-rows share ONE merged grid step
-  tag     per-block-row execution-unit selection for the mixed backend
+  tag     per-block-row execution-unit selection (VPU or MXU)
           (:func:`tag_block_rows`, folded into :func:`build_mixed_plan`)
   pack    flatten everything into the descriptor stream
           (:func:`_pack_workspace`, merge-width aware)
@@ -310,8 +310,7 @@ def build_plan(row_ptr: np.ndarray, col_indices: np.ndarray, shape,
 
 @dataclasses.dataclass
 class FusedEllWorkspace:
-    """Descriptor-table packing of an :class:`SpmmPlan` or
-    :class:`MixedPlan`.
+    """Descriptor-table packing of a :class:`MixedPlan`.
 
     Every segment's ``(R_pad, L)`` ELL panel is flattened row-major and
     concatenated into one slot array; each row-block of ``row_block``
@@ -320,8 +319,8 @@ class FusedEllWorkspace:
     analogue of the paper baking per-instance bounds into the generated
     code — so one static grid covers blocks with heterogeneous ``L``.
 
-    Mixed plans additionally tag each descriptor (``blk_tag``) with the
-    execution unit it drives.  A VPU block's slots are the ``(bm, L)``
+    Each descriptor is tagged (``blk_tag``) with the execution unit it
+    drives.  A VPU block's slots are the ``(bm, L)``
     ELL panel (one column id per slot, ``blk_coff == blk_off``); an MXU
     block-row's slots are its ``(K, bm, bk)`` value panels flattened,
     while its column stream carries only the ``K`` *block*-column ids —
@@ -403,8 +402,8 @@ class FusedEllWorkspace:
     blk_cont: Optional[np.ndarray] = None  # (B//W,) int32 piece trips
 
     def __post_init__(self):
-        # pure-VPU packings (the pre-mixed layout): every block is VPU
-        # and the column stream is slot-parallel, so coff == off
+        # a workspace given no tags is all VPU, whose column stream is
+        # slot-parallel, so coff == off
         if self.blk_tag is None:
             self.blk_tag = np.zeros_like(self.blk_L)
         if self.blk_coff is None:
@@ -428,36 +427,16 @@ class FusedEllWorkspace:
         return bool(np.any(self.blk_tag == MXU_TAG))
 
 
-def build_fused_workspace(plan, *, merge_width: int = 1
+def build_fused_workspace(plan: "MixedPlan", *, merge_width: int = 1
                           ) -> FusedEllWorkspace:
-    """Pack a plan into the single-dispatch descriptor-table layout.
-
-    Accepts either a pure-VPU :class:`SpmmPlan` (the original ELL
-    layout: tags all ``VPU_TAG``, column stream slot-parallel) or a
-    :class:`MixedPlan`, whose MXU block-rows join the same descriptor
-    stream with ``MXU_TAG`` so the whole mixed plan still lowers as ONE
-    ``pallas_call``.  ``merge_width`` is the CGCM width from the merge
-    stage (:func:`choose_merge_width`).  Every trip's staged panel is
-    bounded by the platform's window
+    """Pack a :class:`MixedPlan` into the single-dispatch descriptor-
+    table layout: its VPU and MXU block-rows in one tagged stream, so
+    the whole plan lowers as ONE ``pallas_call``.  ``merge_width`` is
+    the CGCM width from the merge stage (:func:`choose_merge_width`).
+    Every trip's staged panel is bounded by the platform's window
     (:func:`~repro.platform.stage_limits`).
     """
-    if isinstance(plan, MixedPlan):
-        return _pack_workspace(plan, mixed_kernel=True,
-                               merge_width=merge_width)
-    # a pure-VPU SpmmPlan is the degenerate mixed plan (identity nnz
-    # map, no MXU block-rows) — ONE packing loop serves both layouts,
-    # so a packing-invariant fix can never diverge the two backends.
-    # mixed_kernel=False skips the MXU-branch slot-stream floor, keeping
-    # the ELL layout exactly slot-parallel (cols size == gather size).
-    trivial = MixedPlan(
-        strategy=plan.strategy, m=plan.m, n=plan.n, nnz=plan.nnz,
-        d_tiling=plan.d_tiling, row_block=plan.row_block, bk=8,
-        vpu=plan, vpu_rows=np.arange(plan.m, dtype=np.int64),
-        vpu_nnz_map=np.arange(plan.nnz, dtype=np.int64),
-        mxu_rows=[], plan_seconds=plan.plan_seconds,
-        fingerprint=plan.fingerprint)
-    return _pack_workspace(trivial, mixed_kernel=False,
-                           merge_width=merge_width)
+    return _pack_workspace(plan, merge_width=merge_width)
 
 
 # the plan-transform pipeline's stage order (DESIGN.md §7.9); "shard"
@@ -477,8 +456,6 @@ class SparseEinsumSpec:
     dispatch layer can bind the right kernel body and build the right
     operand gathers (DESIGN.md §13):
 
-    ``mixed``            run the tag stage (MXU block-rows join the
-                         descriptor stream).
     ``row_operands``     dense operands indexed by the OUTPUT row (e.g.
                          attention's Q) — each needs a
                          :func:`workspace_row_map` gather into
@@ -490,18 +467,14 @@ class SparseEinsumSpec:
                          running max/rescale across its trips.
     """
     name: str                       # kernel family: "spmm" | "sattn"
-    mixed: bool = False
     row_operands: int = 0
     col_operands: int = 1
     segment_softmax: bool = False
 
 
-SPMM_EINSUM = SparseEinsumSpec(name="spmm")
-SPMM_MIXED_EINSUM = SparseEinsumSpec(name="spmm", mixed=True)
-SPARSE_ATTN_EINSUM = SparseEinsumSpec(
+SPMM_MIXED_EINSUM = SparseEinsumSpec(name="spmm")
+SPARSE_ATTN_MIXED_EINSUM = SparseEinsumSpec(
     name="sattn", row_operands=1, col_operands=2, segment_softmax=True)
-SPARSE_ATTN_MIXED_EINSUM = dataclasses.replace(
-    SPARSE_ATTN_EINSUM, mixed=True)
 
 
 def workspace_row_map(inv_perm, ws_rows: int, cont=None,
@@ -560,12 +533,11 @@ def build_einsum_workspace(spec: SparseEinsumSpec, row_ptr: np.ndarray,
       merge  :func:`choose_merge_width` (skipped when ``merge_width``
              is pinned — the sharded path decides globally, the
              autotuner per candidate)
-      build / tag  :func:`build_plan`, or :func:`build_mixed_plan`
-             (``spec.mixed``) whose tag stage is
+      build / tag  :func:`build_mixed_plan`, whose tag stage is
              :func:`tag_block_rows`
       pack   :func:`build_fused_workspace` → :func:`_pack_workspace`
 
-    The spec only steers the tag stage here — the packed workspace is
+    The spec does not steer the stages: the packed workspace is
     operand-agnostic by construction (it encodes the pattern, never the
     contraction), which is exactly why SpMM and sparse attention share
     it.  Every stage is also callable on its own; this wrapper is the
@@ -574,23 +546,17 @@ def build_einsum_workspace(spec: SparseEinsumSpec, row_ptr: np.ndarray,
     if merge_width is None:
         merge_width = choose_merge_width(
             row_ptr, row_block=row_block, merge_threshold=merge_threshold)
-    if spec.mixed:
-        plan = build_mixed_plan(
-            row_ptr, col_indices, shape, d, strategy=strategy,
-            row_block=row_block, bk=bk, mxu_gain=mxu_gain,
-            fingerprint=fingerprint, max_dt=max_dt,
-            merge_target_segments=merge_target_segments)
-    else:
-        plan = build_plan(
-            row_ptr, col_indices, shape, d, strategy=strategy,
-            row_block=row_block, fingerprint=fingerprint, max_dt=max_dt,
-            merge_target_segments=merge_target_segments)
+    plan = build_mixed_plan(
+        row_ptr, col_indices, shape, d, strategy=strategy,
+        row_block=row_block, bk=bk, mxu_gain=mxu_gain,
+        fingerprint=fingerprint, max_dt=max_dt,
+        merge_target_segments=merge_target_segments)
     return build_fused_workspace(plan, merge_width=merge_width)
 
 
 def build_workspace(row_ptr: np.ndarray, col_indices: np.ndarray, shape,
                     d: int, *, strategy: str = "nnz_split",
-                    row_block: int = 8, mixed: bool = False, bk: int = 8,
+                    row_block: int = 8, bk: int = 8,
                     mxu_gain: float = 4.0, merge_threshold: int = 0,
                     merge_width: Optional[int] = None,
                     fingerprint: str = "", max_dt: int = 512,
@@ -598,9 +564,8 @@ def build_workspace(row_ptr: np.ndarray, col_indices: np.ndarray, shape,
                     ) -> FusedEllWorkspace:
     """The SpMM specialization of :func:`build_einsum_workspace` —
     kept as the historical entry point for ``A·X`` callers."""
-    spec = SPMM_MIXED_EINSUM if mixed else SPMM_EINSUM
     return build_einsum_workspace(
-        spec, row_ptr, col_indices, shape, d, strategy=strategy,
+        SPMM_MIXED_EINSUM, row_ptr, col_indices, shape, d, strategy=strategy,
         row_block=row_block, bk=bk, mxu_gain=mxu_gain,
         merge_threshold=merge_threshold, merge_width=merge_width,
         fingerprint=fingerprint, max_dt=max_dt,
@@ -870,18 +835,14 @@ def build_mixed_plan(row_ptr: np.ndarray, col_indices: np.ndarray, shape,
                      fingerprint=fingerprint)
 
 
-def _pack_workspace(plan: MixedPlan, *, mixed_kernel: bool,
+def _pack_workspace(plan: MixedPlan, *,
                     merge_width: int = 1) -> FusedEllWorkspace:
-    """Pack a :class:`MixedPlan` into one tagged descriptor stream —
-    THE packing loop, shared by both fused backends (pure-VPU plans
-    arrive as degenerate mixed plans, see ``build_fused_workspace``).
+    """Pack a :class:`MixedPlan` into one tagged descriptor stream.
 
     VPU blocks first (plan order, gather remapped from sub-nnz to global
     nnz ids), then the MXU block-rows, each starting at a
     :data:`STAGE_TILE`-aligned slot with lane-padded panels.  Column and
     slot streams advance independently (see :class:`FusedEllWorkspace`).
-    ``mixed_kernel=False`` marks the degenerate wrap, whose gather ids
-    are already global.
 
     Blocks whose panel exceeds the platform's staging window
     (:func:`~repro.platform.stage_limits`) are split into pieces
@@ -931,9 +892,9 @@ def _pack_workspace(plan: MixedPlan, *, mixed_kernel: bool,
         Lp = max(seg.L, 1)
         nblk = seg.R_pad // bm
         g = seg.gather_idx
-        if mixed_kernel and sub_nnz == 0:   # all-empty VPU rows
+        if sub_nnz == 0:                   # all-empty VPU rows
             g = np.full(g.shape, nnz, np.int64)
-        elif mixed_kernel:                 # sub-nnz ids -> global ids
+        else:                              # sub-nnz ids -> global ids
             g = np.where(g < sub_nnz,
                          plan.vpu_nnz_map[np.minimum(g, sub_nnz - 1)], nnz)
         CL = max(min(Lp, W // bm), 1)
@@ -1069,7 +1030,7 @@ def partition_rows_for_chips(row_ptr: np.ndarray, n_chips: int,
     """Chip row boundaries by the given strategy.
 
     ``align`` rounds the interior bounds to multiples of that many rows
-    — the BCSR/mixed path passes its ``row_block`` so chips own whole
+    — the sharded fused path passes its ``row_block`` so chips own whole
     block-rows and no (bm x bk) block ever straddles a chip (the final
     bound stays ``m``; the ragged tail pads inside its own chip).
     """
@@ -1169,7 +1130,7 @@ class ShardedFusedWorkspace:
     ws_rows: int             # per-chip workspace rows == B * row_block
     row_block: int
     n_chips: int
-    shard_plans: List       # per-chip SpmmPlan/MixedPlan (stats/debug)
+    shard_plans: List       # per-chip MixedPlan (stats/debug)
     blk_tag: Optional[np.ndarray] = None   # (C, B) int32 VPU_TAG/MXU_TAG
     blk_coff: Optional[np.ndarray] = None  # (C, B) int32 into cols_flat
     bk: int = 8
@@ -1370,8 +1331,7 @@ def build_sharded_workspace(row_ptr: np.ndarray, col_indices: np.ndarray,
                             strategy: str = "nnz_split", row_block: int = 8,
                             fingerprint: str = "", max_dt: int = 512,
                             merge_target_segments: int = 16,
-                            backend: str = "pallas_ell", bk: int = 8,
-                            mxu_gain: float = 4.0,
+                            bk: int = 8, mxu_gain: float = 4.0,
                             x_sharding: str = "replicated",
                             merge_threshold: int = 0
                             ) -> ShardedFusedWorkspace:
@@ -1379,10 +1339,10 @@ def build_sharded_workspace(row_ptr: np.ndarray, col_indices: np.ndarray,
     chip (see :class:`ShardedFusedWorkspace`).  Host-only — needs no
     devices; the mesh enters at dispatch time.
 
-    ``backend="pallas_bcsr"`` plans each chip range as a mixed VPU/MXU
-    plan (see :func:`build_mixed_plan`) and aligns the chip boundaries
-    to ``row_block`` so the partitioner sees block-row — not scalar-row
-    — boundaries and no (bm x bk) block straddles a chip.
+    Each chip range is planned as a mixed VPU/MXU plan (see
+    :func:`build_mixed_plan`), and the chip boundaries are aligned to
+    ``row_block`` so the partitioner sees block-row — not scalar-row —
+    boundaries and no (bm x bk) block straddles a chip.
 
     ``x_sharding="rows"`` additionally splits X into ``bk``-row panels
     owned contiguously by chips, remaps each chip's column stream into
@@ -1402,7 +1362,6 @@ def build_sharded_workspace(row_ptr: np.ndarray, col_indices: np.ndarray,
     if x_sharding not in ("replicated", "rows"):
         raise ValueError(
             f"x_sharding must be 'replicated' or 'rows', got {x_sharding!r}")
-    mixed = backend == "pallas_bcsr"
     row_ptr = np.asarray(row_ptr)
     col_indices = np.asarray(col_indices)
     m, n = shape
@@ -1411,10 +1370,8 @@ def build_sharded_workspace(row_ptr: np.ndarray, col_indices: np.ndarray,
     # shard): one global width, chip cuts at merged-trip boundaries
     merge_width = choose_merge_width(row_ptr, row_block=row_block,
                                      merge_threshold=merge_threshold)
-    align = 1 if (not mixed and merge_width == 1) else (row_block
-                                                        * merge_width)
     bounds = partition_rows_for_chips(row_ptr, n_chips, strategy,
-                                      align=align)
+                                      align=row_block * merge_width)
 
     plans: List = []
     shards: List[FusedEllWorkspace] = []
@@ -1424,18 +1381,11 @@ def build_sharded_workspace(row_ptr: np.ndarray, col_indices: np.ndarray,
         base = int(row_ptr[r0])
         sub_ptr = row_ptr[r0:r1 + 1] - base
         sub_cols = col_indices[base:int(row_ptr[r1])]
-        if mixed:
-            plan = build_mixed_plan(
-                sub_ptr, sub_cols, (r1 - r0, n), d, strategy=strategy,
-                row_block=row_block, bk=bk, mxu_gain=mxu_gain,
-                fingerprint=f"{fingerprint}/chip{c}", max_dt=max_dt,
-                merge_target_segments=merge_target_segments)
-        else:
-            plan = build_plan(sub_ptr, sub_cols, (r1 - r0, n), d,
-                              strategy=strategy, row_block=row_block,
-                              fingerprint=f"{fingerprint}/chip{c}",
-                              max_dt=max_dt,
-                              merge_target_segments=merge_target_segments)
+        plan = build_mixed_plan(
+            sub_ptr, sub_cols, (r1 - r0, n), d, strategy=strategy,
+            row_block=row_block, bk=bk, mxu_gain=mxu_gain,
+            fingerprint=f"{fingerprint}/chip{c}", max_dt=max_dt,
+            merge_target_segments=merge_target_segments)
         plans.append(plan)
         shards.append(build_fused_workspace(plan,
                                             merge_width=merge_width))
@@ -1589,8 +1539,7 @@ class BatchedFusedWorkspace:
 
 def build_batched_workspace(structures, d: int, *,
                             strategy: str = "nnz_split",
-                            row_block: int = 8,
-                            backend: str = "pallas_ell", bk: int = 8,
+                            row_block: int = 8, bk: int = 8,
                             mxu_gain: float = 4.0,
                             merge_threshold: int = 0,
                             fingerprint: str = "", max_dt: int = 512,
@@ -1614,7 +1563,6 @@ def build_batched_workspace(structures, d: int, *,
     """
     if not structures:
         raise ValueError("build_batched_workspace needs >= 1 request")
-    mixed = backend == "pallas_bcsr"
     structures = [(np.asarray(rp), np.asarray(ci), tuple(shape))
                   for rp, ci, shape in structures]
     if np.ndim(merge_threshold) == 0:
@@ -1635,18 +1583,11 @@ def build_batched_workspace(structures, d: int, *,
     total_nnz = 0
     n_max = 0
     for r, (row_ptr, col_indices, shape) in enumerate(structures):
-        if mixed:
-            plan = build_mixed_plan(
-                row_ptr, col_indices, shape, d, strategy=strategy,
-                row_block=row_block, bk=bk, mxu_gain=mxu_gain,
-                fingerprint=f"{fingerprint}/req{r}", max_dt=max_dt,
-                merge_target_segments=merge_target_segments)
-        else:
-            plan = build_plan(row_ptr, col_indices, shape, d,
-                              strategy=strategy, row_block=row_block,
-                              fingerprint=f"{fingerprint}/req{r}",
-                              max_dt=max_dt,
-                              merge_target_segments=merge_target_segments)
+        plan = build_mixed_plan(
+            row_ptr, col_indices, shape, d, strategy=strategy,
+            row_block=row_block, bk=bk, mxu_gain=mxu_gain,
+            fingerprint=f"{fingerprint}/req{r}", max_dt=max_dt,
+            merge_target_segments=merge_target_segments)
         plans.append(plan)
         shards.append(build_fused_workspace(plan, merge_width=mw))
         bases.append(total_nnz)

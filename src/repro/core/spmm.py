@@ -6,24 +6,24 @@ jit cache) a ``CompiledSpmm`` — plan + device constants + differentiable
 callable.  ``spmm`` is the one-shot convenience wrapper.
 
 Backends:
-  pallas_ell   faithful CCM/VPU Pallas kernel, fused: the whole
-               multi-segment plan is ONE pallas_call via a descriptor
-               table + one inverse-permutation gather (validated in
-               interpret mode on CPU; native on TPU).  With ``mesh`` /
-               ``n_chips`` the plan is row-partitioned across chips
+  pallas_bcsr  the fused dispatch: each bm-aligned row-block is tagged
+               VPU (the paper's CCM ELL gather+FMA) or MXU ((bm x bk)
+               block matmuls) at plan time (``build_mixed_plan``;
+               ``mxu_gain`` tunes the tagging, and ``mxu_gain=0`` tags
+               every block VPU), and the whole plan is ONE pallas_call
+               via a descriptor table + one inverse-permutation gather
+               (validated in interpret mode on CPU; native on TPU).
+               With ``mesh`` / ``n_chips`` the rows are partitioned
+               across chips at block-row boundaries
                (``partition_rows_for_chips``) and each chip runs its
                shard as one pallas_call under shard_map.
-  pallas_bcsr  MXU-enabled MIXED plan: each bm-aligned row-block is
-               tagged VPU (ELL gather+FMA) or MXU ((bm x bk) block
-               matmuls) at plan time (``build_mixed_plan``), and the
-               whole mixed plan is STILL one pallas_call — or one per
-               chip under mesh/n_chips, with chip boundaries aligned to
-               block-rows.  ``mxu_gain`` tunes the tagging heuristic.
   ref          pure-jnp gather/segment-sum (jit-friendly; used inside
                the model stack and the 512-device dry-run)
   dense        densified matmul (tiny tests only)
+  auto         pallas_bcsr on a TPU or when sharding is asked for, ref
+               otherwise
 
-Both fused backends take a ``staging`` knob (DESIGN.md §7.7):
+The fused backend takes a ``staging`` knob (DESIGN.md §7.7):
 ``"resident"`` (whole flat slot buffer + X panel in VMEM — the
 interpret-mode default and bit-identity oracle) or ``"dma"``
 (double-buffered per-block slot-panel DMA, the TPU default), resolved
@@ -45,14 +45,12 @@ from jax.sharding import Mesh
 from . import ccm
 from .csr import CSRMatrix
 from .jit_cache import GLOBAL_CACHE, JitCache, mesh_fingerprint
-from .plan import (MXU_TAG, PANEL_EDGES, SPARSE_ATTN_EINSUM,
-                   SPARSE_ATTN_MIXED_EINSUM, VPU_TAG,
-                   BatchedFusedWorkspace, MixedPlan,
-                   ShardedFusedWorkspace, SpmmPlan,
-                   build_batched_workspace, build_einsum_workspace,
-                   build_fused_workspace, build_mixed_plan, build_plan,
-                   build_sharded_workspace, choose_merge_width,
-                   choose_panel_edge, panel_stats,
+from .plan import (MXU_TAG, PANEL_EDGES, SPARSE_ATTN_MIXED_EINSUM,
+                   VPU_TAG, BatchedFusedWorkspace, MixedPlan,
+                   ShardedFusedWorkspace, build_batched_workspace,
+                   build_einsum_workspace, build_fused_workspace,
+                   build_mixed_plan, build_sharded_workspace,
+                   choose_merge_width, choose_panel_edge, panel_stats,
                    sharded_workspace_row_maps, workspace_row_map)
 from ..analysis.verify import (PlanVerificationError, check_workspace,
                                resolve_validate)
@@ -61,19 +59,16 @@ from ..kernels.ops import resolve_interpret, resolve_staging, span
 from ..platform import LANE, STAGE_TILE, resident_fits
 
 __all__ = [
-    "BACKENDS", "FUSED_BACKENDS", "X_SHARDING_MODES",
+    "BACKENDS", "X_SHARDING_MODES",
     "CompiledSpmm", "CompiledBatchedSpmm", "CompiledSparseAttention",
     "PlanVerificationError", "chip_mesh", "resolve_chip_mesh",
     "compile_spmm", "compile_batched_spmm", "compile_sparse_attention",
     "spmm", "sparse_attention",
 ]
 
-BACKENDS = ("pallas_ell", "pallas_bcsr", "ref", "dense", "auto")
-
-# backends that lower through the fused descriptor-table dispatch (and
-# therefore support mesh/n_chips sharding and the staging/x_sharding
-# knobs)
-FUSED_BACKENDS = ("pallas_ell", "pallas_bcsr")
+# "pallas_bcsr" is the fused descriptor-table dispatch, the one backend
+# with mesh/n_chips sharding and the staging/x_sharding knobs
+BACKENDS = ("pallas_bcsr", "ref", "dense", "auto")
 
 # X placement on the sharded fused path (DESIGN.md §7.8):
 #   replicated  every chip holds all of X (the PR 2 layout) — n·d_pad
@@ -99,7 +94,7 @@ def _resolve_x_sharding_for(backend: str, x_sharding, interpret: bool,
     artifact).  ``"rows"`` without a mesh, or any non-replicated value
     on a non-fused backend, is an error — the knob only exists where
     the fetch-table machinery does."""
-    if backend in FUSED_BACKENDS:
+    if backend == "pallas_bcsr":
         if x_sharding in (None, "auto"):
             if mesh is not None and mesh.size > 1 and not interpret:
                 return "rows"
@@ -116,9 +111,8 @@ def _resolve_x_sharding_for(backend: str, x_sharding, interpret: bool,
         return x_sharding
     if x_sharding not in (None, "auto", "replicated"):
         raise ValueError(
-            f"x_sharding is a fused-dispatch knob "
-            f"({'/'.join(FUSED_BACKENDS)}); backend={backend!r} has no "
-            f"sharded lowering")
+            f"x_sharding is a fused-dispatch knob (pallas_bcsr); "
+            f"backend={backend!r} has no sharded lowering")
     return "replicated"
 
 
@@ -127,27 +121,28 @@ def _resolve_staging_for(backend: str, staging, interpret: bool) -> str:
     dispatch, so non-fused backends pin ``"resident"`` (and reject an
     explicit ``"dma"`` the way single-device backends reject a mesh) —
     keeping ref/dense cache keys independent of a knob they ignore."""
-    if backend in FUSED_BACKENDS:
+    if backend == "pallas_bcsr":
         return resolve_staging(staging, interpret)
     if staging not in (None, "auto", "resident"):
         raise ValueError(
-            f"staging is a fused-dispatch knob ({'/'.join(FUSED_BACKENDS)});"
-            f" backend={backend!r} has no staged lowering")
+            f"staging is a fused-dispatch knob (pallas_bcsr); "
+            f"backend={backend!r} has no staged lowering")
     return "resident"
 
 
 def _resolve_backend(backend: str, *, sharded: bool = False) -> str:
+    """The effective backend: ``"auto"`` is the fused dispatch on a TPU,
+    and off it wherever sharding is asked for (mesh/n_chips is a
+    fused-path feature, run in interpret mode on CPU); otherwise the
+    jnp ``ref`` backend.  A name outside :data:`BACKENDS` is an error
+    here, before anything is built."""
+    if backend not in BACKENDS:
+        raise ValueError(
+            f"backend must be one of {BACKENDS}, got {backend!r}")
     if backend != "auto":
         return backend
-    if jax.default_backend() == "tpu":
-        # the mixed fused path: MXU where block structure pays, VPU
-        # elsewhere — sharded or not, it is the TPU serving default
+    if sharded or jax.default_backend() == "tpu":
         return "pallas_bcsr"
-    if sharded:
-        # mesh/n_chips is a fused-path feature; an explicit sharding
-        # request must not fall back to the single-device ref backend
-        # (on CPU the fused kernel runs via interpret mode)
-        return "pallas_ell"
     return "ref"
 
 
@@ -210,16 +205,15 @@ class _FusedConsts:
     """Device-resident fused-plan constants: ONE descriptor table + flat
     slot arrays for all segments, so the forward pass is a single
     pallas_call plus one inverse-permutation gather (no per-segment
-    dispatch loop, no scatters).  Mixed (pallas_bcsr) plans additionally
-    carry the per-block execution-unit tag and column-stream offsets."""
+    dispatch loop, no scatters)."""
+    blk_tag: jax.Array       # (B,) int32 — VPU/MXU tag
     blk_off: jax.Array       # (B,) int32 — first slot per row-block
+    blk_coff: jax.Array      # (B,) int32 into cols_flat
     blk_L: jax.Array         # (B,) int32 — loop trips per row-block
     cols_flat: jax.Array     # (Sc,) int32 — X row / block-column stream
     vals: "_SlotValues"      # how the (S,) slot-value stream is staged
     inv_perm: jax.Array      # (m,) int32 — output row -> workspace row
     num_blocks: int
-    blk_tag: Optional[jax.Array] = None   # (B,) int32 — VPU/MXU tag
-    blk_coff: Optional[jax.Array] = None  # (B,) int32 into cols_flat
     max_span: int = 0        # staged-DMA slot window (DESIGN.md §7.7)
     max_cspan: int = 0       # staged-DMA cols window
     merge_width: int = 1     # CGCM width (DESIGN.md §7.9)
@@ -391,7 +385,9 @@ class _ShardedConsts:
     descriptor tables (leading axis = chips), the GLOBAL inverse
     permutation into the flattened (n_chips * ws_rows) workspace, and
     the mesh the shard_map dispatch runs over."""
+    blk_tag: jax.Array       # (C, B) int32 — VPU/MXU tag
     blk_off: jax.Array       # (C, B) int32
+    blk_coff: jax.Array      # (C, B) int32 into cols_flat
     blk_L: jax.Array         # (C, B) int32
     cols_flat: jax.Array     # (C, Sc) int32
     vals: _SlotValues        # how the (C, S) slot-value stream is staged
@@ -400,8 +396,6 @@ class _ShardedConsts:
     num_blocks: int          # common per-chip block count B
     n_chips: int
     mesh: Mesh
-    blk_tag: Optional[jax.Array] = None   # (C, B) int32 — VPU/MXU tag
-    blk_coff: Optional[jax.Array] = None  # (C, B) int32 into cols_flat
     max_span: int = 0        # cross-chip max staged-DMA slot window
     max_cspan: int = 0       # cross-chip max staged-DMA cols window
     chip_span: tuple = ()    # (C,) per-chip staged-DMA slot windows
@@ -474,11 +468,12 @@ class CompiledSpmm(_SlotValueCounts):
         self.x_sharding = _resolve_x_sharding_for(
             self.backend, x_sharding, self.interpret, self.mesh)
         self.n_chips = None if self.mesh is None else int(self.mesh.size)
-        if self.mesh is not None and self.backend not in FUSED_BACKENDS:
+        fused = self.backend == "pallas_bcsr"
+        if self.mesh is not None and not fused:
             raise ValueError(
                 f"mesh/n_chips sharding is a fused-dispatch feature "
-                f"({'/'.join(FUSED_BACKENDS)}); backend="
-                f"{self.backend!r} is single-device")
+                f"(pallas_bcsr); backend={self.backend!r} is "
+                f"single-device")
         self.cache = cache
         self.d = d
         self.shape = a.shape
@@ -487,15 +482,14 @@ class CompiledSpmm(_SlotValueCounts):
         self._col_indices = a.col_indices
         self._fingerprint = a.fingerprint
         self._nnz = a.nnz
-        # the mixed/MXU kernel slices (bk, dt) X panels per block-column,
-        # so X rows are padded up to the block-column grid
+        # the MXU trip slices (bk, dt) X panels per block-column, so X
+        # rows are padded up to the block-column grid
         self._x_rows_pad = -(-a.shape[1] // bk) * bk
 
-        self.plan: Optional[SpmmPlan] = None
         self.mixed_plan: Optional[MixedPlan] = None
         self._fused: Optional[_FusedConsts] = None
         self._sharded: Optional[_ShardedConsts] = None
-        if self.backend in FUSED_BACKENDS and self.mesh is not None:
+        if fused and self.mesh is not None:
             # the sharded workspace re-plans every chip range itself, so
             # packing a global plan here would duplicate O(padded_nnz)
             # host work; only the d tiling is needed from this level
@@ -503,8 +497,8 @@ class CompiledSpmm(_SlotValueCounts):
             sw: ShardedFusedWorkspace = build_sharded_workspace(
                 a.row_ptr, a.col_indices, a.shape, d,
                 n_chips=self.n_chips, strategy=strategy, row_block=bm,
-                fingerprint=a.fingerprint, backend=self.backend,
-                bk=bk, mxu_gain=mxu_gain, x_sharding=self.x_sharding,
+                fingerprint=a.fingerprint, bk=bk, mxu_gain=mxu_gain,
+                x_sharding=self.x_sharding,
                 merge_threshold=self.merge_threshold)
             self.sharded_workspace = sw
             _verify_workspace_timed(
@@ -517,25 +511,17 @@ class CompiledSpmm(_SlotValueCounts):
             _record_build(
                 sum(p.plan_seconds for p in sw.shard_plans),
                 sw.pack_seconds)
-        elif self.backend == "pallas_bcsr":
+        elif fused:
             self.mixed_plan = build_mixed_plan(
                 a.row_ptr, a.col_indices, a.shape, d, strategy=strategy,
                 row_block=bm, bk=bk, mxu_gain=mxu_gain,
                 fingerprint=a.fingerprint)
             self.d_tiling = self.mixed_plan.d_tiling
-        else:
-            self.plan = build_plan(
-                a.row_ptr, a.col_indices, a.shape, d, strategy=strategy,
-                row_block=bm, fingerprint=a.fingerprint)
-            self.d_tiling = self.plan.d_tiling
-
-        if self._sharded is None and self.backend in FUSED_BACKENDS:
             # merge stage: the CGCM width is a plan-time decision from
             # the instance's row lengths (DESIGN.md §7.9); 1 = no merge
             mw = choose_merge_width(a.row_ptr, row_block=bm,
                                     merge_threshold=self.merge_threshold)
-            ws = build_fused_workspace(self.mixed_plan or self.plan,
-                                       merge_width=mw)
+            ws = build_fused_workspace(self.mixed_plan, merge_width=mw)
             _verify_workspace_timed(
                 ws, level=self.validate, n_cols=a.shape[1],
                 context=f"compile_spmm[{self.backend}]")
@@ -543,9 +529,7 @@ class CompiledSpmm(_SlotValueCounts):
                 self.staging, self.interpret, ws,
                 4 * self._x_rows_pad * self.d_tiling.dt, "compile_spmm")
             self._fused = _fused_consts(ws, a.nnz)
-            _record_build(
-                (self.mixed_plan or self.plan).plan_seconds,
-                ws.pack_seconds)
+            _record_build(self.mixed_plan.plan_seconds, ws.pack_seconds)
         elif self.backend == "ref":
             self._cols = jnp.asarray(a.col_indices)
 
@@ -643,8 +627,7 @@ class CompiledSpmm(_SlotValueCounts):
             vals_flat = fw.vals.stage(vals)
         with span("spmm.stage_operands"):
             x_pad = ccm.pad_cols(x, self.d_tiling.d_pad)
-            if backend == "pallas_bcsr" and (
-                    x_pad.shape[0] < self._x_rows_pad):
+            if x_pad.shape[0] < self._x_rows_pad:
                 x_pad = jnp.pad(
                     x_pad,
                     ((0, self._x_rows_pad - x_pad.shape[0]), (0, 0)))
@@ -662,27 +645,17 @@ class CompiledSpmm(_SlotValueCounts):
     def _kernel(self, fw, vals_flat, x_pad):
         """The fused dispatch of the plan's constants ``fw``: one
         pallas_call, or one per chip under shard_map."""
-        knobs = dict(bm=self.bm, mw=fw.merge_width,
+        tables = (fw.blk_tag, fw.blk_off, fw.blk_coff, fw.blk_L,
+                  fw.cols_flat, vals_flat, x_pad, fw.cont)
+        knobs = dict(bm=self.bm, bk=self.bk, mw=fw.merge_width,
                      interpret=self.interpret, staging=self.staging)
         if self._sharded is not None:
-            knobs.update(mesh=fw.mesh, span=fw.chip_span,
-                         cspan=fw.chip_cspan, x_sharding=fw.x_sharding,
-                         x_send=fw.x_send, x_recv=fw.x_recv)
-            ell, mixed = (kops.spmm_ell_fused_sharded_op,
-                          kops.spmm_bcsr_fused_sharded_op)
-        else:
-            knobs.update(span=fw.max_span, cspan=fw.max_cspan)
-            ell, mixed = kops.spmm_ell_fused_op, kops.spmm_bcsr_fused_op
-        if self.backend == "pallas_ell":
-            return ell(fw.blk_off, fw.blk_L, fw.cols_flat, vals_flat,
-                       x_pad, fw.cont, **knobs)
-        if self.backend == "pallas_bcsr":
-            # the mixed VPU/MXU plan lowers through the same descriptor-
-            # table machinery as pallas_ell
-            return mixed(fw.blk_tag, fw.blk_off, fw.blk_coff, fw.blk_L,
-                         fw.cols_flat, vals_flat, x_pad, fw.cont,
-                         bk=self.bk, **knobs)
-        raise ValueError(self.backend)
+            return kops.spmm_bcsr_fused_sharded_op(
+                *tables, mesh=fw.mesh, span=fw.chip_span,
+                cspan=fw.chip_cspan, x_sharding=fw.x_sharding,
+                x_send=fw.x_send, x_recv=fw.x_recv, **knobs)
+        return kops.spmm_bcsr_fused_op(*tables, span=fw.max_span,
+                                       cspan=fw.max_cspan, **knobs)
 
     # -- gradients ----------------------------------------------------------
     def _sddmm(self, dy, x):
@@ -746,19 +719,23 @@ def compile_spmm(a: CSRMatrix, d: int, *, strategy: str = "nnz_split",
                  cache: JitCache = GLOBAL_CACHE) -> CompiledSpmm:
     """Build (or fetch) the structure-specialized SpMM artifact.
 
-    ``mesh`` / ``n_chips`` (fused backends: pallas_ell / pallas_bcsr)
-    shard the fused plan across a 1-D device mesh: rows are partitioned
-    by the same strategy at the chip level (block-row aligned for the
-    mixed backend) and each chip runs its range as one pallas_call under
-    shard_map.  The resolved mesh is part of the cache key — same
-    normalization as ``interpret``.  ``bk`` / ``mxu_gain`` parameterize
-    the pallas_bcsr mixed plan (block width, VPU-vs-MXU tagging) and are
+    ``backend`` is one of :data:`BACKENDS`; ``"auto"`` resolves to the
+    fused ``"pallas_bcsr"`` on a TPU or when sharding is asked for, and
+    to the jnp ``"ref"`` otherwise.  Any other name is a ``ValueError``.
+
+    ``mesh`` / ``n_chips`` (the fused backend) shard the fused plan
+    across a 1-D device mesh: rows are partitioned by the same strategy
+    at the chip level, at block-row boundaries, and each chip runs its
+    range as one pallas_call under shard_map.  The resolved mesh is
+    part of the cache key — same normalization as ``interpret``.
+    ``bk`` / ``mxu_gain`` parameterize the plan (block width,
+    VPU-vs-MXU tagging; ``mxu_gain=0`` tags every block VPU) and are
     part of the specialization identity as well.
 
     ``staging`` selects the fused kernels' operand staging (DESIGN.md
     §7.7): ``"resident"`` keeps the flat slot buffer and X panel in
-    VMEM, ``"dma"`` double-buffers per-block slot panels (and, on the
-    mixed backend, per-trip X panels) from HBM.  ``"auto"``/``None``
+    VMEM, ``"dma"`` double-buffers per-block slot panels and per-trip
+    X panels from HBM.  ``"auto"``/``None``
     resolves to ``"dma"`` on a real TPU and ``"resident"`` under
     interpret mode; the resolved mode is part of the cache key and the
     two lowerings are bit-identical.
@@ -850,13 +827,13 @@ class CompiledBatchedSpmm(_SlotValueCounts):
                  merge_threshold=0,
                  validate: Optional[str] = None):
         # sharded=True resolution: batching stacks descriptor tables, so
-        # "auto" must land on a fused backend even on CPU (interpret)
+        # "auto" must land on the fused backend even on CPU (interpret)
         self.backend = _resolve_backend(backend, sharded=True)
-        if self.backend not in FUSED_BACKENDS:
+        if self.backend != "pallas_bcsr":
             raise ValueError(
-                f"batched dispatch stacks descriptor tables — a fused "
-                f"backend is required ({'/'.join(FUSED_BACKENDS)}), "
-                f"got {self.backend!r}")
+                f"batched dispatch stacks descriptor tables — the fused "
+                f"backend is required (pallas_bcsr), got "
+                f"{self.backend!r}")
         self.strategy = strategy
         self.bm = bm
         self.bk = bk
@@ -875,8 +852,7 @@ class CompiledBatchedSpmm(_SlotValueCounts):
         self.d_tiling = ccm.plan_d_tiles(d, rows_in_flight=bm)
         bw: BatchedFusedWorkspace = build_batched_workspace(
             [(a.row_ptr, a.col_indices, a.shape) for a in structures],
-            d, strategy=strategy, row_block=bm, backend=self.backend,
-            bk=bk, mxu_gain=mxu_gain,
+            d, strategy=strategy, row_block=bm, bk=bk, mxu_gain=mxu_gain,
             merge_threshold=self.merge_threshold,
             fingerprint="+".join(a.fingerprint[:8] for a in structures))
         self.batched_workspace = bw
@@ -920,21 +896,11 @@ class CompiledBatchedSpmm(_SlotValueCounts):
         fw = self._consts
         x_pad = ccm.pad_cols(x, self.d_tiling.d_pad)
         vals_flat = fw.vals.stage(vals)
-        if self.backend == "pallas_ell":
-            from ..kernels.ops import spmm_ell_fused_op
-            y_ws = spmm_ell_fused_op(
-                fw.blk_off, fw.blk_L, fw.cols_flat, vals_flat, x_pad,
-                fw.cont, bm=self.bm, mw=fw.merge_width,
-                interpret=self.interpret, staging=self.staging,
-                span=fw.max_span, cspan=fw.max_cspan)
-        else:
-            from ..kernels.ops import spmm_bcsr_fused_op
-            y_ws = spmm_bcsr_fused_op(
-                fw.blk_tag, fw.blk_off, fw.blk_coff, fw.blk_L,
-                fw.cols_flat, vals_flat, x_pad, fw.cont, bm=self.bm,
-                bk=self.bk, mw=fw.merge_width, interpret=self.interpret,
-                staging=self.staging, span=fw.max_span,
-                cspan=fw.max_cspan)
+        y_ws = kops.spmm_bcsr_fused_op(
+            fw.blk_tag, fw.blk_off, fw.blk_coff, fw.blk_L, fw.cols_flat,
+            vals_flat, x_pad, fw.cont, bm=self.bm, bk=self.bk,
+            mw=fw.merge_width, interpret=self.interpret,
+            staging=self.staging, span=fw.max_span, cspan=fw.max_cspan)
         # one inverse-permutation gather un-interleaves ALL requests
         return y_ws[fw.inv_perm]
 
@@ -1042,9 +1008,9 @@ def _attention_panel(a: CSRMatrix, backend: str, bm: Optional[int],
                      mesh: Optional[Mesh]) -> tuple:
     """The ``(bm, bk)`` MXU panel of an attention artifact: as the
     caller gave it (a missing one is 8), or, where neither is given, a
-    square panel derived from the mask on the mixed backend
+    square panel derived from the mask on the fused backend
     (:func:`~repro.core.plan.choose_panel_edge`, DESIGN.md §13.4).  The
-    other backends have no MXU trip and keep 8x8."""
+    ``ref`` backend has no MXU trip and keeps 8x8."""
     if bm is not None or bk is not None:
         return (PANEL_EDGES[0] if bm is None else int(bm),
                 PANEL_EDGES[0] if bk is None else int(bk))
@@ -1121,11 +1087,12 @@ class CompiledSparseAttention(_SlotValueCounts):
                                             self.interpret)
         self.mesh = resolve_chip_mesh(mesh, n_chips)
         self.n_chips = None if self.mesh is None else int(self.mesh.size)
-        if self.mesh is not None and self.backend not in FUSED_BACKENDS:
+        fused = self.backend == "pallas_bcsr"
+        if self.mesh is not None and not fused:
             raise ValueError(
                 f"mesh/n_chips sharding is a fused-dispatch feature "
-                f"({'/'.join(FUSED_BACKENDS)}); backend="
-                f"{self.backend!r} is single-device")
+                f"(pallas_bcsr); backend={self.backend!r} is "
+                f"single-device")
         bm, bk = _attention_panel(a, self.backend, bm, bk, mxu_gain,
                                   self.mesh)
         self.bm, self.bk = bm, bk
@@ -1151,18 +1118,16 @@ class CompiledSparseAttention(_SlotValueCounts):
         self._row_map: Optional[jax.Array] = None   # ws slot -> Q row
         self._panels = None                          # PanelStats
         self._vpu = True      # whether the stream holds VPU trips
-        if self.backend in FUSED_BACKENDS:
-            # the ELL backend tags nothing MXU: no panels
+        if fused:
             self._panels = panel_stats(
                 a.row_ptr, a.col_indices, a.shape, [(bm, bk)],
-                mxu_gain=mxu_gain if self.backend == "pallas_bcsr"
-                else 0.0)[0]
-        if self.backend in FUSED_BACKENDS and self.mesh is not None:
+                mxu_gain=mxu_gain)[0]
+        if fused and self.mesh is not None:
             sw: ShardedFusedWorkspace = build_sharded_workspace(
                 a.row_ptr, a.col_indices, a.shape, self.dv,
                 n_chips=self.n_chips, strategy=strategy, row_block=bm,
-                fingerprint=a.fingerprint, backend=self.backend,
-                bk=bk, mxu_gain=mxu_gain, x_sharding="replicated",
+                fingerprint=a.fingerprint, bk=bk, mxu_gain=mxu_gain,
+                x_sharding="replicated",
                 merge_threshold=self.merge_threshold)
             self.sharded_workspace = sw
             _require_resident_fit(
@@ -1171,10 +1136,8 @@ class CompiledSparseAttention(_SlotValueCounts):
             row_maps = sharded_workspace_row_maps(sw)
             _verify_workspace_timed(
                 sw, level=self.validate, n_cols=a.shape[1],
-                spec=(SPARSE_ATTN_MIXED_EINSUM
-                      if self.backend == "pallas_bcsr"
-                      else SPARSE_ATTN_EINSUM),
-                vals=np.asarray(a.vals), row_map=row_maps,
+                spec=SPARSE_ATTN_MIXED_EINSUM, vals=np.asarray(a.vals),
+                row_map=row_maps,
                 context=f"compile_sparse_attention[{self.backend}"
                         f"/sharded]")
             self._sharded = _sharded_consts(sw, self.mesh)
@@ -1183,13 +1146,10 @@ class CompiledSparseAttention(_SlotValueCounts):
             _record_build(
                 sum(p.plan_seconds for p in sw.shard_plans),
                 sw.pack_seconds)
-        elif self.backend in FUSED_BACKENDS:
-            spec = (SPARSE_ATTN_MIXED_EINSUM
-                    if self.backend == "pallas_bcsr"
-                    else SPARSE_ATTN_EINSUM)
+        elif fused:
             ws = build_einsum_workspace(
-                spec, a.row_ptr, a.col_indices, a.shape, self.dv,
-                strategy=strategy, row_block=bm, bk=bk,
+                SPARSE_ATTN_MIXED_EINSUM, a.row_ptr, a.col_indices,
+                a.shape, self.dv, strategy=strategy, row_block=bm, bk=bk,
                 mxu_gain=mxu_gain, merge_threshold=self.merge_threshold,
                 fingerprint=a.fingerprint)
             self.workspace = ws
@@ -1203,15 +1163,14 @@ class CompiledSparseAttention(_SlotValueCounts):
                 ws.inv_perm, ws.ws_rows, ws.blk_cont,
                 ws.merge_width * ws.row_block)
             _verify_workspace_timed(
-                ws, level=self.validate, n_cols=a.shape[1], spec=spec,
-                vals=np.asarray(a.vals), row_map=row_map,
+                ws, level=self.validate, n_cols=a.shape[1],
+                spec=SPARSE_ATTN_MIXED_EINSUM, vals=np.asarray(a.vals),
+                row_map=row_map,
                 context=f"compile_sparse_attention[{self.backend}]")
             self._fused = _fused_consts(ws, a.nnz)
             self._vpu = _runs_vpu_trips(ws)
             self._row_map = jnp.asarray(row_map)
             _record_build(0.0, ws.pack_seconds)
-        elif self.backend != "ref":
-            raise ValueError(self.backend)
 
         self._erows: Optional[np.ndarray] = None
 
@@ -1235,13 +1194,13 @@ class CompiledSparseAttention(_SlotValueCounts):
     @property
     def mxu_panels(self) -> Optional[int]:
         """MXU panels of ``bm x bk`` one call multiplies; None off the
-        fused backends."""
+        fused backend."""
         return None if self._panels is None else self._panels.panels
 
     @property
     def panel_fill(self) -> Optional[float]:
         """Nonzeros over the slots of those panels (0 without any);
-        None off the fused backends."""
+        None off the fused backend."""
         return None if self._panels is None else self._panels.fill
 
     def _kv_bytes(self) -> int:
@@ -1363,7 +1322,7 @@ def compile_sparse_attention(a: CSRMatrix, dh: int,
     a new artifact while repeated (B, H) instances of one layer hit.
 
     ``bm``/``bk`` pin the MXU panel; where neither is given it is
-    derived from the mask (a square panel of 8 to 128 on the mixed
+    derived from the mask (a square panel of 8 to 128 on the fused
     backend, DESIGN.md §13.4), and the resolved panel joins the key."""
     backend = _resolve_backend(
         backend, sharded=mesh is not None or n_chips is not None)

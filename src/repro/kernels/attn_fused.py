@@ -6,14 +6,14 @@ lets one generated kernel beat AOT pipelines; sparse attention is the
 strongest test because the SAME plan must drive three chained
 contractions.  An AOT pipeline runs them as three dispatches with the
 score matrix ``S = mask ⊙ (Q·Kᵀ)`` round-tripping through HBM twice;
-here each descriptor trip computes its scores via the SDDMM pattern
-(``kernels/sddmm.py``), folds them into a running softmax held in the
+here each descriptor trip computes its scores as a sampled dense-dense
+product (SDDMM) over its slots, folds them into a running softmax held in the
 vector register file, and immediately consumes ``S·V`` through the
 existing ELL/BCSR trip machinery — ``S`` never materializes
 (DESIGN.md §13).
 
 Per grid step the descriptor is read from SMEM exactly as in the SpMM
-twins (``spmm_ell_fused``/``spmm_bcsr_fused``); the only new state is
+kernel (``spmm_bcsr_fused``); the only new state is
 the online-softmax carry per sub-block row: accumulator ``acc`` plus
 running max ``m`` and running denominator ``l``.  Each trip rescales
 the carry by ``exp(m - m_new)`` before folding its contribution, so a

@@ -22,10 +22,6 @@ import collections
 import jax
 
 from .attn_fused import attn_fused, attn_fused_sharded, attn_fused_staged
-from .spmm_csr import spmm_ell_segment
-from .spmm_ell_fused import (spmm_ell_fused, spmm_ell_fused_sharded,
-                             spmm_ell_fused_staged)
-from .spmm_bcsr import spmm_bcsr
 from .spmm_bcsr_fused import (spmm_bcsr_fused, spmm_bcsr_fused_sharded,
                               spmm_bcsr_fused_staged)
 from .staging import chip_windows, num_calls
@@ -43,10 +39,8 @@ DISPATCH_COUNTS: "collections.Counter[str]" = collections.Counter()
 # and a renamed wrapper cannot leave a stale key behind.
 DISPATCH_KEYS = frozenset({
     # per-pallas_call invariant keys (one per plan, n_chips when sharded)
-    "ell_segment", "ell_fused", "bcsr", "bcsr_fused", "attn_fused",
-    "sddmm",
+    "bcsr_fused", "attn_fused",
     # lowering-variant keys: WHICH path served a forward
-    "ell_fused_dma", "ell_fused_sharded", "ell_fused_xshard",
     "bcsr_fused_dma", "bcsr_fused_sharded", "bcsr_fused_xshard",
     "attn_fused_dma", "attn_fused_sharded",
 })
@@ -135,74 +129,11 @@ def _resolve_op_staging(staging, interpret, span: int, cspan: int) -> str:
     return "resident"
 
 
-def spmm_ell_segment_op(cols_pad_flat, vals_pad, x, *, bm: int = 8,
-                        interpret=None):
-    interpret = resolve_interpret(interpret)
-    DISPATCH_COUNTS["ell_segment"] += 1
-    return spmm_ell_segment(cols_pad_flat, vals_pad, x, bm=bm,
-                            interpret=interpret)
-
-
 def _launches(staging: str, num_blocks: int, mw: int) -> int:
     """``pallas_call`` launches one fused forward issues per chip: one,
     or for a staged stream longer than one call's SMEM tables, one per
     call (``staging.issue_in_calls``)."""
     return num_calls(num_blocks, mw) if staging == "dma" else 1
-
-
-def spmm_ell_fused_op(blk_off, blk_L, cols_flat, vals_flat, x, cont=None,
-                      *, bm: int = 8, mw: int = 1, interpret=None,
-                      staging=None, span: int = 0, cspan: int = 0):
-    """ONE dispatch for the whole plan, either staging mode; staged
-    launches additionally count under ``ell_fused_dma`` so tests can
-    assert WHICH lowering served a forward."""
-    interpret = resolve_interpret(interpret)
-    staging = _resolve_op_staging(staging, interpret, span, cspan)
-    n = _launches(staging, blk_off.shape[0], mw)
-    DISPATCH_COUNTS["ell_fused"] += n
-    if staging == "dma":
-        DISPATCH_COUNTS["ell_fused_dma"] += n
-        return spmm_ell_fused_staged(blk_off, blk_L, cols_flat, vals_flat,
-                                     x, cont, span=span, cspan=cspan,
-                                     bm=bm, mw=mw, interpret=interpret)
-    return spmm_ell_fused(blk_off, blk_L, cols_flat, vals_flat, x, cont,
-                          bm=bm, mw=mw, interpret=interpret)
-
-
-def spmm_ell_fused_sharded_op(blk_off, blk_L, cols_flat, vals_flat, x,
-                              cont=None, *, mesh, bm: int = 8, mw: int = 1,
-                              interpret=None,
-                              staging=None, span=0, cspan=0,
-                              x_sharding: str = "replicated",
-                              x_send=None, x_recv=None):
-    """One fused dispatch per chip: counts ``mesh.size`` pallas_calls
-    under the ``ell_fused`` key (the per-forward invariant the sharded
-    tests assert) plus one ``ell_fused_sharded`` wrapper call —
-    ``mesh.size`` under ``ell_fused_dma`` when staged, and ``mesh.size``
-    under ``ell_fused_xshard`` when X is row-sharded (the fetch-table
-    exchange path; ``span``/``cspan`` accept per-chip tuples)."""
-    interpret = resolve_interpret(interpret)
-    span = chip_windows(span, mesh.size)
-    cspan = chip_windows(cspan, mesh.size)
-    staging = _resolve_op_staging(staging, interpret, min(span),
-                                  min(cspan))
-    n = mesh.size * _launches(staging, blk_off.shape[1], mw)
-    DISPATCH_COUNTS["ell_fused"] += n
-    DISPATCH_COUNTS["ell_fused_sharded"] += 1
-    if x_sharding == "rows":
-        DISPATCH_COUNTS["ell_fused_xshard"] += n
-    if staging == "dma":
-        DISPATCH_COUNTS["ell_fused_dma"] += n
-    else:
-        span = cspan = (0,) * mesh.size   # resident ignores the windows:
-                                          # keep them out of the memoized
-                                          # shard_map cache key
-    return spmm_ell_fused_sharded(blk_off, blk_L, cols_flat, vals_flat, x,
-                                  cont, mesh=mesh, bm=bm, mw=mw,
-                                  interpret=interpret,
-                                  staging=staging, span=span, cspan=cspan,
-                                  x_sharding=x_sharding, x_send=x_send,
-                                  x_recv=x_recv)
 
 
 def attn_fused_op(blk_tag, blk_off, blk_coff, blk_L, cols_flat,
@@ -255,14 +186,6 @@ def attn_fused_sharded_op(blk_tag, blk_off, blk_coff, blk_L, cols_flat,
                               span=span, cspan=cspan)
 
 
-def spmm_bcsr_op(block_cols_pad, block_vals_pad, x, *, kmax: int,
-                 interpret=None):
-    interpret = resolve_interpret(interpret)
-    DISPATCH_COUNTS["bcsr"] += 1
-    return spmm_bcsr(block_cols_pad, block_vals_pad, x, kmax=kmax,
-                     interpret=interpret)
-
-
 def spmm_bcsr_fused_op(blk_tag, blk_off, blk_coff, blk_L, cols_flat,
                        vals_flat, x, cont=None, *, bm: int = 8, bk: int = 8,
                        mw: int = 1, interpret=None, staging=None,
@@ -294,9 +217,10 @@ def spmm_bcsr_fused_sharded_op(blk_tag, blk_off, blk_coff, blk_L,
                                x_send=None, x_recv=None):
     """One mixed fused dispatch per chip: counts ``mesh.size``
     pallas_calls under the ``bcsr_fused`` key plus one
-    ``bcsr_fused_sharded`` wrapper call — same accounting shape as the
-    ELL sharded path, with ``bcsr_fused_dma`` tracking staged chips and
-    ``bcsr_fused_xshard`` tracking row-sharded-X chips."""
+    ``bcsr_fused_sharded`` wrapper call, ``mesh.size`` under
+    ``bcsr_fused_dma`` when staged, and ``mesh.size`` under
+    ``bcsr_fused_xshard`` when X is row-sharded (the fetch-table
+    exchange path; ``span``/``cspan`` accept per-chip tuples)."""
     interpret = resolve_interpret(interpret)
     span = chip_windows(span, mesh.size)
     cspan = chip_windows(cspan, mesh.size)

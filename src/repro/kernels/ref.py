@@ -16,17 +16,6 @@ def spmm_dense_ref(a_dense: jax.Array, x: jax.Array) -> jax.Array:
     return a_dense.astype(jnp.float32) @ x.astype(jnp.float32)
 
 
-def spmm_ell_segment_ref(cols_pad, vals_pad, x):
-    """Oracle for one ELL segment: (R_pad, L) cols/vals against X (n, d).
-
-    Padding slots carry val == 0 so they contribute nothing (col 0 is a
-    harmless real row — same trick as the kernels).
-    """
-    gathered = x[cols_pad]                       # (R_pad, L, d)
-    return jnp.einsum("rl,rld->rd", vals_pad.astype(jnp.float32),
-                      gathered.astype(jnp.float32))
-
-
 def spmm_csr_ref(row_ptr, col_indices, vals, x, m: int) -> jax.Array:
     """Row-by-row CSR oracle (Algorithm 1 of the paper, vectorized over d
     via CCM — Algorithm 2).  Host-side structure, jnp compute."""

@@ -7,8 +7,8 @@ structure, and ONE ``pallas_call`` covers both:
 
   VPU descriptor (tag 0): ``blk_L`` = padded nnz/row; each trip gathers
       one X row per block row and scales it by that slot's value, read
-      as a scalar from SMEM — identical arithmetic to the pure-ELL
-      kernel, which is this kernel with every tag 0.
+      as a scalar from SMEM.  A plan tagged with ``mxu_gain=0`` is all
+      VPU descriptors.
   MXU descriptor (tag 1): ``blk_L`` = the block-row's own ``K``; each
       trip multiplies one ``(bm, bk)`` value panel (an aligned vector
       tile: the packer lane-pads panels to ``(bm, LANE)``) against the

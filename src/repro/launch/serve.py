@@ -60,9 +60,9 @@ from ..core.autotune import (TuneConfig, default_candidates,
 from ..analysis.verify import resolve_validate
 from ..core.csr import CSRMatrix, random_csr
 from ..core.jit_cache import GLOBAL_CACHE, JitCache
-from ..core.spmm import (FUSED_BACKENDS, PlanVerificationError,
-                         _resolve_backend, _resolve_staging_for,
-                         compile_batched_spmm, compile_spmm)
+from ..core.spmm import (PlanVerificationError, _resolve_backend,
+                         _resolve_staging_for, compile_batched_spmm,
+                         compile_spmm)
 from ..data.pipeline import DeviceStage
 from ..kernels.ops import resolve_interpret
 from ..models.model import Model
@@ -196,10 +196,10 @@ class SpmmServer:
         # sharded=True resolution: batching needs the fused descriptor-
         # table path, so "auto" must not fall back to ref on CPU
         self.backend = _resolve_backend(backend, sharded=True)
-        if self.backend not in FUSED_BACKENDS:
+        if self.backend != "pallas_bcsr":
             raise ValueError(
                 f"SpmmServer batches through the fused dispatch "
-                f"({'/'.join(FUSED_BACKENDS)}), got {self.backend!r}")
+                f"(pallas_bcsr), got {self.backend!r}")
         if max_batch < 1:
             raise ValueError(f"max_batch must be >= 1, got {max_batch}")
         self.strategy = strategy

@@ -30,7 +30,6 @@ from .mesh import make_chip_mesh, make_host_mesh
 
 
 def spmm_shard_preflight(n_chips: int,
-                         backend: str = "pallas_ell",
                          x_sharding: str = "auto",
                          autotune: bool = False) -> int:
     """Validate the sharded fused SpMM path on this host's devices before
@@ -39,22 +38,15 @@ def spmm_shard_preflight(n_chips: int,
     asking for more chips than the host exposes raises rather than
     silently validating a smaller mesh than the run was configured for.
 
-    ``backend`` selects the fused dispatch the run will use: the VPU ELL
-    path (``pallas_ell``) or the mixed VPU/MXU path (``pallas_bcsr``),
-    which exercises block-row-aligned chip partitioning and the MXU
-    descriptor stream.  ``x_sharding`` selects X placement on the mesh
-    ("replicated", "rows" = exact-panel fetch from owning chips, or
-    "auto" — the same resolution the run itself will get), so a
+    It exercises block-row-aligned chip partitioning and the mixed
+    VPU/MXU descriptor stream.  ``x_sharding`` selects X placement on
+    the mesh ("replicated", "rows" = exact-panel fetch from owning
+    chips, or "auto" — the same resolution the run itself will get), so a
     fetch-table/exchange lowering failure surfaces before step 0 too.
     ``autotune=True`` additionally runs the per-instance plan search
     (DESIGN.md §11) on the preflight fixture — warming the jit cache
     with the winner and surfacing search-path failures up front."""
-    from ..core import (FUSED_BACKENDS, JitCache, X_SHARDING_MODES,
-                        random_csr, spmm)
-    if backend not in FUSED_BACKENDS:
-        raise ValueError(
-            f"--spmm-backend must be one of {FUSED_BACKENDS}, "
-            f"got {backend!r}")
+    from ..core import JitCache, X_SHARDING_MODES, random_csr, spmm
     if x_sharding not in ("auto", *X_SHARDING_MODES):
         raise ValueError(
             f"--x-sharding must be 'auto' or one of {X_SHARDING_MODES}, "
@@ -73,19 +65,19 @@ def spmm_shard_preflight(n_chips: int,
     # interpret=None resolves to the mode the run itself will use
     # (native on TPU, interpret on CPU) — the whole point is to surface
     # lowering failures of the real path before step 0
-    y = spmm(a, x, strategy="nnz_split", backend=backend,
+    y = spmm(a, x, strategy="nnz_split", backend="pallas_bcsr",
              interpret=None, mesh=mesh, x_sharding=x_sharding,
              cache=cache)
     y_ref = spmm(a, x, strategy="nnz_split", backend="ref", cache=cache)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                rtol=1e-4, atol=1e-4)
     if autotune:
-        y_t = spmm(a, x, backend=backend, interpret=None, mesh=mesh,
+        y_t = spmm(a, x, backend="pallas_bcsr", interpret=None, mesh=mesh,
                    x_sharding=x_sharding, autotune=True, cache=cache)
         np.testing.assert_allclose(np.asarray(y_t), np.asarray(y_ref),
                                    rtol=1e-4, atol=1e-4)
     print(f"[train] spmm shard preflight OK on {n_chips} chip(s) "
-          f"({backend}, x_sharding={x_sharding}"
+          f"(x_sharding={x_sharding}"
           f"{', autotuned' if autotune else ''})", flush=True)
     return n_chips
 
@@ -121,7 +113,7 @@ def run_training(cfg, *, steps: int, global_batch: int, seq_len: int,
                  ckpt_dir=None, ckpt_every: int = 20, lr: float = 3e-4,
                  microbatches: int = 1, remat: str = "full",
                  data_parallel: int = 1, model_parallel: int = 1,
-                 spmm_chips: int = 0, spmm_backend: str = "pallas_ell",
+                 spmm_chips: int = 0,
                  spmm_x_sharding: str = "auto", spmm_autotune: bool = False,
                  log_every: int = 10,
                  fault_injector=None, watchdog: Watchdog = None,
@@ -130,7 +122,7 @@ def run_training(cfg, *, steps: int, global_batch: int, seq_len: int,
     if spmm_chips:
         # the sparse-aggregation chips share the host devices with the
         # train mesh; fail fast here rather than mid-run
-        spmm_shard_preflight(spmm_chips, spmm_backend, spmm_x_sharding,
+        spmm_shard_preflight(spmm_chips, spmm_x_sharding,
                              autotune=spmm_autotune)
     if "sattn" in cfg.pattern:
         sparse_attn_preflight(cfg, seq_len)
@@ -238,10 +230,6 @@ def main():
     ap.add_argument("--spmm-chips", type=int, default=0,
                     help="validate the sharded fused SpMM path on this "
                          "many chips before training (0 = skip)")
-    ap.add_argument("--spmm-backend", default="pallas_ell",
-                    choices=["pallas_ell", "pallas_bcsr"],
-                    help="fused SpMM dispatch the preflight validates: "
-                         "VPU ELL or the mixed VPU/MXU (BCSR) path")
     ap.add_argument("--x-sharding", default="auto",
                     choices=["auto", "replicated", "rows"],
                     help="X placement the preflight validates on the "
@@ -265,7 +253,7 @@ def main():
         ckpt_dir=args.ckpt_dir, ckpt_every=args.ckpt_every, lr=args.lr,
         microbatches=args.microbatches, remat=args.remat,
         data_parallel=args.dp, model_parallel=args.tp,
-        spmm_chips=args.spmm_chips, spmm_backend=args.spmm_backend,
+        spmm_chips=args.spmm_chips,
         spmm_x_sharding=args.x_sharding, spmm_autotune=args.autotune)
     print(f"[train] done: first loss {losses[0]:.4f} "
           f"last loss {losses[-1]:.4f} ({time.time()-t0:.1f}s)")

@@ -15,7 +15,6 @@ Pinned here:
   * the Table IV invariant: exactly one pallas_call per chip per
     forward, on the traced jaxpr and in DISPATCH_COUNTS,
   * the jit-cache key separates every resolved knob,
-  * sddmm_csr's interpret auto-resolution (satellite of the same PR),
   * the model-layer bridge: sparse_self_attention_layer == dense GQA
     attention with the equivalent window+global mask,
   * the MXU panel derived from the mask (DESIGN.md §13.4): every edge
@@ -38,12 +37,14 @@ from repro.core import (CSRMatrix, compile_sparse_attention, random_csr,
 from repro.core.jit_cache import JitCache
 from repro.core.plan import STRATEGIES
 from repro.kernels import ops
-from repro.kernels.sddmm import sddmm_csr
 
 ROOT = Path(__file__).resolve().parents[1]
 N_DEV = len(jax.devices())
 MAX_CHIPS = min(N_DEV, 4)
-FUSED = ("pallas_ell", "pallas_bcsr")
+# the fused backend with every block-row tagged VPU, and as the mask
+# tags it; parametrized by key
+FUSED = {"vpu": dict(backend="pallas_bcsr", mxu_gain=0.0),
+         "pallas_bcsr": dict(backend="pallas_bcsr")}
 
 
 def _dense_oracle(a, vals, q, k, v):
@@ -94,7 +95,7 @@ def test_fused_matches_dense_oracle(backend, staging):
     want = _dense_oracle(a, a.vals, q, k, v)
     for strategy in STRATEGIES:
         c = compile_sparse_attention(
-            a, 12, 20, strategy=strategy, backend=backend,
+            a, 12, 20, strategy=strategy, **FUSED[backend],
             interpret=True, staging=staging, cache=JitCache())
         got = np.asarray(c(jnp.asarray(a.vals), q, k, v))
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5,
@@ -121,7 +122,7 @@ def test_multi_trip_rows_stay_exact(backend):
     q, k, v = _qkv(a.m, a.n, 8, 8, seed=8)
     q = q * 12.0
     want = _dense_oracle(a, a.vals, q, k, v)
-    got = np.asarray(sparse_attention(a, q, k, v, backend=backend,
+    got = np.asarray(sparse_attention(a, q, k, v, **FUSED[backend],
                                       interpret=True, cache=JitCache()))
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
@@ -131,7 +132,7 @@ def test_empty_rows_produce_zero_output():
     cols = np.array([0, 3, 1], np.int32)
     a = CSRMatrix((4, 5), row_ptr, cols, jnp.ones((3,), jnp.float32))
     q, k, v = _qkv(4, 5, 6, 6, seed=9)
-    y = np.asarray(sparse_attention(a, q, k, v, backend="pallas_ell",
+    y = np.asarray(sparse_attention(a, q, k, v, **FUSED["vpu"],
                                     interpret=True, cache=JitCache()))
     assert np.all(y[1] == 0) and np.all(y[3] == 0)
     np.testing.assert_allclose(y, _dense_oracle(a, a.vals, q, k, v),
@@ -150,7 +151,7 @@ def test_gradients_match_ref_backend(backend):
         return jax.grad(f, argnums=(0, 1, 2, 3))(vals, q, k, v)
 
     g_fused = loss(compile_sparse_attention(
-        a, 8, 12, backend=backend, interpret=True, cache=JitCache()))
+        a, 8, 12, **FUSED[backend], interpret=True, cache=JitCache()))
     g_ref = loss(compile_sparse_attention(
         a, 8, 12, backend="ref", cache=JitCache()))
     for gf, gr, name in zip(g_fused, g_ref, ("vals", "q", "k", "v")):
@@ -162,9 +163,9 @@ def test_gradients_match_ref_backend(backend):
 def test_merged_bit_matches_unmerged(backend):
     a = _mask(m=64, n=48, seed=13, density=0.08)
     q, k, v = _qkv(a.m, a.n, 8, 8, seed=14)
-    y0 = sparse_attention(a, q, k, v, backend=backend, interpret=True,
+    y0 = sparse_attention(a, q, k, v, **FUSED[backend], interpret=True,
                           merge_threshold=0, cache=JitCache())
-    y1 = sparse_attention(a, q, k, v, backend=backend, interpret=True,
+    y1 = sparse_attention(a, q, k, v, **FUSED[backend], interpret=True,
                           merge_threshold=16, cache=JitCache())
     assert np.array_equal(np.asarray(y0), np.asarray(y1))
 
@@ -174,10 +175,10 @@ def test_merged_bit_matches_unmerged(backend):
 def test_sharded_bit_matches_single_chip(backend, staging):
     a = _mask(m=64, n=48, seed=15, density=0.1)
     q, k, v = _qkv(a.m, a.n, 8, 8, seed=16)
-    y0 = sparse_attention(a, q, k, v, backend=backend, interpret=True,
+    y0 = sparse_attention(a, q, k, v, **FUSED[backend], interpret=True,
                           staging=staging, cache=JitCache())
     for chips in range(1, MAX_CHIPS + 1):
-        y = sparse_attention(a, q, k, v, backend=backend,
+        y = sparse_attention(a, q, k, v, **FUSED[backend],
                              interpret=True, staging=staging,
                              n_chips=chips, cache=JitCache())
         assert np.array_equal(np.asarray(y0), np.asarray(y)), \
@@ -203,7 +204,7 @@ def _iter_eqns(jaxpr):
 def test_forward_is_one_pallas_call(backend, staging):
     a = _mask(seed=17)
     q, k, v = _qkv(a.m, a.n, 8, 8, seed=18)
-    c = compile_sparse_attention(a, 8, 8, backend=backend,
+    c = compile_sparse_attention(a, 8, 8, **FUSED[backend],
                                  interpret=True, staging=staging,
                                  cache=JitCache())
     jaxpr = jax.make_jaxpr(
@@ -219,7 +220,6 @@ def test_forward_is_one_pallas_call(backend, staging):
     assert ops.DISPATCH_COUNTS["attn_fused"] == 1
     assert ops.DISPATCH_COUNTS["attn_fused_dma"] == (
         1 if staging == "dma" else 0)
-    assert ops.DISPATCH_COUNTS["sddmm"] == 0   # no separate SDDMM pass
 
 
 @pytest.mark.skipif(N_DEV < 2, reason="single-device host")
@@ -228,7 +228,7 @@ def test_sharded_forward_is_one_pallas_call_per_chip(backend):
     chips = MAX_CHIPS
     a = _mask(m=64, n=48, seed=19, density=0.1)
     q, k, v = _qkv(a.m, a.n, 8, 8, seed=20)
-    c = compile_sparse_attention(a, 8, 8, backend=backend,
+    c = compile_sparse_attention(a, 8, 8, **FUSED[backend],
                                  interpret=True, n_chips=chips,
                                  cache=JitCache())
     jaxpr = jax.make_jaxpr(
@@ -274,15 +274,16 @@ def test_acceptance_on_8_device_mesh():
         v = jnp.asarray(rng.standard_normal((64, 16)), jnp.float32)
         y_ref = sparse_attention(a, q, k, v, backend="ref",
                                  cache=JitCache())
-        for backend in ("pallas_ell", "pallas_bcsr"):
-            y0 = sparse_attention(a, q, k, v, backend=backend,
-                                  interpret=True, cache=JitCache())
-            ops.reset_dispatch_counts()
-            y8 = sparse_attention(a, q, k, v, backend=backend,
-                                  interpret=True, n_chips=8,
+        for gain in (0.0, 4.0):      # all-VPU, and as the mask tags it
+            y0 = sparse_attention(a, q, k, v, backend="pallas_bcsr",
+                                  mxu_gain=gain, interpret=True,
                                   cache=JitCache())
-            assert ops.DISPATCH_COUNTS["attn_fused"] == 8, backend
-            assert np.array_equal(np.asarray(y0), np.asarray(y8)), backend
+            ops.reset_dispatch_counts()
+            y8 = sparse_attention(a, q, k, v, backend="pallas_bcsr",
+                                  mxu_gain=gain, interpret=True,
+                                  n_chips=8, cache=JitCache())
+            assert ops.DISPATCH_COUNTS["attn_fused"] == 8, gain
+            assert np.array_equal(np.asarray(y0), np.asarray(y8)), gain
             np.testing.assert_allclose(np.asarray(y8), np.asarray(y_ref),
                                        rtol=1e-4, atol=1e-4)
         print("OK")
@@ -294,51 +295,31 @@ def test_acceptance_on_8_device_mesh():
 
 
 # ---------------------------------------------------------------------------
-# Cache-key discipline + the sddmm satellite
+# Cache-key discipline
 # ---------------------------------------------------------------------------
 
 def test_jit_cache_key_separates_knobs():
     a = _mask(seed=21)
     cache = JitCache()
-    c0 = compile_sparse_attention(a, 8, backend="pallas_ell",
+    c0 = compile_sparse_attention(a, 8, **FUSED["vpu"],
                                   interpret=True, cache=cache)
-    assert compile_sparse_attention(a, 8, backend="pallas_ell",
+    assert compile_sparse_attention(a, 8, **FUSED["vpu"],
                                     interpret=True, cache=cache) is c0
     distinct = [
-        compile_sparse_attention(a, 8, backend="pallas_ell",
+        compile_sparse_attention(a, 8, **FUSED["vpu"],
                                  interpret=True, staging="dma",
                                  cache=cache),
-        compile_sparse_attention(a, 8, backend="pallas_ell",
+        compile_sparse_attention(a, 8, **FUSED["vpu"],
                                  interpret=True, sm_scale=1.0,
                                  cache=cache),
-        compile_sparse_attention(a, 8, 16, backend="pallas_ell",
+        compile_sparse_attention(a, 8, 16, **FUSED["vpu"],
                                  interpret=True, cache=cache),
-        compile_sparse_attention(a, 8, backend="pallas_ell",
+        compile_sparse_attention(a, 8, **FUSED["vpu"],
                                  interpret=True, merge_threshold=16,
                                  cache=cache),
     ]
     assert all(c is not c0 for c in distinct)
     assert len({id(c) for c in distinct}) == len(distinct)
-
-
-def test_sddmm_csr_interpret_auto_resolves():
-    """Satellite: interpret=None must resolve like the fused kernels
-    (interpreted off-TPU) instead of the old hardwired default, count a
-    dispatch, and agree with the explicit interpret=True path."""
-    a = random_csr(24, 16, density=0.2, family="uniform", seed=22)
-    rng = np.random.default_rng(23)
-    dy = jnp.asarray(rng.standard_normal((a.m, 8)), jnp.float32)
-    x = jnp.asarray(rng.standard_normal((a.n, 8)), jnp.float32)
-    ops.reset_dispatch_counts()
-    d_auto = sddmm_csr(a, dy, x, T=8)
-    assert ops.DISPATCH_COUNTS["sddmm"] == 1
-    d_true = sddmm_csr(a, dy, x, T=8, interpret=True)
-    assert np.array_equal(np.asarray(d_auto), np.asarray(d_true))
-    rows = np.repeat(np.arange(a.m), np.diff(a.row_ptr))
-    want = np.sum(np.asarray(dy)[rows] * np.asarray(x)[a.col_indices],
-                  axis=1)
-    np.testing.assert_allclose(np.asarray(d_auto), want, rtol=1e-5,
-                               atol=1e-5)
 
 
 # ---------------------------------------------------------------------------
@@ -403,11 +384,11 @@ def test_split_blocks_keep_the_softmax_exact(mixed, pack_with_window):
     from trip to trip: at a tiny staging window both lowerings
     reproduce the unsplit plan bit for bit."""
     from repro.core import (build_fused_workspace, build_mixed_plan,
-                            build_plan, workspace_row_map)
+                            workspace_row_map)
     a = _mask(m=40, n=48, density=0.4, seed=3)
     q, k, v = _qkv(40, 48, 16, 16, seed=4)
-    build = build_mixed_plan if mixed else build_plan
-    plan = build(a.row_ptr, a.col_indices, a.shape, 16)
+    plan = build_mixed_plan(a.row_ptr, a.col_indices, a.shape, 16,
+                            mxu_gain=4.0 if mixed else 0.0)
 
     def run(ws, staging):
         vals = jnp.concatenate([a.vals, jnp.zeros((1,), jnp.float32)])
@@ -474,7 +455,7 @@ def test_live_lane_staging_matches_the_plain_gather(backend, staging):
     a = sparse_attention_mask(96, window=24, num_global=4)
     q, k, v = _qkv(a.m, a.n, 16, 16, seed=22)
     # the 8x8 layout: 120 of each panel's 128 lanes are padding
-    art = compile_sparse_attention(a, 16, backend=backend, bm=8, bk=8,
+    art = compile_sparse_attention(a, 16, **FUSED[backend], bm=8, bk=8,
                                    interpret=True, staging=staging,
                                    cache=JitCache())
     vals = jnp.asarray(a.vals)
@@ -582,9 +563,9 @@ def test_derived_panel_through_compile_sparse_attention():
     assert c.mxu_panels == int(ws.blk_L[ws.blk_tag == MXU_TAG].sum())
     assert c.panel_fill == pytest.approx(a.nnz / (c.mxu_panels * 16 * 16))
     assert c8.mxu_panels > c.mxu_panels and c8.panel_fill > c.panel_fill
-    ell = compile_sparse_attention(a, 16, backend="pallas_ell",
-                                   interpret=True, cache=cache)
-    assert (ell.bm, ell.bk, ell.mxu_panels) == (8, 8, 0)
+    vpu = compile_sparse_attention(a, 16, **FUSED["vpu"], interpret=True,
+                                   cache=cache)
+    assert (vpu.bm, vpu.bk, vpu.mxu_panels) == (8, 8, 0)
     ref = compile_sparse_attention(a, 16, backend="ref", cache=cache)
     assert ref.mxu_panels is None and ref.panel_fill is None
     q, k, v = _qkv(a.m, a.n, 16, 16, seed=33)

@@ -27,6 +27,10 @@ from repro.core.jit_cache import JitCache
 from repro.core.plan import STRATEGIES
 
 N_DEV = len(jax.devices())
+# the fused backend with every block-row tagged VPU, and as the mask
+# tags it; sampled by key
+FUSED = {"vpu": dict(backend="pallas_bcsr", mxu_gain=0.0),
+         "pallas_bcsr": dict(backend="pallas_bcsr")}
 
 
 def _mask_from_lengths(lengths, n, seed):
@@ -92,11 +96,11 @@ def _qkv(a, dh, dv, seed):
 @settings(max_examples=10, deadline=None)
 @given(a=mask_cases(), dh=st.integers(1, 16), dv=st.integers(1, 24),
        strategy=st.sampled_from(STRATEGIES),
-       backend=st.sampled_from(("pallas_ell", "pallas_bcsr")))
+       backend=st.sampled_from(sorted(FUSED)))
 def test_fused_sandwich_matches_dense_oracle(a, dh, dv, strategy,
                                              backend):
     q, k, v = _qkv(a, dh, dv, seed=dh + dv)
-    y = sparse_attention(a, q, k, v, strategy=strategy, backend=backend,
+    y = sparse_attention(a, q, k, v, strategy=strategy, **FUSED[backend],
                          interpret=True, cache=JitCache())
     np.testing.assert_allclose(np.asarray(y),
                                _dense_oracle(a, a.vals, q, k, v),
@@ -106,7 +110,7 @@ def test_fused_sandwich_matches_dense_oracle(a, dh, dv, strategy,
 @settings(max_examples=8, deadline=None)
 @given(a=mask_cases(), dh=st.integers(1, 12),
        strategy=st.sampled_from(STRATEGIES),
-       backend=st.sampled_from(("pallas_ell", "pallas_bcsr")))
+       backend=st.sampled_from(sorted(FUSED)))
 def test_gradient_matches_ref_backend(a, dh, strategy, backend):
     q, k, v = _qkv(a, dh, dh, seed=dh + 1)
     vals = jnp.asarray(a.vals)
@@ -117,7 +121,7 @@ def test_gradient_matches_ref_backend(a, dh, strategy, backend):
         return jax.grad(f, argnums=(0, 1, 2, 3))(vals, q, k, v)
 
     gf = grad_of(compile_sparse_attention(
-        a, dh, strategy=strategy, backend=backend, interpret=True,
+        a, dh, strategy=strategy, **FUSED[backend], interpret=True,
         cache=JitCache()))
     gr = grad_of(compile_sparse_attention(
         a, dh, strategy=strategy, backend="ref", cache=JitCache()))
@@ -129,7 +133,7 @@ def test_gradient_matches_ref_backend(a, dh, strategy, backend):
 @settings(max_examples=8, deadline=None)
 @given(a=mask_cases(), dh=st.integers(1, 12),
        strategy=st.sampled_from(STRATEGIES),
-       backend=st.sampled_from(("pallas_ell", "pallas_bcsr")),
+       backend=st.sampled_from(sorted(FUSED)),
        staging=st.sampled_from(("resident", "dma")),
        chips=st.integers(1, 4))
 def test_staged_sharded_bit_matches_resident_single(a, dh, strategy,
@@ -138,9 +142,9 @@ def test_staged_sharded_bit_matches_resident_single(a, dh, strategy,
     chips = min(chips, N_DEV)
     q, k, v = _qkv(a, dh, dh, seed=dh + 2)
     y0 = sparse_attention(a, q, k, v, strategy=strategy,
-                          backend=backend, interpret=True,
+                          **FUSED[backend], interpret=True,
                           staging="resident", cache=JitCache())
-    y = sparse_attention(a, q, k, v, strategy=strategy, backend=backend,
+    y = sparse_attention(a, q, k, v, strategy=strategy, **FUSED[backend],
                          interpret=True, staging=staging, n_chips=chips,
                          cache=JitCache())
     assert np.array_equal(np.asarray(y0), np.asarray(y))

@@ -27,6 +27,10 @@ from repro.core.plan import build_workspace
 from repro.kernels import ops
 
 
+# the fused backend with every block-row tagged VPU (ELL gather+FMA)
+VPU = dict(backend="pallas_bcsr", mxu_gain=0.0)
+
+
 @pytest.fixture
 def a():
     return random_csr(48, 40, density=0.08, family="powerlaw", seed=7)
@@ -42,7 +46,7 @@ def _const_timer(compiled, vals, x):
 
 def test_constant_timer_picks_best_predicted(a):
     compiled, res = autotune_spmm_with_result(
-        a, 4, backend="pallas_ell", interpret=True,
+        a, 4, **VPU, interpret=True,
         measure=_const_timer, cache=JitCache())
     best_pred = min(res.measured_s, key=lambda c: res.predicted_s[c])
     assert res.config == best_pred
@@ -62,7 +66,7 @@ def test_rigged_timer_overrides_prediction(a):
     # rig: make the LAST finalist (worst predicted among finalists)
     # measure fastest.  Identify it via a probe run's finalist set.
     _, probe_res = autotune_spmm_with_result(
-        a, 4, backend="pallas_ell", interpret=True,
+        a, 4, **VPU, interpret=True,
         measure=_const_timer, cache=JitCache())
     finalists = sorted(probe_res.measured_s,
                        key=lambda c: probe_res.predicted_s[c])
@@ -76,7 +80,7 @@ def test_rigged_timer_overrides_prediction(a):
         return 0.5 if len(calls) == len(finalists) else 2.0
 
     _, res = autotune_spmm_with_result(
-        a, 4, backend="pallas_ell", interpret=True, measure=rigged,
+        a, 4, **VPU, interpret=True, measure=rigged,
         cache=cache)
     assert res.config == target
     assert res.best_measured_s == 0.5
@@ -84,10 +88,10 @@ def test_rigged_timer_overrides_prediction(a):
 
 def test_memoization_second_compile_is_pure_hit(a):
     cache = JitCache()
-    c1 = compile_spmm(a, 4, backend="pallas_ell", interpret=True,
+    c1 = compile_spmm(a, 4, **VPU, interpret=True,
                       autotune=True, measure=_const_timer, cache=cache)
     s1 = cache.stats()
-    c2 = compile_spmm(a, 4, backend="pallas_ell", interpret=True,
+    c2 = compile_spmm(a, 4, **VPU, interpret=True,
                       autotune=True, measure=_const_timer, cache=cache)
     s2 = cache.stats()
     assert c2 is c1                       # same memoized artifact
@@ -100,7 +104,7 @@ def test_spmm_autotune_matches_ref(a):
     x = jnp.asarray(
         np.random.default_rng(0).standard_normal((a.n, 4)), jnp.float32)
     y_ref = spmm(a, x, backend="ref", cache=JitCache())
-    y = spmm(a, x, backend="pallas_ell", interpret=True, autotune=True,
+    y = spmm(a, x, **VPU, interpret=True, autotune=True,
              measure=_const_timer, cache=JitCache())
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                rtol=1e-4, atol=1e-4)
@@ -108,7 +112,7 @@ def test_spmm_autotune_matches_ref(a):
 
 def test_autotune_records_tune_seconds(a):
     ops.reset_dispatch_counts()
-    autotune_spmm(a, 4, backend="pallas_ell", interpret=True,
+    autotune_spmm(a, 4, **VPU, interpret=True,
                   measure=_const_timer, cache=JitCache())
     assert ops.BUILD_SECONDS["tune"] > 0
     assert ops.BUILD_SECONDS["plan"] > 0
@@ -120,7 +124,7 @@ def test_autotune_rejects_untunable_backend(a):
         autotune_spmm(a, 4, backend="ref", interpret=True,
                       cache=JitCache())
     with pytest.raises(ValueError, match="at least one candidate"):
-        autotune_spmm(a, 4, backend="pallas_ell", interpret=True,
+        autotune_spmm(a, 4, **VPU, interpret=True,
                       candidates=[], cache=JitCache())
 
 
@@ -137,15 +141,15 @@ def test_predict_seconds_rewards_merging(a):
     """The analytic model's per-trip overhead term makes a CGCM-merged
     plan rank at or above the unmerged plan of the same strategy on a
     powerlaw instance (fewer grid steps, same streamed bytes)."""
-    c0 = TuneConfig(merge_threshold=0)
-    c1 = TuneConfig(merge_threshold=32)
+    c0 = TuneConfig(merge_threshold=0, mxu_gain=0.0)
+    c1 = TuneConfig(merge_threshold=32, mxu_gain=0.0)
     p0 = predict_seconds(a, 4, c0)
     p1 = predict_seconds(a, 4, c1)
     assert p0 > 0 and p1 > 0
     ws0 = build_workspace(a.row_ptr, a.col_indices, a.shape, 4,
-                          merge_threshold=0)
+                          mxu_gain=0.0, merge_threshold=0)
     ws1 = build_workspace(a.row_ptr, a.col_indices, a.shape, 4,
-                          merge_threshold=32)
+                          mxu_gain=0.0, merge_threshold=32)
     assert ws1.num_trips < ws0.num_blocks
     assert p1 < p0
     # the saving is dominated by the per-trip term (the streamed-bytes
@@ -199,10 +203,10 @@ def test_cache_bounded_autotune_evicts_but_stays_correct(a):
     """A tiny cache forces the tune result itself out; the search just
     reruns (correctness never depends on residency)."""
     cache = JitCache(capacity=2)
-    c1 = autotune_spmm(a, 4, backend="pallas_ell", interpret=True,
+    c1 = autotune_spmm(a, 4, **VPU, interpret=True,
                        measure=_const_timer, cache=cache)
     assert cache.stats()["evictions"] > 0
-    c2 = autotune_spmm(a, 4, backend="pallas_ell", interpret=True,
+    c2 = autotune_spmm(a, 4, **VPU, interpret=True,
                        measure=_const_timer, cache=cache)
     x = jnp.asarray(
         np.random.default_rng(1).standard_normal((a.n, 4)), jnp.float32)

@@ -15,7 +15,7 @@ from benchmarks.common import (CALIB_BENCH, bench_record,
                                load_bench_json, write_bench_json)
 
 
-def _rec(bench="fused_ell", strategy="nnz_split", backend="pallas_ell",
+def _rec(bench="fused_ell", strategy="nnz_split", backend="pallas_bcsr",
          n_chips=0, wall_ms=1.0, dispatches=1.0):
     return bench_record(bench, strategy, backend, n_chips, wall_ms,
                         dispatches)
@@ -136,13 +136,13 @@ def test_diff_table_verdicts_match_the_gate():
     rows = {line.split("|")[1].strip(): line
             for line in table.splitlines() if line.startswith("| `")}
     assert len(rows) == 5
-    assert "REGRESSION" in rows["`fused_ell/nnz_split/pallas_ell/0`"]
-    assert "coverage" in rows["`gone/nnz_split/pallas_ell/0`"]
-    assert "new" in rows["`brand_new/nnz_split/pallas_ell/0`"]
-    assert "OK" in rows["`fused_mixed/nnz_split/pallas_ell/0`"]
+    assert "REGRESSION" in rows["`fused_ell/nnz_split/pallas_bcsr/0`"]
+    assert "coverage" in rows["`gone/nnz_split/pallas_bcsr/0`"]
+    assert "new" in rows["`brand_new/nnz_split/pallas_bcsr/0`"]
+    assert "OK" in rows["`fused_mixed/nnz_split/pallas_bcsr/0`"]
     assert "calib" in rows["`calib/-/dense/0`"]
     # the wall ratio column is machine-scale normalized: 10x shows 10.00
-    assert "| 10.00 |" in rows["`fused_ell/nnz_split/pallas_ell/0`"]
+    assert "| 10.00 |" in rows["`fused_ell/nnz_split/pallas_bcsr/0`"]
 
 
 def test_diff_table_scale_relaxes_ratio():
@@ -161,14 +161,15 @@ def test_diff_table_scale_relaxes_ratio():
 
 def test_checked_in_baseline_is_valid():
     """The baseline CI gates on must stay schema-valid and cover the
-    fused hot-path cells (both execution units, sharded + not)."""
+    fused hot-path cells (staged + not, sharded + not, merged, tuned)."""
     from pathlib import Path
     baseline = load_bench_json(
         Path(__file__).resolve().parents[1] / "BENCH_baseline.json")
     benches = {r["bench"] for r in baseline}
-    assert {"calib", "fused_ell", "fused_mixed", "fused_ell_sharded",
-            "fused_mixed_sharded", "codegen_plan", "attn_fused",
-            "attn_fused_dma", "attn_fused_sharded",
-            "attn_fused_skew_merged"} <= benches
+    assert {"calib", "fused_mixed", "fused_mixed_sharded",
+            "fused_mixed_dma", "fused_mixed_dma_sharded",
+            "fused_mixed_xshard", "fused_mixed_dma_xshard",
+            "fused_mixed_merged", "fused_mixed_tuned", "codegen_plan",
+            "attn_fused", "attn_fused_dma"} <= benches
     backends = {r["backend"] for r in baseline}
-    assert {"pallas_ell", "pallas_bcsr"} <= backends
+    assert "pallas_bcsr" in backends

@@ -1,11 +1,14 @@
-"""Fused ELL hot path + jit-cache correctness (deterministic; no
-hypothesis needed — this is the tier-1 safety net for the serving path).
+"""Fused ELL (all-VPU) hot path + jit-cache correctness (deterministic;
+no hypothesis needed — this is the tier-1 safety net for the serving
+path).  The all-VPU plan is the fused backend tagged with
+``mxu_gain=0``: every block-row an ELL gather+FMA trip, the layout the
+Pokec cell runs on the chip.
 
-Covers the PR's acceptance criteria:
+Covers:
   * exactly ONE pallas dispatch per (matrix, d) instance, whatever the
     segment count (the paper's one-artifact-per-instance claim),
-  * fused pallas_ell == ref backend on all three strategies, including
-    a guaranteed multi-segment nnz_split plan,
+  * the fused all-VPU plan == ref backend on all three strategies,
+    including a guaranteed multi-segment nnz_split plan,
   * interpret is part of every jit-cache key,
   * GLOBAL_CACHE-style concurrent access builds each key exactly once.
 """
@@ -16,12 +19,17 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import CSRMatrix, compile_spmm, random_csr, spmm
+from repro.core import (CSRMatrix, autotune_spmm, compile_batched_spmm,
+                        compile_sparse_attention, compile_spmm, random_csr,
+                        spmm)
 from repro.core.jit_cache import JitCache
-from repro.core.plan import build_fused_workspace, build_plan
+from repro.core.plan import (build_fused_workspace, build_mixed_plan,
+                             build_plan)
 from repro.kernels import ops
 
 STRATEGIES = ("row_split", "nnz_split", "merge_split")
+# the fused backend with every block-row tagged VPU
+VPU = dict(backend="pallas_bcsr", mxu_gain=0.0)
 
 
 def _skewed_csr(seed=0):
@@ -47,14 +55,15 @@ def _x(n, d, seed=1):
 def test_single_dispatch_regardless_of_segment_count(strategy):
     a = _skewed_csr()
     x = _x(a.n, 16)
-    c = compile_spmm(a, 16, strategy=strategy, backend="pallas_ell",
-                     interpret=True, cache=JitCache())
+    c = compile_spmm(a, 16, strategy=strategy, interpret=True,
+                     cache=JitCache(), **VPU)
     ops.reset_dispatch_counts()
     c(jnp.asarray(a.vals), x)
-    assert ops.DISPATCH_COUNTS["ell_fused"] == 1
-    assert ops.DISPATCH_COUNTS["ell_segment"] == 0
+    assert ops.DISPATCH_COUNTS["bcsr_fused"] == 1
+    assert not c.mixed_plan.mxu_rows
     if strategy == "nnz_split":
-        assert len(c.plan.segments) > 1      # the claim is non-trivial
+        # the claim is non-trivial
+        assert len(c.mixed_plan.vpu.segments) > 1
 
 
 @pytest.mark.parametrize("strategy", STRATEGIES)
@@ -62,8 +71,8 @@ def test_fused_matches_ref_backend(strategy):
     a = _skewed_csr(seed=3)
     x = _x(a.n, 20, seed=4)
     y_ref = spmm(a, x, strategy=strategy, backend="ref", cache=JitCache())
-    y = spmm(a, x, strategy=strategy, backend="pallas_ell",
-             interpret=True, cache=JitCache())
+    y = spmm(a, x, strategy=strategy, interpret=True, cache=JitCache(),
+             **VPU)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                rtol=1e-4, atol=1e-4)
 
@@ -78,8 +87,8 @@ def test_fused_multi_segment_nnz_split_regression():
     x = _x(a.n, 16, seed=8)
     y_ref = spmm(a, x, strategy="nnz_split", backend="ref",
                  cache=JitCache())
-    y = spmm(a, x, strategy="nnz_split", backend="pallas_ell",
-             interpret=True, cache=JitCache())
+    y = spmm(a, x, strategy="nnz_split", interpret=True, cache=JitCache(),
+             **VPU)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                rtol=1e-4, atol=1e-4)
 
@@ -87,8 +96,8 @@ def test_fused_multi_segment_nnz_split_regression():
 def test_fused_workspace_descriptor_invariants():
     a = random_csr(50, 60, density=0.1, family="powerlaw", seed=2)
     for strategy in STRATEGIES:
-        plan = build_plan(a.row_ptr, a.col_indices, a.shape, 16,
-                          strategy=strategy)
+        plan = build_mixed_plan(a.row_ptr, a.col_indices, a.shape, 16,
+                                strategy=strategy, mxu_gain=0.0)
         ws = build_fused_workspace(plan)
         bm = plan.row_block
         assert ws.ws_rows == ws.num_blocks * bm
@@ -115,8 +124,8 @@ def test_fused_gradients_match_dense():
     a = _skewed_csr(seed=5)
     d = 12
     x = _x(a.n, d, seed=6)
-    c = compile_spmm(a, d, strategy="nnz_split", backend="pallas_ell",
-                     interpret=True, cache=JitCache())
+    c = compile_spmm(a, d, strategy="nnz_split", interpret=True,
+                     cache=JitCache(), **VPU)
     vals = jnp.asarray(a.vals)
 
     def loss(v, xx):
@@ -141,18 +150,37 @@ def test_cache_key_distinguishes_interpret():
     for interpret=False calls (and vice versa)."""
     a = random_csr(16, 16, density=0.2, family="uniform", seed=9)
     cache = JitCache()
-    c1 = compile_spmm(a, 8, backend="pallas_ell", interpret=True,
-                      cache=cache)
-    c2 = compile_spmm(a, 8, backend="pallas_ell", interpret=False,
-                      cache=cache)
+    c1 = compile_spmm(a, 8, interpret=True, cache=cache, **VPU)
+    c2 = compile_spmm(a, 8, interpret=False, cache=cache, **VPU)
     assert c1 is not c2
     assert c1.interpret is True and c2.interpret is False
     assert cache.stats()["entries"] == 2
     # and the default (None) resolves to a concrete flag that hits one
     # of the two entries rather than minting a third artifact
-    c3 = compile_spmm(a, 8, backend="pallas_ell", cache=cache)
+    c3 = compile_spmm(a, 8, cache=cache, **VPU)
     assert c3 is (c1 if c3.interpret else c2)
     assert cache.stats()["entries"] == 2
+
+
+BUILDERS = {
+    "compile_spmm": lambda a, **kw: compile_spmm(a, 8, **kw),
+    "compile_batched_spmm": lambda a, **kw: compile_batched_spmm(
+        [a], 8, **kw),
+    "compile_sparse_attention": lambda a, **kw: compile_sparse_attention(
+        a, 8, **kw),
+    "autotune_spmm": lambda a, **kw: autotune_spmm(a, 8, **kw),
+}
+
+
+@pytest.mark.parametrize("build", BUILDERS)
+def test_unknown_backend_is_rejected_at_build(build):
+    """A backend name outside BACKENDS fails when the artifact is built,
+    with the names there are, not at its first call."""
+    a = random_csr(16, 16, density=0.2, family="uniform", seed=4)
+    with pytest.raises(ValueError,
+                       match="backend must be one of .*pallas_bcsr"):
+        BUILDERS[build](a, backend="pallas_ell", interpret=True,
+                        cache=JitCache())
 
 
 def test_jit_cache_single_flight_under_threads():

@@ -4,7 +4,8 @@ Hypothesis generates adversarial CSR structures — skewed, empty-row,
 single-row, power-law degree — crossed with strategy and d, and asserts
 the end-to-end oracles the deterministic suites spot-check:
 
-  * fused pallas_ell == ref backend (allclose, f32 accumulate),
+  * the fused all-VPU plan (mxu_gain=0) == ref backend (allclose, f32
+    accumulate),
   * sharded fused == unsharded fused BIT-identical (same per-row
     accumulation order; sharding must be a pure re-partitioning),
   * DMA-staged fused == resident fused BIT-identical across backends,
@@ -34,6 +35,10 @@ from repro.core.plan import (LANE, MAX_MERGE_WIDTH, MXU_TAG, STRATEGIES,
                              choose_merge_width)
 
 N_DEV = len(jax.devices())
+# the fused backend with every block-row tagged VPU, and as the
+# structure tags it; sampled by key
+FUSED = {"vpu": dict(backend="pallas_bcsr", mxu_gain=0.0),
+         "pallas_bcsr": dict(backend="pallas_bcsr")}
 
 
 def _csr_from_lengths(lengths, n, seed):
@@ -81,7 +86,7 @@ def test_fused_matches_ref(a, d, strategy):
     x = jnp.asarray(
         np.random.default_rng(d).standard_normal((a.n, d)), jnp.float32)
     y_ref = spmm(a, x, strategy=strategy, backend="ref", cache=JitCache())
-    y = spmm(a, x, strategy=strategy, backend="pallas_ell",
+    y = spmm(a, x, strategy=strategy, **FUSED["vpu"],
              interpret=True, cache=JitCache())
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                rtol=1e-4, atol=1e-4)
@@ -96,9 +101,9 @@ def test_sharded_bit_matches_fused(a, d, strategy, chips):
     x = jnp.asarray(
         np.random.default_rng(d + 1).standard_normal((a.n, d)),
         jnp.float32)
-    y0 = spmm(a, x, strategy=strategy, backend="pallas_ell",
+    y0 = spmm(a, x, strategy=strategy, **FUSED["vpu"],
               interpret=True, cache=JitCache())
-    y = spmm(a, x, strategy=strategy, backend="pallas_ell",
+    y = spmm(a, x, strategy=strategy, **FUSED["vpu"],
              interpret=True, n_chips=chips, cache=JitCache())
     assert np.array_equal(np.asarray(y), np.asarray(y0))
 
@@ -139,7 +144,7 @@ def test_sharded_mixed_bit_matches_fused(a, d, strategy, chips):
 @settings(max_examples=8, deadline=None)
 @given(a=csr_cases(), d=st.integers(1, 24),
        strategy=st.sampled_from(STRATEGIES),
-       backend=st.sampled_from(("pallas_ell", "pallas_bcsr")))
+       backend=st.sampled_from(sorted(FUSED)))
 def test_staged_bit_matches_resident(a, d, strategy, backend):
     """staging="dma" re-stages operands through double-buffered panel
     DMA but must reproduce the resident lowering BIT-for-bit on every
@@ -147,9 +152,9 @@ def test_staged_bit_matches_resident(a, d, strategy, backend):
     x = jnp.asarray(
         np.random.default_rng(d + 4).standard_normal((a.n, d)),
         jnp.float32)
-    y_res = spmm(a, x, strategy=strategy, backend=backend,
+    y_res = spmm(a, x, strategy=strategy, **FUSED[backend],
                  interpret=True, staging="resident", cache=JitCache())
-    y_dma = spmm(a, x, strategy=strategy, backend=backend,
+    y_dma = spmm(a, x, strategy=strategy, **FUSED[backend],
                  interpret=True, staging="dma", cache=JitCache())
     assert np.array_equal(np.asarray(y_dma), np.asarray(y_res))
 
@@ -157,7 +162,7 @@ def test_staged_bit_matches_resident(a, d, strategy, backend):
 @settings(max_examples=8, deadline=None)
 @given(a=csr_cases(), d=st.integers(1, 16),
        strategy=st.sampled_from(STRATEGIES),
-       backend=st.sampled_from(("pallas_ell", "pallas_bcsr")),
+       backend=st.sampled_from(sorted(FUSED)),
        chips=st.integers(1, 4))
 def test_staged_sharded_bit_matches_resident_sharded(a, d, strategy,
                                                      backend, chips):
@@ -165,10 +170,10 @@ def test_staged_sharded_bit_matches_resident_sharded(a, d, strategy,
     x = jnp.asarray(
         np.random.default_rng(d + 5).standard_normal((a.n, d)),
         jnp.float32)
-    y_res = spmm(a, x, strategy=strategy, backend=backend,
+    y_res = spmm(a, x, strategy=strategy, **FUSED[backend],
                  interpret=True, staging="resident", n_chips=chips,
                  cache=JitCache())
-    y_dma = spmm(a, x, strategy=strategy, backend=backend,
+    y_dma = spmm(a, x, strategy=strategy, **FUSED[backend],
                  interpret=True, staging="dma", n_chips=chips,
                  cache=JitCache())
     assert np.array_equal(np.asarray(y_dma), np.asarray(y_res))
@@ -177,7 +182,7 @@ def test_staged_sharded_bit_matches_resident_sharded(a, d, strategy,
 @settings(max_examples=8, deadline=None)
 @given(a=csr_cases(), d=st.integers(1, 16),
        strategy=st.sampled_from(STRATEGIES),
-       backend=st.sampled_from(("pallas_ell", "pallas_bcsr")),
+       backend=st.sampled_from(sorted(FUSED)),
        staging=st.sampled_from(("resident", "dma")),
        chips=st.integers(1, 4))
 def test_xshard_bit_matches_replicated(a, d, strategy, backend, staging,
@@ -190,10 +195,10 @@ def test_xshard_bit_matches_replicated(a, d, strategy, backend, staging,
     x = jnp.asarray(
         np.random.default_rng(d + 6).standard_normal((a.n, d)),
         jnp.float32)
-    y_rep = spmm(a, x, strategy=strategy, backend=backend,
+    y_rep = spmm(a, x, strategy=strategy, **FUSED[backend],
                  interpret=True, staging=staging, n_chips=chips,
                  x_sharding="replicated", cache=JitCache())
-    y_row = spmm(a, x, strategy=strategy, backend=backend,
+    y_row = spmm(a, x, strategy=strategy, **FUSED[backend],
                  interpret=True, staging=staging, n_chips=chips,
                  x_sharding="rows", cache=JitCache())
     assert np.array_equal(np.asarray(y_row), np.asarray(y_rep))
@@ -210,7 +215,7 @@ def test_xshard_fetch_table_invariants(a, d, strategy, chips):
     compact local workspace."""
     ws = build_sharded_workspace(a.row_ptr, a.col_indices, a.shape, d,
                                  n_chips=chips, strategy=strategy,
-                                 x_sharding="rows")
+                                 mxu_gain=0.0, x_sharding="rows")
     assert ws.x_panels == max(-(-a.n // ws.bk), 1)
     assert ws.x_own_panels * ws.n_chips >= ws.x_panels
     T = ws.x_local_panels
@@ -243,7 +248,8 @@ def test_sharded_workspace_invariants(a, d, strategy, chips):
     row coverage is a bijection, efficiency stays in (0, 1], and the
     per-chip descriptor tables tile their real slots contiguously."""
     ws = build_sharded_workspace(a.row_ptr, a.col_indices, a.shape, d,
-                                 n_chips=chips, strategy=strategy)
+                                 n_chips=chips, strategy=strategy,
+                                 mxu_gain=0.0)
     assert ws.nnz == a.nnz
     if a.nnz:
         assert 0 < ws.efficiency <= 1
@@ -284,7 +290,7 @@ def test_sharded_workspace_invariants(a, d, strategy, chips):
 @settings(max_examples=8, deadline=None)
 @given(a=csr_cases(), d=st.integers(1, 16),
        strategy=st.sampled_from(STRATEGIES),
-       backend=st.sampled_from(("pallas_ell", "pallas_bcsr")),
+       backend=st.sampled_from(sorted(FUSED)),
        staging=st.sampled_from(("resident", "dma")),
        chips=st.integers(1, 4))
 def test_merged_bit_matches_unmerged(a, d, strategy, backend, staging,
@@ -293,10 +299,10 @@ def test_merged_bit_matches_unmerged(a, d, strategy, backend, staging,
     x = jnp.asarray(
         np.random.default_rng(d + 7).standard_normal((a.n, d)),
         jnp.float32)
-    y0 = spmm(a, x, strategy=strategy, backend=backend, interpret=True,
+    y0 = spmm(a, x, strategy=strategy, **FUSED[backend], interpret=True,
               staging=staging, n_chips=chips, merge_threshold=0,
               cache=JitCache())
-    y1 = spmm(a, x, strategy=strategy, backend=backend, interpret=True,
+    y1 = spmm(a, x, strategy=strategy, **FUSED[backend], interpret=True,
               staging=staging, n_chips=chips, merge_threshold=16,
               cache=JitCache())
     assert np.array_equal(np.asarray(y1), np.asarray(y0))
@@ -305,7 +311,7 @@ def test_merged_bit_matches_unmerged(a, d, strategy, backend, staging,
 @settings(max_examples=6, deadline=None)
 @given(a=csr_cases(), d=st.integers(1, 8),
        strategy=st.sampled_from(STRATEGIES),
-       backend=st.sampled_from(("pallas_ell", "pallas_bcsr")))
+       backend=st.sampled_from(sorted(FUSED)))
 def test_merged_gradient_bit_matches_unmerged(a, d, strategy, backend):
     """The custom-VJP backward runs through the same fused dispatch, so
     merging must not perturb a gradient bit either."""
@@ -315,7 +321,7 @@ def test_merged_gradient_bit_matches_unmerged(a, d, strategy, backend):
     vals = jnp.asarray(a.vals)
     grads = []
     for threshold in (0, 16):
-        c = compile_spmm(a, d, strategy=strategy, backend=backend,
+        c = compile_spmm(a, d, strategy=strategy, **FUSED[backend],
                          interpret=True, merge_threshold=threshold,
                          cache=JitCache())
 
@@ -327,14 +333,15 @@ def test_merged_gradient_bit_matches_unmerged(a, d, strategy, backend):
     assert np.array_equal(np.asarray(grads[0][1]), np.asarray(grads[1][1]))
 
 
-@pytest.mark.parametrize("backend", ["pallas_ell", "pallas_bcsr", "ref"])
+@pytest.mark.parametrize("backend", [*FUSED, "ref"])
 def test_gradient_of_a_matrix_without_nonzeros(backend):
     """The shrunk example hypothesis found for the property above: a
     (1, 1) matrix with no nonzero.  The chunked SDDMM must not divide
     its zero nonzeros into chunks of size zero."""
     a = CSRMatrix((1, 1), np.zeros(2, np.int64), np.zeros(0, np.int32),
                   np.zeros(0, np.float32))
-    c = compile_spmm(a, 1, strategy="row_split", backend=backend,
+    c = compile_spmm(a, 1, strategy="row_split",
+                     **FUSED.get(backend, dict(backend=backend)),
                      interpret=True, cache=JitCache())
     dvals, dx = jax.grad(lambda v, xx: jnp.sum(c(v, xx) ** 2),
                          argnums=(0, 1))(jnp.zeros((0,), jnp.float32),
@@ -356,7 +363,8 @@ def test_merged_workspace_invariants(a, d, strategy, mixed, threshold):
     extents and stay in bounds, and W == 1 is byte-identical to the
     unmerged packer."""
     ws = build_workspace(a.row_ptr, a.col_indices, a.shape, d,
-                         strategy=strategy, mixed=mixed,
+                         strategy=strategy,
+                         mxu_gain=4.0 if mixed else 0.0,
                          merge_threshold=threshold)
     W = ws.merge_width
     assert 1 <= W <= MAX_MERGE_WIDTH and (W & (W - 1)) == 0
@@ -385,7 +393,8 @@ def test_merged_workspace_invariants(a, d, strategy, mixed, threshold):
     # the unmerged build's descriptors, in order: CGCM only inserts
     # inert zero-trip pads (to fill a trip, or before a tag change)
     ws0 = build_workspace(a.row_ptr, a.col_indices, a.shape, d,
-                          strategy=strategy, mixed=mixed,
+                          strategy=strategy,
+                          mxu_gain=4.0 if mixed else 0.0,
                           merge_threshold=0)
     real = ws.blk_L > 0
     assert int(real.sum()) == ws0.num_blocks
@@ -420,7 +429,7 @@ def test_sharded_merged_workspace_invariants(a, d, strategy, chips,
     staged windows sized to merged trips and still in bounds."""
     ws = build_sharded_workspace(a.row_ptr, a.col_indices, a.shape, d,
                                  n_chips=chips, strategy=strategy,
-                                 merge_threshold=threshold)
+                                 mxu_gain=0.0, merge_threshold=threshold)
     W = ws.merge_width
     assert 1 <= W <= MAX_MERGE_WIDTH and (W & (W - 1)) == 0
     assert W == choose_merge_width(a.row_ptr, row_block=ws.row_block,

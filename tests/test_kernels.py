@@ -11,6 +11,8 @@ from repro.kernels.ref import sddmm_ref, spmm_csr_ref, spmm_dense_ref
 
 FAMILIES = ("uniform", "powerlaw", "banded")
 STRATEGIES = ("row_split", "nnz_split", "merge_split")
+# the fused backend with every block-row tagged VPU (ELL gather+FMA)
+VPU = dict(backend="pallas_bcsr", mxu_gain=0.0)
 
 
 def _case(m, n, d, family, seed, dtype=jnp.float32, density=0.15):
@@ -23,32 +25,32 @@ def _case(m, n, d, family, seed, dtype=jnp.float32, density=0.15):
 
 @pytest.mark.parametrize("family", FAMILIES)
 @pytest.mark.parametrize("strategy", STRATEGIES)
-def test_pallas_ell_matches_oracle(family, strategy):
+def test_pallas_vpu_matches_oracle(family, strategy):
     a, x = _case(33, 47, 20, family, seed=hash((family, strategy)) % 1000)
     y_ref = spmm_dense_ref(a.to_dense(), x)
-    y = spmm(a, x, strategy=strategy, backend="pallas_ell", interpret=True,
-             cache=JitCache())
+    y = spmm(a, x, strategy=strategy, interpret=True, cache=JitCache(),
+             **VPU)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                rtol=1e-4, atol=1e-4)
 
 
 @pytest.mark.parametrize("shape", [(8, 8, 4), (16, 64, 8), (64, 16, 45),
                                    (40, 40, 128), (7, 130, 16)])
-def test_pallas_ell_shape_sweep(shape):
+def test_pallas_vpu_shape_sweep(shape):
     m, n, d = shape
     a, x = _case(m, n, d, "uniform", seed=m * 7 + d)
     y_ref = spmm_dense_ref(a.to_dense(), x)
-    y = spmm(a, x, backend="pallas_ell", interpret=True, cache=JitCache())
+    y = spmm(a, x, interpret=True, cache=JitCache(), **VPU)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                rtol=1e-4, atol=1e-4)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_pallas_ell_dtypes(dtype):
+def test_pallas_vpu_dtypes(dtype):
     a, x = _case(24, 32, 16, "powerlaw", seed=5, dtype=dtype)
     y_ref = spmm_dense_ref(a.to_dense().astype(jnp.float32),
                            x.astype(jnp.float32))
-    y = spmm(a, x, backend="pallas_ell", interpret=True, cache=JitCache())
+    y = spmm(a, x, interpret=True, cache=JitCache(), **VPU)
     tol = 1e-4 if dtype == jnp.float32 else 5e-2
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                rtol=tol, atol=tol)
@@ -74,12 +76,14 @@ def test_empty_rows_and_dense_row():
                     jnp.float32)
     y_ref = spmm_dense_ref(jnp.asarray(dense), x)
     for strategy in STRATEGIES:
-        for backend in ("pallas_ell", "pallas_bcsr", "ref"):
-            y = spmm(a, x, strategy=strategy, backend=backend,
-                     interpret=True, cache=JitCache())
+        for name, kw in (("vpu", VPU),
+                         ("pallas_bcsr", {"backend": "pallas_bcsr"}),
+                         ("ref", {"backend": "ref"})):
+            y = spmm(a, x, strategy=strategy, interpret=True,
+                     cache=JitCache(), **kw)
             np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                        rtol=1e-4, atol=1e-4,
-                                       err_msg=f"{strategy}/{backend}")
+                                       err_msg=f"{strategy}/{name}")
 
 
 def test_gradients_match_dense():
@@ -125,13 +129,16 @@ def test_ell_segment_ref_matches_csr_ref():
 
 @pytest.mark.parametrize("shape", [(12, 18, 9), (40, 33, 45), (8, 8, 128)])
 def test_sddmm_pallas_matches_ref(shape):
-    from repro.kernels.sddmm import sddmm_csr
+    """The gradient's SDDMM (dvals = <dY[row], X[col]>) of a fused
+    artifact, pulled back through jax.grad, against the oracle."""
     m, n, d = shape
     a, _ = _case(m, n, 4, "powerlaw", seed=m + d)
     rng = np.random.default_rng(0)
     dy = jnp.asarray(rng.standard_normal((m, d)), jnp.float32)
     x = jnp.asarray(rng.standard_normal((n, d)), jnp.float32)
-    got = sddmm_csr(a, dy, x, T=8, interpret=True)
+    c = compile_spmm(a, d, backend="pallas_bcsr", interpret=True,
+                     cache=JitCache())
+    got = jax.grad(lambda v: jnp.sum(c(v, x) * dy))(jnp.asarray(a.vals))
     want = sddmm_ref(a.row_ptr, a.col_indices, dy, x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-4)
@@ -145,7 +152,7 @@ def test_kernels_public_exports_importable():
     for name in kernels.__all__:
         assert getattr(kernels, name, None) is not None, name
     from repro.kernels.ops import (  # noqa: F401
-        DISPATCH_COUNTS, default_interpret, reset_dispatch_counts,
-        resolve_interpret, spmm_bcsr_op, spmm_ell_fused_op,
-        spmm_ell_fused_sharded_op, spmm_ell_segment_op)
-    assert "spmm_ell_fused_sharded" in kernels.__all__
+        DISPATCH_COUNTS, attn_fused_op, attn_fused_sharded_op,
+        default_interpret, reset_dispatch_counts, resolve_interpret,
+        spmm_bcsr_fused_op, spmm_bcsr_fused_sharded_op)
+    assert "spmm_bcsr_fused_sharded" in kernels.__all__

@@ -1,6 +1,9 @@
 """MoE <-> SpMM integration: the in-jit gather path must agree with the
 concrete-routing JIT-planned SpMM paths on identical routings (the
 first-class integration of the paper's technique, DESIGN.md §4.4)."""
+import functools
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -29,8 +32,14 @@ def _gather_path(tokens, logits, w_up, w_dn, k, C):
     return ms.combine(oe, gates, eids, slots)
 
 
-@pytest.mark.parametrize("backend", ["ref", "pallas_ell", "pallas_bcsr"])
-def test_moe_gather_equals_concrete_spmm(backend):
+@pytest.mark.parametrize("backend", ["ref", "vpu", "pallas_bcsr"])
+def test_moe_gather_equals_concrete_spmm(backend, monkeypatch):
+    if backend == "vpu":
+        # the fused backend with every block-row tagged VPU
+        spmm_mod = importlib.import_module("repro.core.spmm")
+        monkeypatch.setattr(spmm_mod, "spmm", functools.partial(
+            spmm_mod.spmm, mxu_gain=0.0))
+        backend = "pallas_bcsr"
     tokens, logits, w_up, w_dn = _setup()
     y_gather = _gather_path(tokens, logits, w_up, w_dn, 2, 12)
     y_spmm = ms.moe_apply_concrete(tokens, logits, w_up, w_dn, top_k=2,

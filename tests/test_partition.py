@@ -156,7 +156,7 @@ def test_align_sharded_workspace_packs_degenerate_shards():
     unsharded fused dispatch bit-for-bit."""
     a = random_csr(10, 32, density=0.3, family="uniform", seed=7)
     ws = build_sharded_workspace(a.row_ptr, a.col_indices, a.shape, 8,
-                                 n_chips=6, backend="pallas_bcsr")
+                                 n_chips=6)
     assert len(set(ws.inv_perm.tolist())) == a.m
     assert ws.nnz == a.nnz
     x = jnp.asarray(
@@ -177,8 +177,9 @@ def test_n_chips_1_bit_matches_unsharded_fused():
     x = jnp.asarray(
         np.random.default_rng(6).standard_normal((a.n, 20)), jnp.float32)
     for strategy in STRATEGIES:
-        y0 = spmm(a, x, strategy=strategy, backend="pallas_ell",
-                  interpret=True, cache=JitCache())
-        y1 = spmm(a, x, strategy=strategy, backend="pallas_ell",
-                  interpret=True, n_chips=1, cache=JitCache())
+        y0 = spmm(a, x, strategy=strategy, backend="pallas_bcsr",
+                  mxu_gain=0.0, interpret=True, cache=JitCache())
+        y1 = spmm(a, x, strategy=strategy, backend="pallas_bcsr",
+                  mxu_gain=0.0, interpret=True, n_chips=1,
+                  cache=JitCache())
         assert np.array_equal(np.asarray(y0), np.asarray(y1)), strategy

@@ -19,7 +19,10 @@ from repro.launch.serve import (SpmmRequest, SpmmServer, _serve_callables,
                                 d_bucket, generate)
 from repro.models import Model
 
-FUSED = ("pallas_ell", "pallas_bcsr")
+# the fused backend with every block-row tagged VPU, and as the
+# structure tags it; parametrized by key
+FUSED = {"vpu": dict(backend="pallas_bcsr", mxu_gain=0.0),
+         "pallas_bcsr": dict(backend="pallas_bcsr")}
 STAGINGS = ("resident", "dma")
 
 
@@ -58,7 +61,7 @@ def test_batched_bit_identical_to_solo(backend, staging):
     (slot padding, d-bucketing, and the common CGCM width must not
     perturb per-lane accumulation order)."""
     reqs = _tenants()
-    kw = dict(backend=backend, staging=staging, interpret=True,
+    kw = dict(**FUSED[backend], staging=staging, interpret=True,
               max_batch=8, cache=JitCache())
     server = SpmmServer(**kw)
     solo = [server.serve([r])[0] for r in reqs]
@@ -84,9 +87,9 @@ def test_batched_is_one_fused_dispatch(backend):
     time like the sharded twin in test_sharded_fused)."""
     reqs = _tenants()
     compiled = compile_batched_spmm(
-        [r.a for r in reqs], 32, backend=backend, interpret=True,
+        [r.a for r in reqs], 32, **FUSED[backend], interpret=True,
         cache=JitCache())
-    counter = "ell_fused" if backend == "pallas_ell" else "bcsr_fused"
+    counter = "bcsr_fused"
     ops.reset_dispatch_counts()
     ys = compiled([r.a.vals for r in reqs], [r.x for r in reqs])
     assert ops.DISPATCH_COUNTS[counter] == 1
@@ -104,7 +107,8 @@ def test_batched_workspace_uniform_windows():
     which keeps per-member ones)."""
     reqs = _tenants()
     compiled = CompiledBatchedSpmm([r.a for r in reqs], 32,
-                                   backend="pallas_ell", interpret=True)
+                                   backend="pallas_bcsr", mxu_gain=0.0,
+                                   interpret=True)
     bw = compiled.batched_workspace
     R = bw.n_requests
     B = bw.num_blocks // R

@@ -35,6 +35,8 @@ from repro.kernels import ops
 ROOT = Path(__file__).resolve().parents[1]
 N_DEV = len(jax.devices())
 MAX_CHIPS = min(N_DEV, 4)
+# the fused backend with every block-row tagged VPU (ELL gather+FMA)
+VPU = dict(backend="pallas_bcsr", mxu_gain=0.0)
 
 
 def _skewed_csr(seed=0):
@@ -60,9 +62,9 @@ def _x(n, d, seed=1):
 def test_sharded_bit_matches_unsharded(strategy):
     a = _skewed_csr(seed=2)
     x = _x(a.n, 16, seed=3)
-    y0 = spmm(a, x, strategy=strategy, backend="pallas_ell",
+    y0 = spmm(a, x, strategy=strategy, **VPU,
               interpret=True, cache=JitCache())
-    y = spmm(a, x, strategy=strategy, backend="pallas_ell",
+    y = spmm(a, x, strategy=strategy, **VPU,
              interpret=True, n_chips=MAX_CHIPS, cache=JitCache())
     assert np.array_equal(np.asarray(y), np.asarray(y0))
 
@@ -70,16 +72,15 @@ def test_sharded_bit_matches_unsharded(strategy):
 def test_one_dispatch_per_chip_eager():
     a = _skewed_csr(seed=4)
     x = _x(a.n, 16, seed=5)
-    c = compile_spmm(a, 16, strategy="nnz_split", backend="pallas_ell",
+    c = compile_spmm(a, 16, strategy="nnz_split", **VPU,
                      interpret=True, n_chips=MAX_CHIPS, cache=JitCache())
     vals = jnp.asarray(a.vals)
     ops.reset_dispatch_counts()
     c(vals, x)
-    assert ops.DISPATCH_COUNTS["ell_fused"] == MAX_CHIPS
-    assert ops.DISPATCH_COUNTS["ell_fused_sharded"] == 1
-    assert ops.DISPATCH_COUNTS["ell_segment"] == 0
+    assert ops.DISPATCH_COUNTS["bcsr_fused"] == MAX_CHIPS
+    assert ops.DISPATCH_COUNTS["bcsr_fused_sharded"] == 1
     c(vals, x)
-    assert ops.DISPATCH_COUNTS["ell_fused"] == 2 * MAX_CHIPS
+    assert ops.DISPATCH_COUNTS["bcsr_fused"] == 2 * MAX_CHIPS
 
 
 def test_one_dispatch_per_chip_under_jit():
@@ -88,17 +89,17 @@ def test_one_dispatch_per_chip_under_jit():
     built once per instance, not per call)."""
     a = _skewed_csr(seed=6)
     x = _x(a.n, 16, seed=7)
-    c = compile_spmm(a, 16, strategy="nnz_split", backend="pallas_ell",
+    c = compile_spmm(a, 16, strategy="nnz_split", **VPU,
                      interpret=True, n_chips=MAX_CHIPS, cache=JitCache())
     vals = jnp.asarray(a.vals)
     fwd = jax.jit(lambda v, xx: c(v, xx))
     ops.reset_dispatch_counts()
     y = fwd(vals, x)
     jax.block_until_ready(y)
-    assert ops.DISPATCH_COUNTS["ell_fused"] == MAX_CHIPS   # trace-time
+    assert ops.DISPATCH_COUNTS["bcsr_fused"] == MAX_CHIPS   # trace-time
     y2 = fwd(vals, x)
     jax.block_until_ready(y2)
-    assert ops.DISPATCH_COUNTS["ell_fused"] == MAX_CHIPS   # cached exec
+    assert ops.DISPATCH_COUNTS["bcsr_fused"] == MAX_CHIPS   # cached exec
     assert np.array_equal(np.asarray(y), np.asarray(y2))
 
 
@@ -122,7 +123,7 @@ def test_sharded_trace_is_one_pallas_call_inside_shard_map():
     once per chip), with no pallas_call outside it."""
     a = _skewed_csr(seed=10)
     x = _x(a.n, 16, seed=11)
-    c = compile_spmm(a, 16, strategy="nnz_split", backend="pallas_ell",
+    c = compile_spmm(a, 16, strategy="nnz_split", **VPU,
                      interpret=True, n_chips=MAX_CHIPS, cache=JitCache())
     vals = jnp.asarray(a.vals)
     jaxpr = jax.make_jaxpr(lambda v, xx: c(v, xx))(vals, x)
@@ -145,7 +146,7 @@ def test_sharded_gradients_match_dense():
     a = _skewed_csr(seed=8)
     d = 12
     x = _x(a.n, d, seed=9)
-    c = compile_spmm(a, d, strategy="nnz_split", backend="pallas_ell",
+    c = compile_spmm(a, d, strategy="nnz_split", **VPU,
                      interpret=True, n_chips=MAX_CHIPS, cache=JitCache())
     vals = jnp.asarray(a.vals)
 
@@ -169,14 +170,14 @@ def test_sharded_gradients_match_dense():
 def test_cache_key_distinguishes_mesh():
     a = random_csr(16, 16, density=0.2, family="uniform", seed=9)
     cache = JitCache()
-    c0 = compile_spmm(a, 8, backend="pallas_ell", interpret=True,
+    c0 = compile_spmm(a, 8, **VPU, interpret=True,
                       cache=cache)
-    c1 = compile_spmm(a, 8, backend="pallas_ell", interpret=True,
+    c1 = compile_spmm(a, 8, **VPU, interpret=True,
                       n_chips=1, cache=cache)
     assert c0 is not c1                       # unsharded != 1-chip mesh
     assert cache.stats()["entries"] == 2
     # equivalent spellings (n_chips vs explicit mesh) share one artifact
-    c2 = compile_spmm(a, 8, backend="pallas_ell", interpret=True,
+    c2 = compile_spmm(a, 8, **VPU, interpret=True,
                       mesh=chip_mesh(1), cache=cache)
     assert c2 is c1
     assert cache.stats()["entries"] == 2
@@ -205,8 +206,8 @@ def test_sharding_rejects_non_fused_backends(backend):
 
 
 def test_sharding_accepts_bcsr_backend():
-    """Since the BCSR fold-in, the mixed MXU path shards like the ELL
-    path (the PR that closed the 'MXU xor multi-chip' gap)."""
+    """The mixed VPU/MXU plan shards: MXU block-rows and a chip mesh
+    go together."""
     a = random_csr(16, 16, density=0.2, family="uniform", seed=3)
     c = compile_spmm(a, 8, backend="pallas_bcsr", interpret=True,
                      n_chips=1, cache=JitCache())
@@ -214,17 +215,13 @@ def test_sharding_accepts_bcsr_backend():
 
 
 def test_auto_backend_resolves_fused_when_sharded():
-    """backend="auto" + a sharding request must resolve to a FUSED
-    backend on every host — pallas_ell on CPU (via interpret), the
-    mixed pallas_bcsr on TPU — never the single-device ref backend,
-    which would reject the mesh."""
-    from repro.core import FUSED_BACKENDS
+    """backend="auto" + a sharding request must resolve to the fused
+    pallas_bcsr on every host (via interpret off the chip) — never the
+    single-device ref backend, which would reject the mesh."""
     a = _skewed_csr(seed=12)
     x = _x(a.n, 8, seed=13)
     c = compile_spmm(a, 8, backend="auto", n_chips=1, cache=JitCache())
-    assert c.backend in FUSED_BACKENDS and c.n_chips == 1
-    if jax.default_backend() != "tpu":
-        assert c.backend == "pallas_ell"
+    assert c.backend == "pallas_bcsr" and c.n_chips == 1
     y = spmm(a, x, backend="auto", n_chips=1, cache=JitCache())
     y_ref = spmm(a, x, backend="ref", cache=JitCache())
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
@@ -249,12 +246,13 @@ def test_acceptance_on_8_device_mesh():
         x = jnp.asarray(np.random.default_rng(1)
                         .standard_normal((a.n, 20)), jnp.float32)
         for strategy in STRATEGIES:
-            y0 = spmm(a, x, strategy=strategy, backend="pallas_ell",
-                      interpret=True, cache=JitCache())
+            y0 = spmm(a, x, strategy=strategy, backend="pallas_bcsr",
+                      mxu_gain=0.0, interpret=True, cache=JitCache())
             ops.reset_dispatch_counts()
-            y8 = spmm(a, x, strategy=strategy, backend="pallas_ell",
-                      interpret=True, n_chips=8, cache=JitCache())
-            assert ops.DISPATCH_COUNTS["ell_fused"] == 8, strategy
+            y8 = spmm(a, x, strategy=strategy, backend="pallas_bcsr",
+                      mxu_gain=0.0, interpret=True, n_chips=8,
+                      cache=JitCache())
+            assert ops.DISPATCH_COUNTS["bcsr_fused"] == 8, strategy
             assert np.array_equal(np.asarray(y0), np.asarray(y8)), strategy
         print("OK")
     """)

@@ -96,7 +96,8 @@ def _batched_case():
 CASES = {
     "spmm_bcsr": (lambda: _spmm_case("pallas_bcsr"),
                   [("spmm.call", _stages("spmm"))]),
-    "spmm_ell_sharded": (lambda: _spmm_case("pallas_ell", n_chips=1),
+    "spmm_ell_sharded": (lambda: _spmm_case("pallas_bcsr", n_chips=1,
+                                            mxu_gain=0.0),
                          [("spmm.call", _stages("spmm"))]),
     "spmm_ref": (lambda: _spmm_case("ref"), [("spmm.call", [])]),
     "spmm_vjp": (lambda: _spmm_case("pallas_bcsr", grad=True),

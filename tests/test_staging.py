@@ -5,13 +5,13 @@ What staging must preserve — and what this module pins:
 
   * BIT-identity: the staged lowering reorders nothing, it only moves
     operands from resident VMEM buffers to per-block DMA panels, so
-    staged == resident exactly (both backends, all three strategies,
-    single-chip and sharded).
+    staged == resident exactly (all-VPU and mixed plans, all three
+    strategies, single-chip and sharded).
   * the Table IV invariant: still exactly ONE pallas_call per chip per
     forward, asserted via DISPATCH_COUNTS and on the traced jaxpr.
   * specialization identity: the resolved staging mode is part of the
     jit-cache key ("resident" and "dma" artifacts never alias), and
-    "auto" resolves per backend (interpret -> resident, TPU -> dma).
+    "auto" resolves per platform (interpret -> resident, TPU -> dma).
   * workspace metadata: every descriptor's fixed DMA window
     [off, off + max_span) / [coff, coff + max_cspan) stays in bounds.
 """
@@ -27,9 +27,8 @@ import numpy as np
 import pytest
 
 from repro.core import (CSRMatrix, MXU_TAG, build_mixed_plan,
-                        build_fused_workspace, build_plan,
-                        build_sharded_workspace, choose_merge_width,
-                        compile_spmm, random_csr, spmm)
+                        build_fused_workspace, build_sharded_workspace,
+                        choose_merge_width, compile_spmm, random_csr, spmm)
 from repro.core.jit_cache import JitCache
 from repro.core.plan import LANE, STRATEGIES, STAGE_TILE
 from repro.kernels import ops
@@ -39,7 +38,10 @@ ROOT = Path(__file__).resolve().parents[1]
 N_DEV = len(jax.devices())
 MAX_CHIPS = min(N_DEV, 4)
 
-FUSED = ("pallas_ell", "pallas_bcsr")
+# the fused backend with every block-row tagged VPU, and as the
+# structure tags it; parametrized by key
+FUSED = {"vpu": dict(backend="pallas_bcsr", mxu_gain=0.0),
+         "pallas_bcsr": dict(backend="pallas_bcsr")}
 
 
 def _mixed_csr(seed=0, m=48, n=64):
@@ -69,9 +71,9 @@ def _x(n, d, seed=1):
 def test_staged_bit_identical_to_resident(backend, strategy):
     a = _mixed_csr(seed=2)
     x = _x(a.n, 20, seed=3)
-    y_res = spmm(a, x, strategy=strategy, backend=backend,
+    y_res = spmm(a, x, strategy=strategy, **FUSED[backend],
                  interpret=True, staging="resident", cache=JitCache())
-    y_dma = spmm(a, x, strategy=strategy, backend=backend,
+    y_dma = spmm(a, x, strategy=strategy, **FUSED[backend],
                  interpret=True, staging="dma", cache=JitCache())
     assert np.array_equal(np.asarray(y_dma), np.asarray(y_res))
 
@@ -80,9 +82,9 @@ def test_staged_bit_identical_to_resident(backend, strategy):
 def test_staged_bit_identical_on_skewed_powerlaw(backend):
     a = random_csr(120, 96, density=0.06, family="powerlaw", seed=4)
     x = _x(a.n, 24, seed=5)
-    y_res = spmm(a, x, backend=backend, interpret=True,
+    y_res = spmm(a, x, **FUSED[backend], interpret=True,
                  staging="resident", cache=JitCache())
-    y_dma = spmm(a, x, backend=backend, interpret=True,
+    y_dma = spmm(a, x, **FUSED[backend], interpret=True,
                  staging="dma", cache=JitCache())
     assert np.array_equal(np.asarray(y_dma), np.asarray(y_res))
 
@@ -112,8 +114,8 @@ def test_split_blocks_bit_identical_to_unsplit(mixed, threshold,
     result bit for bit."""
     a = _mixed_csr(seed=10)
     x = _x(a.n, 20, seed=11)
-    build = build_mixed_plan if mixed else build_plan
-    plan = build(a.row_ptr, a.col_indices, a.shape, 20)
+    plan = build_mixed_plan(a.row_ptr, a.col_indices, a.shape, 20,
+                            mxu_gain=4.0 if mixed else 0.0)
     mw = choose_merge_width(a.row_ptr, merge_threshold=threshold)
     ws0 = build_fused_workspace(plan, merge_width=mw)
     ws = pack_with_window(plan, 32, merge_width=mw)
@@ -192,12 +194,12 @@ def test_staged_sharded_bit_identical(backend):
     and sharding must compose without touching a single bit."""
     a = _mixed_csr(seed=6, m=56)
     x = _x(a.n, 16, seed=7)
-    y0 = spmm(a, x, backend=backend, interpret=True, staging="dma",
+    y0 = spmm(a, x, **FUSED[backend], interpret=True, staging="dma",
               cache=JitCache())
     for chips in range(1, MAX_CHIPS + 1):
-        y_res = spmm(a, x, backend=backend, interpret=True,
+        y_res = spmm(a, x, **FUSED[backend], interpret=True,
                      staging="resident", n_chips=chips, cache=JitCache())
-        y_dma = spmm(a, x, backend=backend, interpret=True,
+        y_dma = spmm(a, x, **FUSED[backend], interpret=True,
                      staging="dma", n_chips=chips, cache=JitCache())
         assert np.array_equal(np.asarray(y_dma), np.asarray(y_res)), chips
         assert np.array_equal(np.asarray(y_dma), np.asarray(y0)), chips
@@ -210,9 +212,9 @@ def test_staged_gradients_bit_match_resident():
     x = _x(a.n, 12, seed=9)
     vals = jnp.asarray(a.vals)
     for backend in FUSED:
-        c_res = compile_spmm(a, 12, backend=backend, interpret=True,
+        c_res = compile_spmm(a, 12, **FUSED[backend], interpret=True,
                              staging="resident", cache=JitCache())
-        c_dma = compile_spmm(a, 12, backend=backend, interpret=True,
+        c_dma = compile_spmm(a, 12, **FUSED[backend], interpret=True,
                              staging="dma", cache=JitCache())
 
         def loss(c):
@@ -238,12 +240,12 @@ def _iter_eqns(jaxpr):
 
 
 @pytest.mark.parametrize("backend,counter",
-                         [("pallas_ell", "ell_fused"),
+                         [("vpu", "bcsr_fused"),
                           ("pallas_bcsr", "bcsr_fused")])
 def test_staged_trace_is_one_pallas_call(backend, counter):
     a = _mixed_csr(seed=10)
     x = _x(a.n, 16, seed=11)
-    c = compile_spmm(a, 16, backend=backend, interpret=True,
+    c = compile_spmm(a, 16, **FUSED[backend], interpret=True,
                      staging="dma", cache=JitCache())
     jaxpr = jax.make_jaxpr(lambda v, xx: c(v, xx))(jnp.asarray(a.vals), x)
     pallas = [e for e in _iter_eqns(jaxpr.jaxpr)
@@ -258,13 +260,13 @@ def test_staged_trace_is_one_pallas_call(backend, counter):
 
 
 @pytest.mark.parametrize("backend,counter",
-                         [("pallas_ell", "ell_fused"),
+                         [("vpu", "bcsr_fused"),
                           ("pallas_bcsr", "bcsr_fused")])
 def test_staged_sharded_trace_is_one_pallas_call_per_chip(backend,
                                                           counter):
     a = _mixed_csr(seed=12, m=56)
     x = _x(a.n, 16, seed=13)
-    c = compile_spmm(a, 16, backend=backend, interpret=True,
+    c = compile_spmm(a, 16, **FUSED[backend], interpret=True,
                      staging="dma", n_chips=MAX_CHIPS, cache=JitCache())
     jaxpr = jax.make_jaxpr(lambda v, xx: c(v, xx))(jnp.asarray(a.vals), x)
     eqns = list(_iter_eqns(jaxpr.jaxpr))
@@ -334,22 +336,24 @@ def test_op_wrappers_refuse_dma_without_windows():
     falls back to resident, an explicit "dma" without windows raises."""
     a = _mixed_csr(seed=20)
     x = _x(a.n, 8, seed=21)
-    c = compile_spmm(a, 8, backend="pallas_ell", interpret=True,
-                     staging="resident", cache=JitCache())
+    c = compile_spmm(a, 8, backend="pallas_bcsr", mxu_gain=0.0,
+                     interpret=True, staging="resident", cache=JitCache())
     fw = c._fused
     vals_flat = jnp.concatenate(
         [jnp.asarray(a.vals, jnp.float32), jnp.zeros((1,))])[fw.gather_flat]
     x_pad = jnp.pad(x, ((0, 0), (0, 128 - x.shape[1])))
     with pytest.raises(ValueError):
-        ops.spmm_ell_fused_op(fw.blk_off, fw.blk_L, fw.cols_flat,
-                              vals_flat, x_pad, interpret=True,
-                              staging="dma")       # no span/cspan
+        ops.spmm_bcsr_fused_op(fw.blk_tag, fw.blk_off, fw.blk_coff,
+                               fw.blk_L, fw.cols_flat, vals_flat, x_pad,
+                               interpret=True,
+                               staging="dma")      # no span/cspan
     # auto (None) without windows stays resident even if it would
     # otherwise resolve to dma — and produces the right answer
     ops.reset_dispatch_counts()
-    y = ops.spmm_ell_fused_op(fw.blk_off, fw.blk_L, fw.cols_flat,
-                              vals_flat, x_pad, interpret=True)
-    assert ops.DISPATCH_COUNTS["ell_fused_dma"] == 0
+    y = ops.spmm_bcsr_fused_op(fw.blk_tag, fw.blk_off, fw.blk_coff,
+                               fw.blk_L, fw.cols_flat, vals_flat, x_pad,
+                               interpret=True)
+    assert ops.DISPATCH_COUNTS["bcsr_fused_dma"] == 0
     y_ref = spmm(a, x, backend="ref", cache=JitCache())
     np.testing.assert_allclose(np.asarray(y[fw.inv_perm, :8]),
                                np.asarray(y_ref), rtol=1e-4, atol=1e-4)
@@ -381,9 +385,9 @@ def test_workspace_staging_metadata_invariants():
 
 def test_sharded_workspace_windows_cover_every_chip():
     a = _mixed_csr(seed=19, m=50)
-    for backend in FUSED:
+    for gain in (0.0, 4.0):      # all-VPU, and as the structure tags it
         sw = build_sharded_workspace(a.row_ptr, a.col_indices, a.shape,
-                                     16, n_chips=3, backend=backend)
+                                     16, n_chips=3, mxu_gain=gain)
         # windows are PER CHIP since the hot-shard fix: each chip's
         # window must cover ITS OWN largest block (pad blocks span 0),
         # and max_span stays the cross-chip max for introspection
@@ -407,8 +411,8 @@ def test_sharded_workspace_windows_cover_every_chip():
 
 def test_acceptance_staged_on_8_device_mesh():
     """ISSUE acceptance: staged == resident BIT-identical on an 8-chip
-    host mesh for both fused backends, with exactly n_chips staged
-    dispatches per forward."""
+    host mesh for the all-VPU and the mixed plan, with exactly n_chips
+    staged dispatches per forward."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = str(ROOT / "src")
@@ -421,17 +425,16 @@ def test_acceptance_staged_on_8_device_mesh():
         a = random_csr(128, 96, density=0.06, family="powerlaw", seed=21)
         x = jnp.asarray(np.random.default_rng(22)
                         .standard_normal((96, 16)), jnp.float32)
-        for backend, counter in (("pallas_ell", "ell_fused"),
-                                 ("pallas_bcsr", "bcsr_fused")):
-            y_res = spmm(a, x, backend=backend, interpret=True,
-                         staging="resident", n_chips=8, cache=JitCache())
+        for gain in (0.0, 4.0):  # all-VPU, and as the structure tags it
+            kw = dict(backend="pallas_bcsr", mxu_gain=gain, interpret=True,
+                      n_chips=8)
+            y_res = spmm(a, x, staging="resident", cache=JitCache(), **kw)
             ops.reset_dispatch_counts()
-            y_dma = spmm(a, x, backend=backend, interpret=True,
-                         staging="dma", n_chips=8, cache=JitCache())
-            assert ops.DISPATCH_COUNTS[counter] == 8, backend
-            assert ops.DISPATCH_COUNTS[counter + "_dma"] == 8, backend
+            y_dma = spmm(a, x, staging="dma", cache=JitCache(), **kw)
+            assert ops.DISPATCH_COUNTS["bcsr_fused"] == 8, gain
+            assert ops.DISPATCH_COUNTS["bcsr_fused_dma"] == 8, gain
             assert np.array_equal(np.asarray(y_dma),
-                                  np.asarray(y_res)), backend
+                                  np.asarray(y_res)), gain
         print("STAGED-8DEV-OK")
     """)
     out = subprocess.run([sys.executable, "-c", code], env=env,

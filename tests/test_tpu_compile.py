@@ -24,7 +24,6 @@ from repro.kernels.attn_fused import attn_fused, attn_fused_staged
 from repro.kernels.spmm_bcsr_fused import (_sharded_callable,
                                            spmm_bcsr_fused,
                                            spmm_bcsr_fused_staged)
-from repro.kernels.spmm_ell_fused import spmm_ell_fused_staged
 from repro.models.sparse_attention import sparse_attention_mask
 from repro.platform import resident_fits
 
@@ -82,8 +81,7 @@ def _compile(fn, *args):
 def powerlaw_2_18():
     n = 1 << 18
     a = random_csr(n, n, density=16 / n, family="powerlaw", seed=0)
-    return a, build_workspace(a.row_ptr, a.col_indices, a.shape, D,
-                              mixed=True)
+    return a, build_workspace(a.row_ptr, a.col_indices, a.shape, D)
 
 
 def test_mixed_dma_compiles_at_2_18_rows(one_chip, powerlaw_2_18):
@@ -101,14 +99,19 @@ def test_mixed_dma_compiles_at_2_18_rows(one_chip, powerlaw_2_18):
 
 
 def test_ell_dma_compiles_at_2_18_rows(one_chip, powerlaw_2_18):
+    """The all-VPU plan (mxu_gain=0), the layout of a power-law graph."""
     a, _ = powerlaw_2_18
-    ws = build_workspace(a.row_ptr, a.col_indices, a.shape, D)
+    ws = build_workspace(a.row_ptr, a.col_indices, a.shape, D,
+                         mxu_gain=0.0)
     t = _tables(ws, one_chip)
     x = jax.ShapeDtypeStruct((a.n, D), f32, sharding=one_chip)
-    _compile(lambda off, L, cols, vals, x, cont: spmm_ell_fused_staged(
-        off, L, cols, vals, x, cont, span=ws.max_span, cspan=ws.max_cspan,
-        interpret=False),
-        t["off"], t["L"], t["cols"], t["vals"], x, t["cont"])
+    _compile(
+        lambda tag, off, coff, L, cols, vals, x, cont:
+        spmm_bcsr_fused_staged(tag, off, coff, L, cols, vals, x, cont,
+                               span=ws.max_span, cspan=ws.max_cspan,
+                               interpret=False),
+        t["tag"], t["off"], t["coff"], t["L"], t["cols"], t["vals"], x,
+        t["cont"])
 
 
 def _attention_operands(ws, S, sharding):
@@ -158,7 +161,7 @@ def test_attention_panel_edges_compile_at_4096(one_chip, b):
 @pytest.mark.parametrize("family", ("spmm", "attention"))
 def test_resident_compiles_at_small_size(one_chip, family):
     a = random_csr(256, 256, density=0.05, family="banded", seed=1)
-    ws = build_workspace(a.row_ptr, a.col_indices, a.shape, D, mixed=True)
+    ws = build_workspace(a.row_ptr, a.col_indices, a.shape, D)
     assert ws.has_mxu
     assert resident_fits(ws.num_blocks, ws.gather_flat.size,
                          ws.cols_flat.size, 4 * a.n * 2 * D)
@@ -180,8 +183,7 @@ def test_sharded_mixed_dma_compiles_on_four_chips(topo):
     n = 1 << 16
     a = random_csr(n, n, density=16 / n, family="powerlaw", seed=2)
     sw = build_sharded_workspace(a.row_ptr, a.col_indices, a.shape, D,
-                                 n_chips=4, backend="pallas_bcsr",
-                                 x_sharding="rows")
+                                 n_chips=4, x_sharding="rows")
     mesh = Mesh(np.asarray(topo.devices[:4]), ("chips",))
     on_chips = NamedSharding(mesh, P("chips"))
     t = _tables(sw, on_chips)

@@ -15,8 +15,9 @@ import numpy as np
 import pytest
 
 from repro.core import (CSRMatrix, build_batched_workspace,
-                        build_fused_workspace, build_mixed_plan, build_plan,
-                        build_sharded_workspace, compile_batched_spmm,
+                        build_fused_workspace, build_mixed_plan,
+                        build_sharded_workspace, build_workspace,
+                        compile_batched_spmm,
                         compile_sparse_attention, compile_spmm, random_csr)
 from repro.core.jit_cache import JitCache
 from repro.core.spmm import _SlotValues, _slot_values, _tiles
@@ -58,8 +59,8 @@ def _workspace(case, pack_with_window):
     """(host workspace, its nnz, members laid end to end)."""
     a = _window_mask() if case == "all_mxu" else _mixed_matrix()
     args = (a.row_ptr, a.col_indices, a.shape, 16)
-    if case == "ell":
-        return build_fused_workspace(build_plan(*args)), a.nnz, 1
+    if case == "ell":          # the one-call composition, all VPU
+        return build_workspace(*args, mxu_gain=0.0), a.nnz, 1
     if case == "bcsr_vpu":
         plan = build_mixed_plan(*args, mxu_gain=0.0)
         return build_fused_workspace(plan), a.nnz, 1
@@ -79,11 +80,10 @@ def _workspace(case, pack_with_window):
         bw = build_batched_workspace(
             [(a.row_ptr, a.col_indices, a.shape),
              (b.row_ptr, b.col_indices, b.shape),
-             (a.row_ptr, a.col_indices, a.shape)], 16,
-            backend="pallas_bcsr")
+             (a.row_ptr, a.col_indices, a.shape)], 16)
         return bw, bw.nnz, bw.n_requests
     assert case == "sharded"
-    sw = build_sharded_workspace(*args, n_chips=3, backend="pallas_bcsr")
+    sw = build_sharded_workspace(*args, n_chips=3)
     return sw, sw.nnz, 1
 
 
@@ -132,8 +132,8 @@ def test_compact_split_skips_only_sentinel_lanes(case, pack_with_window):
 def test_artifacts_record_their_gathered_elements():
     a = _mixed_matrix()
     mask = _window_mask()
-    ell = compile_spmm(a, 16, backend="pallas_ell", interpret=True,
-                       cache=JitCache())
+    ell = compile_spmm(a, 16, backend="pallas_bcsr", mxu_gain=0.0,
+                       interpret=True, cache=JitCache())
     assert ell.vals_gather_elems == ell.vals_slots
     mixed = compile_spmm(a, 16, backend="pallas_bcsr", interpret=True,
                          cache=JitCache())

@@ -5,7 +5,7 @@ REAL pipeline artifact and flipping exactly the field the invariant
 guards with ``dataclasses.replace`` — and the test asserts the verifier
 reports the exact violation kind.  Clean round-trips then pin the
 other direction: everything the pipeline actually emits, across
-strategy x backend x staging x chips, verifies with zero
+strategy x tagging x staging x chips, verifies with zero
 error-severity findings (so turning ``validate="full"`` on under the
 whole suite cannot regress anything).
 """
@@ -19,10 +19,10 @@ from repro.analysis.verify import (VALIDATE_MODES, PlanVerificationError,
                                    verify_attention_contract,
                                    verify_workspace)
 from repro.core.csr import CSRMatrix, random_csr
-from repro.core.plan import (MXU_TAG, SPARSE_ATTN_EINSUM,
-                             build_batched_workspace, build_fused_workspace,
-                             build_mixed_plan, build_sharded_workspace,
-                             build_workspace)
+from repro.core.plan import (MXU_TAG, SPARSE_ATTN_MIXED_EINSUM,
+                             build_batched_workspace, build_einsum_workspace,
+                             build_fused_workspace, build_mixed_plan,
+                             build_sharded_workspace, build_workspace)
 
 
 def _kinds(violations):
@@ -33,17 +33,18 @@ def _solo(m=64, n=64, *, density=0.2, mixed=False, merge_threshold=0,
           seed=0, family="uniform", d=16):
     a = random_csr(m, n, density=density, seed=seed, family=family)
     ws = build_workspace(a.row_ptr, a.col_indices, a.shape, d,
-                         mixed=mixed, merge_threshold=merge_threshold)
+                         mxu_gain=4.0 if mixed else 0.0,
+                         merge_threshold=merge_threshold)
     return a, ws
 
 
-def _sharded(m=96, n=96, *, n_chips=2, backend="pallas_ell",
+def _sharded(m=96, n=96, *, n_chips=2, mxu_gain=0.0,
              x_sharding="replicated", density=0.15, seed=1, d=16,
              merge_threshold=0):
     a = random_csr(m, n, density=density, seed=seed)
     sw = build_sharded_workspace(
         a.row_ptr, a.col_indices, a.shape, d, n_chips=n_chips,
-        backend=backend, x_sharding=x_sharding,
+        mxu_gain=mxu_gain, x_sharding=x_sharding,
         merge_threshold=merge_threshold)
     return a, sw
 
@@ -277,25 +278,31 @@ def test_batched_gather_crosses_request_boundary():
 
 def test_attn_mask_negative_weight():
     out = verify_attention_contract(
-        SPARSE_ATTN_EINSUM, np.array([0.5, -1.0, 2.0]))
+        SPARSE_ATTN_MIXED_EINSUM, np.array([0.5, -1.0, 2.0]))
     assert "attn_mask_negative" in _kinds(out)
 
 
 def test_attn_mask_nan_weight():
     out = verify_attention_contract(
-        SPARSE_ATTN_EINSUM, np.array([0.5, np.nan]))
+        SPARSE_ATTN_MIXED_EINSUM, np.array([0.5, np.nan]))
     assert "attn_mask_negative" in _kinds(out)
 
 
 def test_attn_spec_missing_operands():
-    bad = dataclasses.replace(SPARSE_ATTN_EINSUM, col_operands=1)
+    bad = dataclasses.replace(SPARSE_ATTN_MIXED_EINSUM, col_operands=1)
     assert "attn_spec" in _kinds(verify_attention_contract(bad))
 
 
 def test_attn_spec_mixed_mismatch():
-    out = verify_attention_contract(
-        SPARSE_ATTN_EINSUM, np.ones(3), has_mxu=True)
-    assert "attn_spec" in _kinds(out)  # non-mixed spec, MXU-tagged ws
+    """The attention spec carries no tagging flag to mismatch: an
+    MXU-tagged attention workspace meets the attention contract."""
+    a = _mixed_csr_for_verify()
+    ws = build_einsum_workspace(SPARSE_ATTN_MIXED_EINSUM, a.row_ptr,
+                                a.col_indices, a.shape, 16)
+    assert ws.has_mxu
+    out = verify_workspace(ws, n_cols=a.n, spec=SPARSE_ATTN_MIXED_EINSUM,
+                           vals=np.ones(a.nnz))
+    assert _kinds(out) == set()
 
 
 # -- clean round-trips: real pipeline artifacts carry zero errors ------------
@@ -311,11 +318,12 @@ def test_clean_solo(family, mixed, merge_threshold):
     check_workspace(ws, n_cols=a.n)     # and the raising door agrees
 
 
-@pytest.mark.parametrize("backend", ["pallas_ell", "pallas_bcsr"])
+@pytest.mark.parametrize("backend", ["vpu", "pallas_bcsr"])
 @pytest.mark.parametrize("x_sharding", ["replicated", "rows"])
 @pytest.mark.parametrize("n_chips", [2, 4])
 def test_clean_sharded(backend, x_sharding, n_chips):
-    a, sw = _sharded(n_chips=n_chips, backend=backend,
+    a, sw = _sharded(n_chips=n_chips,
+                     mxu_gain=0.0 if backend == "vpu" else 4.0,
                      x_sharding=x_sharding)
     assert _kinds(verify_workspace(sw, n_cols=a.n)) == set()
     check_workspace(sw, n_cols=a.n)
@@ -419,6 +427,6 @@ def test_compile_rejects_out_of_bounds_structure():
     with pytest.raises(PlanVerificationError) as ei:
         # backend pinned to a fused path: "auto" on CPU picks the ref
         # backend, which has no plan IR to verify
-        compile_spmm(a, 8, backend="pallas_ell", interpret=True,
-                     validate="full", autotune=False)
+        compile_spmm(a, 8, backend="pallas_bcsr", mxu_gain=0.0,
+                     interpret=True, validate="full", autotune=False)
     assert any(v.kind == "cols_oob" for v in ei.value.violations)

@@ -5,8 +5,8 @@ What the X-sharded dispatch must preserve — and what this module pins:
   * BIT-identity with the replicated sharded path (and hence with the
     unsharded fused path): the exact-panel exchange copies values, the
     remapped column stream addresses the same rows, the accumulation
-    order never changes — all three strategies x both fused backends x
-    both staging modes x 1..N chips, forward AND gradient (the
+    order never changes — all three strategies x all-VPU and mixed
+    plans x both staging modes x 1..N chips, forward AND gradient (the
     transposed artifact inherits the knob).
   * the Table IV invariant: still exactly one pallas_call per chip per
     forward (plus one all_to_all collective), asserted on DISPATCH
@@ -41,7 +41,10 @@ ROOT = Path(__file__).resolve().parents[1]
 N_DEV = len(jax.devices())
 MAX_CHIPS = min(N_DEV, 4)
 
-FUSED = ("pallas_ell", "pallas_bcsr")
+# the fused backend with every block-row tagged VPU, and as the
+# structure tags it; parametrized by key
+FUSED = {"vpu": dict(backend="pallas_bcsr", mxu_gain=0.0),
+         "pallas_bcsr": dict(backend="pallas_bcsr")}
 
 
 def _mixed_csr(seed=0, m=48, n=64):
@@ -84,10 +87,10 @@ def test_xshard_bit_identical_to_replicated(backend, strategy):
     a = _mixed_csr(seed=2, m=56)
     x = _x(a.n, 20, seed=3)
     for chips in range(1, MAX_CHIPS + 1):
-        y_rep = spmm(a, x, strategy=strategy, backend=backend,
+        y_rep = spmm(a, x, strategy=strategy, **FUSED[backend],
                      interpret=True, n_chips=chips,
                      x_sharding="replicated", cache=JitCache())
-        y_row = spmm(a, x, strategy=strategy, backend=backend,
+        y_row = spmm(a, x, strategy=strategy, **FUSED[backend],
                      interpret=True, n_chips=chips, x_sharding="rows",
                      cache=JitCache())
         assert np.array_equal(np.asarray(y_row), np.asarray(y_rep)), (
@@ -100,9 +103,9 @@ def test_xshard_staged_bit_identical(backend):
     replicated+resident == the unsharded fused dispatch, bit for bit."""
     a = random_csr(120, 96, density=0.06, family="powerlaw", seed=4)
     x = _x(a.n, 24, seed=5)
-    y0 = spmm(a, x, backend=backend, interpret=True, cache=JitCache())
+    y0 = spmm(a, x, **FUSED[backend], interpret=True, cache=JitCache())
     for staging in ("resident", "dma"):
-        y = spmm(a, x, backend=backend, interpret=True, staging=staging,
+        y = spmm(a, x, **FUSED[backend], interpret=True, staging=staging,
                  n_chips=MAX_CHIPS, x_sharding="rows", cache=JitCache())
         assert np.array_equal(np.asarray(y), np.asarray(y0)), staging
 
@@ -114,10 +117,10 @@ def test_xshard_gradients_bit_match_replicated(backend):
     a = _mixed_csr(seed=8)
     x = _x(a.n, 12, seed=9)
     vals = jnp.asarray(a.vals)
-    c_rep = compile_spmm(a, 12, backend=backend, interpret=True,
+    c_rep = compile_spmm(a, 12, **FUSED[backend], interpret=True,
                          n_chips=MAX_CHIPS, x_sharding="replicated",
                          cache=JitCache())
-    c_row = compile_spmm(a, 12, backend=backend, interpret=True,
+    c_row = compile_spmm(a, 12, **FUSED[backend], interpret=True,
                          n_chips=MAX_CHIPS, x_sharding="rows",
                          cache=JitCache())
 
@@ -148,12 +151,12 @@ def _iter_eqns(jaxpr):
 
 
 @pytest.mark.parametrize("backend,counter",
-                         [("pallas_ell", "ell_fused"),
+                         [("vpu", "bcsr_fused"),
                           ("pallas_bcsr", "bcsr_fused")])
 def test_xshard_trace_is_one_pallas_call_per_chip(backend, counter):
     a = _mixed_csr(seed=10, m=56)
     x = _x(a.n, 16, seed=11)
-    c = compile_spmm(a, 16, backend=backend, interpret=True,
+    c = compile_spmm(a, 16, **FUSED[backend], interpret=True,
                      n_chips=MAX_CHIPS, x_sharding="rows",
                      cache=JitCache())
     jaxpr = jax.make_jaxpr(lambda v, xx: c(v, xx))(jnp.asarray(a.vals), x)
@@ -193,19 +196,19 @@ def test_replicated_forward_counts_no_xshard_dispatch():
 def test_jit_cache_keys_on_x_sharding():
     a = _mixed_csr(seed=16)
     cache = JitCache()
-    c_rep = compile_spmm(a, 8, backend="pallas_ell", interpret=True,
+    c_rep = compile_spmm(a, 8, **FUSED["vpu"], interpret=True,
                          n_chips=1, x_sharding="replicated", cache=cache)
-    c_row = compile_spmm(a, 8, backend="pallas_ell", interpret=True,
+    c_row = compile_spmm(a, 8, **FUSED["vpu"], interpret=True,
                          n_chips=1, x_sharding="rows", cache=cache)
     assert c_rep is not c_row
     assert cache.stats()["entries"] == 2
     # "auto" under interpret mode resolves to replicated (the exchange
     # is pure overhead on an emulated mesh), same shape as staging
-    assert compile_spmm(a, 8, backend="pallas_ell", interpret=True,
+    assert compile_spmm(a, 8, **FUSED["vpu"], interpret=True,
                         n_chips=1, x_sharding="auto", cache=cache) is c_rep
-    assert compile_spmm(a, 8, backend="pallas_ell", interpret=True,
+    assert compile_spmm(a, 8, **FUSED["vpu"], interpret=True,
                         n_chips=1, cache=cache) is c_rep
-    assert compile_spmm(a, 8, backend="pallas_ell", interpret=True,
+    assert compile_spmm(a, 8, **FUSED["vpu"], interpret=True,
                         n_chips=1, x_sharding="rows", cache=cache) is c_row
 
 
@@ -213,14 +216,14 @@ def test_xshard_knob_contract():
     a = _mixed_csr(seed=17)
     # rows without a mesh: nothing owns the panels
     with pytest.raises(ValueError):
-        compile_spmm(a, 8, backend="pallas_ell", interpret=True,
+        compile_spmm(a, 8, **FUSED["vpu"], interpret=True,
                      x_sharding="rows", cache=JitCache())
     # the knob only exists on the fused dispatch
     with pytest.raises(ValueError):
         compile_spmm(a, 8, backend="ref", x_sharding="rows",
                      cache=JitCache())
     with pytest.raises(ValueError):
-        compile_spmm(a, 8, backend="pallas_ell", interpret=True,
+        compile_spmm(a, 8, **FUSED["vpu"], interpret=True,
                      n_chips=1, x_sharding="cols", cache=JitCache())
     # replicated/auto are accepted everywhere (they are the default)
     c = compile_spmm(a, 8, backend="ref", x_sharding="replicated",
@@ -234,7 +237,8 @@ def test_xshard_knob_contract():
 def test_fetch_tables_cover_touched_panels(backend):
     a = _mixed_csr(seed=18, m=56, n=96)
     sw = build_sharded_workspace(a.row_ptr, a.col_indices, a.shape, 16,
-                                 n_chips=3, backend=backend,
+                                 n_chips=3,
+                                 mxu_gain=0.0 if backend == "vpu" else 4.0,
                                  x_sharding="rows")
     bk = sw.bk
     assert sw.x_panels == -(-a.n // bk)
@@ -269,7 +273,8 @@ def test_fetch_tables_cover_touched_panels(backend):
 def test_replicated_workspace_has_no_fetch_tables():
     a = _mixed_csr(seed=19)
     sw = build_sharded_workspace(a.row_ptr, a.col_indices, a.shape, 8,
-                                 n_chips=2, x_sharding="replicated")
+                                 n_chips=2, mxu_gain=0.0,
+                                 x_sharding="replicated")
     assert sw.x_fetch is None and sw.x_send is None and sw.x_recv is None
     assert sw.x_local_panels == 0
 
@@ -282,7 +287,8 @@ def test_hot_shard_does_not_inflate_cold_chip_windows():
     chip's ring is sized from its own largest block."""
     a = _hot_csr()
     sw = build_sharded_workspace(a.row_ptr, a.col_indices, a.shape, 8,
-                                 n_chips=4, strategy="nnz_split")
+                                 n_chips=4, strategy="nnz_split",
+                                 mxu_gain=0.0)
     spans = np.asarray(sw.chip_span)
     assert spans.max() == sw.max_span
     assert spans.min() < spans.max()          # cold chips stay small
@@ -306,7 +312,7 @@ def test_hot_shard_staged_switch_still_one_call_per_chip(backend):
         pytest.skip("needs a multi-device mesh")
     a = _hot_csr()
     x = _x(a.n, 8, seed=21)
-    c = compile_spmm(a, 8, backend=backend, interpret=True,
+    c = compile_spmm(a, 8, **FUSED[backend], interpret=True,
                      staging="dma", n_chips=MAX_CHIPS, cache=JitCache())
     sw = c.sharded_workspace
     n_windows = len(set(zip(sw.chip_span.tolist(),
@@ -321,7 +327,7 @@ def test_hot_shard_staged_switch_still_one_call_per_chip(backend):
     # one specialized kernel per distinct window in the traced body;
     # each chip EXECUTES exactly one of them (switch on axis index)
     assert len(in_body) == n_windows
-    y_ref = spmm(a, x, backend=backend, interpret=True,
+    y_ref = spmm(a, x, **FUSED[backend], interpret=True,
                  staging="resident", cache=JitCache())
     y = c(jnp.asarray(a.vals), x)
     assert np.array_equal(np.asarray(y), np.asarray(y_ref))
@@ -332,7 +338,7 @@ def test_hot_shard_staged_switch_still_one_call_per_chip(backend):
 def test_acceptance_xshard_on_8_device_mesh():
     """ISSUE acceptance: X-sharded == replicated BIT-identical (forward
     and gradient) on a forced 8-chip host mesh for all three strategies
-    x both fused backends, one pallas_call per chip, and per-chip VMEM
+    x all-VPU and mixed plans, one pallas_call per chip, and per-chip VMEM
     windows that do not all scale with the hottest shard."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
@@ -348,22 +354,22 @@ def test_acceptance_xshard_on_8_device_mesh():
         x = jnp.asarray(np.random.default_rng(22)
                         .standard_normal((96, 16)), jnp.float32)
         vals = jnp.asarray(a.vals)
-        for backend, counter in (("pallas_ell", "ell_fused"),
-                                 ("pallas_bcsr", "bcsr_fused")):
+        for gain in (0.0, 4.0):  # all-VPU, and as the structure tags it
             for strategy in STRATEGIES:
                 c0 = compile_spmm(a, 16, strategy=strategy,
-                                  backend=backend, interpret=True,
-                                  n_chips=8, x_sharding="replicated",
+                                  backend="pallas_bcsr", mxu_gain=gain,
+                                  interpret=True, n_chips=8,
+                                  x_sharding="replicated",
                                   cache=JitCache())
                 c1 = compile_spmm(a, 16, strategy=strategy,
-                                  backend=backend, interpret=True,
-                                  n_chips=8, x_sharding="rows",
-                                  cache=JitCache())
+                                  backend="pallas_bcsr", mxu_gain=gain,
+                                  interpret=True, n_chips=8,
+                                  x_sharding="rows", cache=JitCache())
                 ops.reset_dispatch_counts()
                 y0, y1 = c0(vals, x), c1(vals, x)
-                assert ops.DISPATCH_COUNTS[counter + "_xshard"] == 8
+                assert ops.DISPATCH_COUNTS["bcsr_fused_xshard"] == 8
                 assert np.array_equal(np.asarray(y0), np.asarray(y1)), (
-                    backend, strategy)
+                    gain, strategy)
                 lf = lambda c: (lambda v, xx:
                                 jnp.sum(jnp.tanh(c(v, xx))))
                 g0 = jax.grad(lf(c0), argnums=(0, 1))(vals, x)
