@@ -115,13 +115,15 @@ def power_law_graph(seed: int, log2_n: int = 20):
 
 def describe_fused(c) -> str:
     """One line on a one-chip fused artifact: how it resolved, its
-    trips and window, the slot-value stream's slots and the elements
-    its staging gathers, its descriptors and piece trips."""
+    trips and window, the slot-value stream's slots beside the
+    nonzeros, the loop steps of its VPU trips, the elements its staging
+    gathers, its descriptors and piece trips."""
     fw = c._fused
     tags = np.asarray(fw.blk_tag)[np.asarray(fw.blk_L) > 0]
     return (f"backend={c.backend} staging={c.staging} "
             f"interpret={c.interpret} trips={fw.num_blocks} "
-            f"window={fw.max_span} slots={c.vals_slots} "
+            f"window={fw.max_span} nnz={c._nnz} slots={c.vals_slots} "
+            f"vpu_steps={c.vpu_steps} "
             f"gathered={c.vals_gather_elems}; VPU/MXU descriptors "
             f"{int((tags == 0).sum())} / {int((tags == 1).sum())}; "
             f"piece trips {int(np.asarray(fw.cont).sum())}")
@@ -321,7 +323,8 @@ def phase_four_chips(seed: int):
     log("chips4", f"compile_spmm n_chips=4 {t_plan:.2f}s: "
                   f"staging={c4.staging} x_sharding={c4.x_sharding} "
                   f"interpret={c4.interpret} chip windows "
-                  f"{c4._sharded.chip_span}; slots={c4.vals_slots} "
+                  f"{c4._sharded.chip_span}; nnz={a.nnz} "
+                  f"slots={c4.vals_slots} vpu_steps={c4.vpu_steps} "
                   f"gathered={c4.vals_gather_elems}; slot-value tables "
                   f"on {placed} devices")
     if any(n != 4 for n in placed) or c4.interpret:

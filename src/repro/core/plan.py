@@ -7,11 +7,9 @@ statically scheduled, so *all* balancing moves to plan time (DESIGN.md
 §7.2) where — unlike an AOT binary — we can see the full ``row_ptr``.
 
 A plan groups rows into **ELL segments**: each segment is a set of rows
-padded to a common nonzeros-per-row ``L`` and lowered as one
-``pallas_call`` with a fully static grid (the TPU analogue of "generated
-code with no data-dependent branches").  The three strategies differ in
-how rows are grouped, i.e. how much padding (wasted FLOPs) and how much
-locality they trade:
+padded to a common nonzeros-per-row ``L``.  The three strategies differ
+in how rows are grouped, i.e. how much padding (wasted FLOPs) and how
+much locality they trade:
 
   row_split    one segment, original row order, L = max row length.
                Fastest to plan; faithful to Fig. 6(a) including its
@@ -37,7 +35,11 @@ Plan construction is a **transform pipeline** (DESIGN.md §7.9):
   tag     per-block-row execution-unit selection (VPU or MXU)
           (:func:`tag_block_rows`, folded into :func:`build_mixed_plan`)
   pack    flatten everything into the descriptor stream
-          (:func:`_pack_workspace`, merge-width aware)
+          (:func:`_pack_workspace`, merge-width aware).  Each VPU block
+          of ``row_block`` rows runs its own rows' longest length
+          (``EllSegment.blk_L``), not its segment's ``L``: the segment
+          only groups rows, the stream drops the columns past each
+          block's own length
   shard   partition rows across chips at merged-trip boundaries and run
           the same pipeline per chip (:func:`build_sharded_workspace`)
 
@@ -138,6 +140,8 @@ class EllSegment:
     R_pad: int               # rows padded up (multiple of row_block)
     cols_pad: np.ndarray     # (R_pad, max(L,1)) int32, pad -> col 0
     gather_idx: np.ndarray   # (R_pad, max(L,1)) int64 into concat(vals,[0])
+    blk_L: np.ndarray        # (R_pad // row_block,) int64 longest row of
+                             # each block, at least 1: its packed length
 
     @property
     def R(self) -> int:
@@ -145,6 +149,8 @@ class EllSegment:
 
     @property
     def padded_nnz(self) -> int:
+        """Slots of the segment's ``(R_pad, L)`` panel; the packed
+        stream holds ``row_block * blk_L.sum()`` of them."""
         return self.R_pad * max(self.L, 1)
 
 
@@ -162,12 +168,16 @@ class SpmmPlan:
 
     @property
     def padded_nnz(self) -> int:
+        """Slots of the segments' panels, each padded to its segment's
+        ``L``.  The packed stream pads each block to its own rows'
+        longest length instead, so it holds at most this many."""
         return sum(s.padded_nnz for s in self.segments)
 
     @property
     def efficiency(self) -> float:
         """real work / padded work — the balance metric the three
-        strategies compete on (1.0 = perfectly balanced, no padding)."""
+        strategies compete on (1.0 = perfectly balanced, no padding),
+        over the segments' panels (see :attr:`padded_nnz`)."""
         return self.nnz / max(self.padded_nnz, 1)
 
     def stats(self) -> dict:
@@ -291,8 +301,12 @@ def build_plan(row_ptr: np.ndarray, col_indices: np.ndarray, shape,
         if nnz > 0:
             safe = np.minimum(idx, nnz - 1)
             cols_pad[:R] = np.where(valid, col_indices[safe], 0)
+        blk_L = np.zeros(R_pad, dtype=np.int64)
+        blk_L[:R] = lengths[rows]
+        blk_L = np.maximum(blk_L.reshape(-1, row_block).max(axis=1), 1)
         segments.append(EllSegment(row_ids=rows, L=L, R_pad=R_pad,
-                                   cols_pad=cols_pad, gather_idx=gather_idx))
+                                   cols_pad=cols_pad, gather_idx=gather_idx,
+                                   blk_L=blk_L))
 
     return SpmmPlan(strategy=strategy, m=m, n=n, nnz=nnz,
                     d_tiling=d_tiling, segments=segments,
@@ -312,9 +326,10 @@ def build_plan(row_ptr: np.ndarray, col_indices: np.ndarray, shape,
 class FusedEllWorkspace:
     """Descriptor-table packing of a :class:`MixedPlan`.
 
-    Every segment's ``(R_pad, L)`` ELL panel is flattened row-major and
-    concatenated into one slot array; each row-block of ``row_block``
-    rows gets a descriptor ``(blk_off, blk_L)`` locating its slots.  The
+    Each row-block of ``row_block`` rows is packed as a ``(bm, L)`` ELL
+    panel, ``L`` its own rows' longest length (at least 1), flattened
+    row-major and concatenated into one slot array; each gets a
+    descriptor ``(blk_off, blk_L)`` locating its slots.  The
     kernel reads the descriptor from SMEM (scalar prefetch) — the TPU
     analogue of the paper baking per-instance bounds into the generated
     code — so one static grid covers blocks with heterogeneous ``L``.
@@ -326,8 +341,8 @@ class FusedEllWorkspace:
     while its column stream carries only the ``K`` *block*-column ids —
     so the two streams diverge and each descriptor gets an independent
     column offset ``blk_coff``.  ``blk_L`` is the per-block loop trip
-    count either way: padded nnz/row for VPU, block steps ``K`` (the
-    per-block-row ``kmax``) for MXU.
+    count either way: the block's longest row for VPU, block steps
+    ``K`` (the per-block-row ``kmax``) for MXU.
 
     Workspace rows are ordered block-by-block (plan order), i.e. a
     permutation (plus padding rows) of the output rows; ``inv_perm``
@@ -374,7 +389,7 @@ class FusedEllWorkspace:
                              #               MXU: block-column per step
     gather_flat: np.ndarray  # (S,) int64 — slot -> index in concat(vals,[0])
     blk_off: np.ndarray      # (B,) int32 — first slot of each row-block
-    blk_L: np.ndarray        # (B,) int32 — loop trips (nnz/row or K)
+    blk_L: np.ndarray        # (B,) int32 — loop trips (longest row or K)
     inv_perm: np.ndarray     # (m,) int32 — y[i] = y_ws[inv_perm[i]]
     ws_rows: int             # total workspace rows == B * row_block
     row_block: int
@@ -840,9 +855,11 @@ def _pack_workspace(plan: MixedPlan, *,
     """Pack a :class:`MixedPlan` into one tagged descriptor stream.
 
     VPU blocks first (plan order, gather remapped from sub-nnz to global
-    nnz ids), then the MXU block-rows, each starting at a
-    :data:`STAGE_TILE`-aligned slot with lane-padded panels.  Column and
-    slot streams advance independently (see :class:`FusedEllWorkspace`).
+    nnz ids), each a ``(bm, L)`` panel at its own rows' longest length
+    ``L`` (:attr:`EllSegment.blk_L`), then the MXU block-rows, each
+    starting at a :data:`STAGE_TILE`-aligned slot with lane-padded
+    panels.  Column and slot streams advance independently (see
+    :class:`FusedEllWorkspace`).
 
     Blocks whose panel exceeds the platform's staging window
     (:func:`~repro.platform.stage_limits`) are split into pieces
@@ -866,51 +883,62 @@ def _pack_workspace(plan: MixedPlan, *,
     # per real descriptor, in stream order
     tags, Ls, offs, coffs, spans, cspans, pieces, npieces = (
         [] for _ in range(8))
-    row_src = []          # (first descriptor, pieces per block, out rows)
+    row_src = []          # (last descriptor of each block, its out rows)
     slot = cpos = n_desc = 0
 
-    def emit(tag, piece_L, nblk, slots_per_step, cols_per_step):
-        """Descriptors of ``nblk`` consecutive blocks, each split into
-        ``piece_L.size`` pieces of ``piece_L`` loop steps."""
+    def emit(tag, block_L, width, rows, slots_per_step, cols_per_step):
+        """Descriptors of consecutive blocks of ``block_L`` loop steps
+        each, every block split into pieces of at most ``width``
+        steps; ``rows`` are the blocks' output rows."""
         nonlocal slot, cpos, n_desc
-        P = piece_L.size
-        L = np.tile(piece_L, nblk)
+        P = -(-block_L // width)
+        last = np.cumsum(P) - 1
+        piece = np.arange(last[-1] + 1) - np.repeat(last + 1 - P, P)
+        L = np.minimum(width, np.repeat(block_L, P) - width * piece)
         span, cspan = slots_per_step * L, cols_per_step * L
+        row_src.append((n_desc + last, rows))
         tags.append(np.full(L.size, tag, np.int64))
         Ls.append(L)
         spans.append(span)
         cspans.append(cspan)
         offs.append(slot + np.cumsum(span) - span)
         coffs.append(cpos + np.cumsum(cspan) - cspan)
-        pieces.append(np.tile(np.arange(P), nblk))
-        npieces.append(np.full(L.size, P))
+        pieces.append(piece)
+        npieces.append(np.repeat(P, P))
         slot += int(span.sum())
         cpos += int(cspan.sum())
         n_desc += L.size
 
+    CL = max(W // bm, 1)                  # widest VPU piece, in steps
     for seg in plan.vpu.segments:
         Lp = max(seg.L, 1)
         nblk = seg.R_pad // bm
-        g = seg.gather_idx
+        c3 = seg.cols_pad.reshape(nblk, bm, Lp)
+        g3 = seg.gather_idx.reshape(nblk, bm, Lp)
+        # each block's (bm, L_b) panel, its pieces' (bm, <= CL) panels
+        # in turn: the lanes split as (piece, lane in piece) before the
+        # rows, and lanes past the block's own length go
+        P = -(-Lp // CL)
+        if P > 1:
+            lanes = ((0, 0), (0, 0), (0, P * CL - Lp))
+            c3 = np.pad(c3, lanes).reshape(nblk, bm, P, CL).swapaxes(1, 2)
+            g3 = np.pad(g3, lanes).reshape(nblk, bm, P, CL).swapaxes(1, 2)
+            lane = np.arange(P * CL).reshape(1, P, 1, CL)
+        else:
+            lane = np.arange(Lp).reshape(1, 1, Lp)
+        keep = np.broadcast_to(
+            lane < seg.blk_L.reshape((nblk,) + (1,) * (c3.ndim - 1)),
+            c3.shape)
+        g = g3[keep]
         if sub_nnz == 0:                   # all-empty VPU rows
             g = np.full(g.shape, nnz, np.int64)
-        else:                              # sub-nnz ids -> global ids
+        elif sub_nnz < nnz:                # sub-nnz ids -> global ids
             g = np.where(g < sub_nnz,
                          plan.vpu_nnz_map[np.minimum(g, sub_nnz - 1)], nnz)
-        CL = max(min(Lp, W // bm), 1)
-        piece_L = np.minimum(CL, Lp - CL * np.arange(-(-Lp // CL)))
-        if piece_L.size == 1:
-            cols_parts.append(seg.cols_pad.reshape(-1))
-            gather_parts.append(g.reshape(-1))
-        else:                              # each piece its own (bm, CL) panel
-            c3 = seg.cols_pad.reshape(nblk, bm, Lp)
-            g3 = g.reshape(nblk, bm, Lp)
-            for b in range(nblk):
-                for lo in range(0, Lp, CL):
-                    cols_parts.append(c3[b, :, lo:lo + CL].reshape(-1))
-                    gather_parts.append(g3[b, :, lo:lo + CL].reshape(-1))
-        row_src.append((n_desc, piece_L.size, plan.vpu_rows[seg.row_ids]))
-        emit(VPU_TAG, piece_L, nblk, bm, bm)
+        # else every row is VPU, in order: the sub ids are the global
+        cols_parts.append(c3[keep])
+        gather_parts.append(g)
+        emit(VPU_TAG, seg.blk_L, CL, plan.vpu_rows[seg.row_ids], bm, bm)
     Kp = max(W // panel, 1)
     for blk in plan.mxu_rows:
         pad = -slot % STAGE_TILE           # aligned panel tiles
@@ -921,10 +949,8 @@ def _pack_workspace(plan: MixedPlan, *,
         gp[:, :, :bk] = blk.gather
         cols_parts.append(blk.bcols)
         gather_parts.append(gp.reshape(-1))
-        row_src.append((n_desc, -(-blk.K // Kp),
-                        np.arange(blk.row0, blk.row0 + blk.nrows)))
-        emit(MXU_TAG, np.minimum(Kp, blk.K - Kp * np.arange(-(-blk.K // Kp))),
-             1, panel, 1)
+        emit(MXU_TAG, np.array([blk.K]), Kp,
+             np.arange(blk.row0, blk.row0 + blk.nrows), panel, 1)
 
     assert slot < (1 << 31), ("mixed workspace exceeds int32 slot space",
                               slot)
@@ -980,10 +1006,9 @@ def _pack_workspace(plan: MixedPlan, *,
     pos = np.zeros(n_desc, np.int64)
     pos[final[real]] = np.nonzero(real)[0]
     inv_perm = np.zeros(plan.m, dtype=np.int32)
-    for first, P, rows in row_src:
+    for last, rows in row_src:
         r = np.arange(rows.size)
-        last = first + (r // bm) * P + P - 1
-        inv_perm[rows] = pos[last] * bm + r % bm
+        inv_perm[rows] = pos[last[r // bm]] * bm + r % bm
 
     trip_spans = np.where(real, span[src], 0).reshape(-1, mw).sum(axis=1)
     trip_cspans = np.where(real, cspan[src], 0).reshape(-1, mw).sum(axis=1)

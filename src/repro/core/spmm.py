@@ -218,6 +218,7 @@ class _FusedConsts:
     max_cspan: int = 0       # staged-DMA cols window
     merge_width: int = 1     # CGCM width (DESIGN.md §7.9)
     cont: Optional[jax.Array] = None   # (B//W,) int32 piece trips
+    vpu_steps: int = 0       # loop steps of the VPU descriptors per call
 
     @property
     def gather_flat(self) -> jax.Array:
@@ -344,14 +345,25 @@ def _slot_values(ws, nnz: int, members: int = 1,
         zero_rows=n_rows - e, shape=g.shape)
 
 
+def _vpu_steps(ws) -> int:
+    """Loop steps a workspace's VPU descriptors run per call, over every
+    chip or request it stacks: the sum of their ``blk_L``."""
+    return int(np.asarray(ws.blk_L, np.int64)[ws.blk_tag == VPU_TAG].sum())
+
+
 class _SlotValueCounts:
-    """``vals_gather_elems`` and ``vals_slots`` of an artifact: the
-    elements its slot-value staging gathers per call and the slots of
-    the stream it stages, fixed when it is built; None off the fused
-    backends."""
+    """``vals_gather_elems``, ``vals_slots`` and ``vpu_steps`` of an
+    artifact: the elements its slot-value staging gathers per call, the
+    slots of the stream it stages and the loop steps its VPU trips run,
+    fixed when it is built; None off the fused backends."""
+
+    def _tables(self):
+        """The artifact's device constants; None off the fused
+        backends."""
+        return self._sharded or self._fused
 
     def _staged(self) -> Optional[_SlotValues]:
-        fw = self._sharded or self._fused
+        fw = self._tables()
         return None if fw is None else fw.vals
 
     @property
@@ -363,6 +375,11 @@ class _SlotValueCounts:
     def vals_slots(self) -> Optional[int]:
         sv = self._staged()
         return None if sv is None else sv.slots
+
+    @property
+    def vpu_steps(self) -> Optional[int]:
+        fw = self._tables()
+        return None if fw is None else fw.vpu_steps
 
 
 def _fused_consts(ws, nnz: int, members: int = 1) -> "_FusedConsts":
@@ -376,7 +393,8 @@ def _fused_consts(ws, nnz: int, members: int = 1) -> "_FusedConsts":
         inv_perm=jnp.asarray(ws.inv_perm), num_blocks=ws.num_blocks,
         blk_tag=jnp.asarray(ws.blk_tag), blk_coff=jnp.asarray(ws.blk_coff),
         max_span=ws.max_span, max_cspan=ws.max_cspan,
-        merge_width=ws.merge_width, cont=jnp.asarray(ws.blk_cont))
+        merge_width=ws.merge_width, cont=jnp.asarray(ws.blk_cont),
+        vpu_steps=_vpu_steps(ws))
 
 
 @dataclasses.dataclass
@@ -410,6 +428,7 @@ class _ShardedConsts:
     x_recv: Optional[jax.Array] = None    # (C, T) int32 into (C*T2,)
     merge_width: int = 1     # CGCM width, global across chips (§7.9)
     cont: Optional[jax.Array] = None      # (C, B//W) int32 piece trips
+    vpu_steps: int = 0       # loop steps of the VPU descriptors per call
 
 
 def _sharded_consts(sw: ShardedFusedWorkspace, mesh: Mesh
@@ -435,7 +454,7 @@ def _sharded_consts(sw: ShardedFusedWorkspace, mesh: Mesh
         x_sharding=sw.x_sharding, x_panels=sw.x_panels,
         x_own_panels=sw.x_own_panels, x_send=put(sw.x_send),
         x_recv=put(sw.x_recv), merge_width=sw.merge_width,
-        cont=put(sw.blk_cont))
+        cont=put(sw.blk_cont), vpu_steps=_vpu_steps(sw))
 
 
 class CompiledSpmm(_SlotValueCounts):
@@ -872,8 +891,8 @@ class CompiledBatchedSpmm(_SlotValueCounts):
         # artifact, so this never retraces after warmup)
         self._jit_forward = jax.jit(self._forward)
 
-    def _staged(self) -> _SlotValues:
-        return self._consts.vals
+    def _tables(self) -> "_FusedConsts":
+        return self._consts
 
     @property
     def n_requests(self) -> int:

@@ -242,7 +242,8 @@ def attn_fused(blk_tag: jax.Array, blk_off: jax.Array,
     blk_tag   : (B,) int32 — 0 = VPU ELL block, 1 = MXU block-row
     blk_off   : (B,) int32 — first slot of each block in vals_flat
     blk_coff  : (B,) int32 — first entry of each block in cols_flat
-    blk_L     : (B,) int32 — trips: padded nnz/row (VPU) or K (MXU)
+    blk_L     : (B,) int32 — trips: the block's longest row (VPU) or K
+                (MXU)
     cols_flat : (Sc,) int32 — K/V row per slot (VPU) / block-col (MXU)
     vals_flat : (S,) float — mask weights per slot, zero on padding
     q_ws      : (B*bm, dh_pad) float — Q in WORKSPACE row order (the

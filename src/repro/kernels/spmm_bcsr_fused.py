@@ -5,10 +5,11 @@ The planner's :class:`~repro.core.plan.MixedPlan` tags every
 ``bm``-aligned row-block with the execution unit that wins on its
 structure, and ONE ``pallas_call`` covers both:
 
-  VPU descriptor (tag 0): ``blk_L`` = padded nnz/row; each trip gathers
-      one X row per block row and scales it by that slot's value, read
-      as a scalar from SMEM.  A plan tagged with ``mxu_gain=0`` is all
-      VPU descriptors.
+  VPU descriptor (tag 0): ``blk_L`` = the longest of the block's own
+      rows, shorter rows padded to it; each trip gathers one X row per
+      block row and scales it by that slot's value, read as a scalar
+      from SMEM.  A plan tagged with ``mxu_gain=0`` is all VPU
+      descriptors.
   MXU descriptor (tag 1): ``blk_L`` = the block-row's own ``K``; each
       trip multiplies one ``(bm, bk)`` value panel (an aligned vector
       tile: the packer lane-pads panels to ``(bm, LANE)``) against the
@@ -227,7 +228,8 @@ def spmm_bcsr_fused(blk_tag: jax.Array, blk_off: jax.Array,
     blk_tag   : (B,) int32 — 0 = VPU ELL block, 1 = MXU block-row
     blk_off   : (B,) int32 — first slot of each block in vals_flat
     blk_coff  : (B,) int32 — first entry of each block in cols_flat
-    blk_L     : (B,) int32 — trips: padded nnz/row (VPU) or K (MXU)
+    blk_L     : (B,) int32 — trips: the block's longest row (VPU) or K
+                (MXU)
     cols_flat : (Sc,) int32 — X row per slot (VPU) / block-column (MXU)
     vals_flat : (S,) float — slot values; MXU panels (K, bm, LANE)
     x         : (n, d_pad) float — columns padded to the lane tile

@@ -225,7 +225,8 @@ def test_chip_smoke_reads_the_staging_tables():
                          cache=JitCache())
         line = chip_smoke.describe_fused(c)
         assert c.vals_gather_elems < c.vals_slots
-        assert (f"slots={c.vals_slots} gathered={c.vals_gather_elems}"
+        assert (f"nnz={mixed.nnz} slots={c.vals_slots} "
+                f"vpu_steps={c.vpu_steps} gathered={c.vals_gather_elems}"
                 in line), line
         print("OK")
     """)
